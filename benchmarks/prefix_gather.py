@@ -1,6 +1,6 @@
-"""Stacked prefix-gather kernel: fused gather + split-select + segment
-reduce (``prefix_select_gather``) vs the plain jnp reference path, on
-the real 2-workload stacked engine tables.
+"""Stacked prefix-gather kernel: fused gather + split-select
+(``prefix_select_gather``) vs the plain jnp reference path, on the real
+2-workload stacked engine tables.
 
 Claims asserted:
   (a) the kernel (interpret mode on CPU, compiled on TPU) matches the
@@ -34,7 +34,7 @@ def _inputs(rng, tb, cfg, P):
     the stacked table, segments clipped like the tempering step's."""
     import jax.numpy as jnp
 
-    R = tb["pref0_flatw"].shape[1]
+    R = cfg.pallas_layout[0]
     C = cfg.C
     wi = rng.integers(0, 2, (P,))
     rows = (rng.integers(0, R // 2, (P, C))
@@ -52,7 +52,7 @@ def _inputs(rng, tb, cfg, P):
 def run(out=print) -> str:
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
     on_tpu = jax.default_backend() == "tpu"
 
@@ -60,29 +60,36 @@ def run(out=print) -> str:
         eng = ScenarioEngine([workload(1), workload(6)], use_pallas=True)
         tb, cfg = eng.tables, eng.cfg
         ref_fn = jax.jit(prefix_select_ref)
-        kern = lambda *a: prefix_select_gather(   # noqa: E731
-            *a, interpret=not on_tpu)
+        tab = tb["pallas_table"]
+
+        def kern(*a):
+            return prefix_select_gather(tab, *a, layout=cfg.pallas_layout)
+
+        def flat(pw):
+            # [Wk, A, S, 3, T+1, 5] -> the kernel's [5, Wk*A*S*3, T+1]
+            pw = np.asarray(pw)
+            return jnp.asarray(np.moveaxis(
+                pw.reshape(-1, pw.shape[-2], pw.shape[-1]), -1, 0))
+
         rng = np.random.default_rng(2026)
         stats = {}
-        with enable_x64():
-            # int64 tables, converted under x64 like the engine does —
-            # an int32 truncation would overflow the slot-sum totals
-            p0 = jnp.asarray(tb["pref0_flatw"])
-            p1 = jnp.asarray(tb["pref1_flatw"])
+        with search_numerics():
+            # int64 tables under x64 like the engine: an int32 truncation
+            # would wrap the WL2-sized prefix sums
+            p0, p1 = flat(tb["pref0w"]), flat(tb["pref1w"])
             for P in CHAINS:
                 args = _inputs(rng, tb, cfg, P)
-                sel_r, tot_r = ref_fn(p0, p1, *args)
-                sel_k, tot_k = kern(p0, p1, *args)
+                sel_r = ref_fn(p0, p1, *args)
+                sel_k = kern(*args)
                 assert (np.asarray(sel_r) == np.asarray(sel_k)).all()
-                assert (np.asarray(tot_r) == np.asarray(tot_k)).all()
 
-                def bench(fn):
-                    fn(p0, p1, *args)[0].block_until_ready()  # warm
+                def bench(fn, *a):
+                    fn(*a).block_until_ready()  # warm
                     return min(
-                        timed(lambda: fn(p0, p1, *args)[0]
-                              .block_until_ready())[1]
+                        timed(lambda: fn(*a).block_until_ready())[1]
                         for _ in range(REPEATS))
-                stats[P] = (bench(ref_fn), bench(kern))
+                stats[P] = (bench(ref_fn, p0, p1, *args),
+                            bench(kern, *args))
         return stats
 
     stats, us = timed(compute)

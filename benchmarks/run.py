@@ -122,6 +122,9 @@ def append_trajectory(path: str, rows, commit: Optional[str]) -> None:
 
 
 def main() -> None:
+    from repro.jaxenv import use_compile_cache
+
+    use_compile_cache()
     args = sys.argv[1:]
     json_path = _take_flag(args, "--json")
     traj_path = _take_flag(args, "--trajectory")

@@ -18,9 +18,13 @@ Two entry points:
     The full CI lane: run an uninterrupted reference, launch a live
     worker and SIGTERM it mid-run (after its first checkpoint appears),
     rerun the worker to resume, and assert the resumed frontiers are
-    **bit-identical** to the reference. The three subprocesses share a
-    JAX persistent compilation cache so only the first pays the XLA
-    compile.
+    **bit-identical** to the reference. The parent never imports JAX
+    and runs its children one after another, so exactly one process
+    holds the device at a time (a TPU belongs to one process). The
+    children share JAX's persistent compilation cache
+    (:func:`repro.jaxenv.use_compile_cache`: ``$JAX_COMPILATION_CACHE_DIR``,
+    else ``.jax_cache/`` at the checkout root), so only the first pays
+    the XLA compile.
 
 Usage::
 
@@ -59,6 +63,9 @@ def _build_sweep():
 
 
 def cmd_run(args: argparse.Namespace) -> int:
+    from repro.jaxenv import use_compile_cache
+
+    use_compile_cache()
     if args.max_segments or args.sleep:
         from repro.pathfinding.resume import SearchCheckpointer
 
@@ -121,17 +128,10 @@ def _wait_for_checkpoint(directory: str, proc: subprocess.Popen,
 def cmd_check(args: argparse.Namespace) -> int:
     workdir = args.workdir or tempfile.mkdtemp(prefix="kill-resume-")
     os.makedirs(workdir, exist_ok=True)
-    env = dict(os.environ)
-    # all three subprocesses share one persistent XLA cache: only the
-    # first pays the compile, and the lane doubles as a cache smoke test
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(workdir, "jax-cache"))
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     me = os.path.abspath(__file__)
 
     def worker(*extra: str) -> subprocess.Popen:
-        return subprocess.Popen([sys.executable, me, "run", *extra],
-                                env=env)
+        return subprocess.Popen([sys.executable, me, "run", *extra])
 
     ref_npz = os.path.join(workdir, "reference.npz")
     res_npz = os.path.join(workdir, "resumed.npz")

@@ -22,8 +22,12 @@ Two entry points:
     service that resumes every job, and a final assertion that each
     resumed job is **bit-identical** to its solo reference — packing,
     preemption and restart are all invisible to a job's trajectory.
-    All subprocesses share a JAX persistent compilation cache so only
-    the first pays the XLA compile.
+    The parent never imports JAX and runs its children one after
+    another, so exactly one process holds the device at a time (a TPU
+    belongs to one process). The children share JAX's persistent
+    compilation cache (:func:`repro.jaxenv.use_compile_cache`:
+    ``$JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/`` at the checkout
+    root), so only the first pays the XLA compile.
 
 Usage::
 
@@ -98,6 +102,9 @@ def _collect(svc, jobs, payload):
 
 
 def cmd_run(args: argparse.Namespace) -> int:
+    from repro.jaxenv import use_compile_cache
+
+    use_compile_cache()
     if args.max_segments or args.sleep:
         from repro.pathfinding.resume import SearchCheckpointer
 
@@ -167,17 +174,10 @@ def _wait_for_checkpoint(root: str, proc: subprocess.Popen,
 def cmd_check(args: argparse.Namespace) -> int:
     workdir = args.workdir or tempfile.mkdtemp(prefix="serve-smoke-")
     os.makedirs(workdir, exist_ok=True)
-    env = dict(os.environ)
-    # every subprocess shares one persistent XLA cache: only the first
-    # pays the compile for the two bucket shapes
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.join(workdir, "jax-cache"))
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     me = os.path.abspath(__file__)
 
     def worker(*extra: str) -> subprocess.Popen:
-        return subprocess.Popen([sys.executable, me, "run", *extra],
-                                env=env)
+        return subprocess.Popen([sys.executable, me, "run", *extra])
 
     ref_npz = os.path.join(workdir, "reference.npz")
     res_npz = os.path.join(workdir, "resumed.npz")

@@ -124,10 +124,10 @@ def _as_jnp(arr: np.ndarray):
     float64/int64 leaf must not silently demote to 32-bit when the
     process runs without global x64 (the search-state checkpoints are
     float64 end to end)."""
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
     if arr.dtype in (np.float64, np.int64, np.uint64, np.complex128):
-        with enable_x64():
+        with search_numerics():
             return jnp.asarray(arr)
     return jnp.asarray(arr)
 

@@ -36,6 +36,7 @@ import dataclasses
 from typing import Optional, Sequence
 
 from repro.core.chiplet import Chiplet
+from repro.core.seqsum import seq_sum
 from repro.core.system import HISystem
 from repro.core.cost import bonding_yield
 from repro.core.techdb import DEFAULT_DB, TechDB
@@ -130,7 +131,7 @@ def packaging_cfp(sys: HISystem, package_area_mm2: float,
     if sys.style in ("3D", "2.5D+3D"):
         pkg = db.packages[sys.pkg_3d]
         order = sys.stack_order(db)
-        bonded_area = sum(sys.chiplets[i].area_mm2(db) for i in order[1:])
+        bonded_area = seq_sum(sys.chiplets[i].area_mm2(db) for i in order[1:])
         cfp += pkg.cfp_kg_per_mm2 * bonded_area
     return cfp / bonding_yield(sys, db)
 
@@ -155,13 +156,13 @@ def embodied_cfp(sys: HISystem, package_area_mm2: float,
     ``(1, 1)`` mesh multiplies by exactly 1.0 per chiplet, reproducing
     the legacy term bit-for-bit."""
     per_chip = [chiplet_mfg_cfp(c, db) for c in sys.chiplets]
-    mfg = sum(per_chip)
-    des = sum(chiplet_design_cfp(c, db) for c in sys.chiplets)
+    mfg = seq_sum(per_chip)
+    des = seq_sum(chiplet_design_cfp(c, db) for c in sys.chiplets)
     pkg = packaging_cfp(sys, package_area_mm2, db)
     if sys.noc:
         from repro.core.comm import system_n_routers
         routers = system_n_routers(sys)
-        pkg = pkg + db.router_area_frac * sum(
+        pkg = pkg + db.router_area_frac * seq_sum(
             m * r for m, r in zip(per_chip, routers))
     else:
         pkg = pkg + db.router_area_frac * mfg

@@ -22,6 +22,7 @@ from repro.core import d2d as d2d_mod
 from repro.core import scalesim as sim_mod
 from repro.core.evaluate import Metrics, package_area_mm2
 from repro.core.scalesim import SimCache
+from repro.core.seqsum import seq_sum
 from repro.core.system import HISystem
 from repro.core.techdb import (
     CHIPLETGYM_BOND_YIELD,
@@ -67,11 +68,11 @@ def evaluate_chipletgym(
     latency = l_cr + l_d2d + l_wr
 
     # energy: MAC energy only
-    energy = sum(s.macs * db.mac_energy_pj(a.core.node)
+    energy = seq_sum(s.macs * db.mac_energy_pj(a.core.node)
                  for a, s in zip(assignments, sims)) * 1e-12
 
     area = package_area_mm2(sys, topo, db)
-    chiplets = sum(cost_mod.chiplet_cost(c, db) for c in sys.chiplets)
+    chiplets = seq_sum(cost_mod.chiplet_cost(c, db) for c in sys.chiplets)
     interposer = 0.0
     if sys.style in ("2.5D", "2.5D+3D") and sys.pkg_25d in ("Passive", "Active"):
         interposer = cost_mod.interposer_cost(area, db)

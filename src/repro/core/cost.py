@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 
 from repro.core.chiplet import Chiplet
+from repro.core.seqsum import seq_sum
 from repro.core.system import HISystem
 from repro.core.techdb import DEFAULT_DB, TechDB
 
@@ -70,7 +71,7 @@ def system_cost(sys: HISystem, package_area_mm2: float,
                 db: TechDB = DEFAULT_DB) -> CostBreakdown:
     """Eq. 15. ``package_area_mm2`` comes from the area model (floorplan
     bbox for 2.5D/hybrid, base-die area for 3D, die area for 2D)."""
-    chiplets = sum(chiplet_cost(c, db) for c in sys.chiplets)
+    chiplets = seq_sum(chiplet_cost(c, db) for c in sys.chiplets)
     interposer = 0.0
     if sys.style in ("2.5D", "2.5D+3D") and sys.pkg_25d in ("Passive", "Active"):
         interposer = interposer_cost(package_area_mm2, db)

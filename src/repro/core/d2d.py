@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 from repro.core import comm as comm_mod
 from repro.core import floorplan as fp
 from repro.core.chiplet import Chiplet
+from repro.core.seqsum import seq_sum
 from repro.core.system import HISystem
 from repro.core.techdb import DEFAULT_DB, DEFAULT_HOP_LATENCY_S, TechDB
 
@@ -119,7 +120,7 @@ class Topology:
         if self.mem_bw_bits_s.get(idx, 0.0) > 0.0:
             return 0.0
         assert self.base_die is not None
-        return sum(l.energy_pj_bit for l in self.path_links(idx, self.base_die))
+        return seq_sum(l.energy_pj_bit for l in self.path_links(idx, self.base_die))
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +249,7 @@ def build_topology(sys: HISystem, db: TechDB = DEFAULT_DB) -> Topology:
                 add_link(lo, hi, sys.pkg_3d, sys.proto_3d, "3D")
         # 2.5D memory: channels distributed by chiplet size (Sec IV-A(2));
         # stacked non-base dies get no direct channel.
-        total_planar_area = sum(areas[i] for i in planar)
+        total_planar_area = seq_sum(areas[i] for i in planar)
         for i in planar:
             mem_bw[i] = total_mem_bw * areas[i] / total_planar_area
     else:  # pure 3D
@@ -302,7 +303,7 @@ def route_reduction(topo: Topology, src_bits: Sequence[int]) -> D2DResult:
         path = topo.path_links(src, topo.dest)
         max_hops = max(max_hops, len(path))
         path_lat = (len(path) * uniform if uniform is not None
-                    else sum(l.hop_latency_s for l in path))
+                    else seq_sum(l.hop_latency_s for l in path))
         if noc_h:
             pair_hops = noc_h[src] + dest_noc
             path_lat += pair_hops * topo.noc_hop_latency_s

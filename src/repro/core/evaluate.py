@@ -25,6 +25,7 @@ from repro.core import cost as cost_mod
 from repro.core import d2d as d2d_mod
 from repro.core import scalesim as sim_mod
 from repro.core.scalesim import OPERAND_BYTES, PSUM_BYTES, SimCache
+from repro.core.seqsum import seq_sum
 from repro.core.system import HISystem
 from repro.core.techdb import DEFAULT_DB, TechDB
 from repro.core.workload import (
@@ -144,7 +145,7 @@ def evaluate(
     e_d2d_j = e_d2d_pj * 1e-12
     # static power burns for the whole system latency — this is the term
     # through which faster execution lowers energy and operational CFP.
-    e_static_j = sum(c.static_power_w(db) for c in sys.chiplets) * latency
+    e_static_j = seq_sum(c.static_power_w(db) for c in sys.chiplets) * latency
     energy = e_compute_j + e_d2d_j + e_static_j
 
     # -- area, cost, carbon ---------------------------------------------------

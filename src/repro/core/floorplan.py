@@ -12,6 +12,8 @@ import dataclasses
 import math
 from typing import Dict, List, Sequence, Set, Tuple
 
+from repro.core.seqsum import seq_sum
+
 
 @dataclasses.dataclass
 class Rect:
@@ -57,7 +59,7 @@ class Floorplan:
 
     @property
     def die_area(self) -> float:
-        return sum(r.die_area for r in self.rects)
+        return seq_sum(r.die_area for r in self.rects)
 
     @property
     def white_space(self) -> float:
@@ -118,7 +120,7 @@ def floorplan(areas: Sequence[float], whitespace_frac: float = 0.10) -> Floorpla
     """
     if not areas:
         raise ValueError("empty chiplet set")
-    total = sum(areas) * (1.0 + whitespace_frac)
+    total = seq_sum(areas) * (1.0 + whitespace_frac)
     side = math.sqrt(total)
     out: Dict[int, Rect] = {}
     _place(list(enumerate(areas)), 0.0, 0.0, side, side, True, out)

@@ -12,6 +12,7 @@ import dataclasses
 from typing import List, Sequence, Tuple
 
 from repro.core.chiplet import Chiplet
+from repro.core.seqsum import seq_sum
 from repro.core.techdb import DEFAULT_DB, TechDB
 
 
@@ -156,7 +157,7 @@ def tile_and_assign(
     total = len(tiles)
 
     powers = [cores[i].compute_power_ratio(db) for i in order]
-    psum = sum(powers)
+    psum = seq_sum(powers)
     ideal = [p / psum * total for p in powers]                   # line 6
     counts = [int(x) for x in ideal]                             # line 7
     remaining = total - sum(counts)

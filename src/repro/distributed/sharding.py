@@ -280,29 +280,42 @@ def constrain(x, spec_template: Sequence) -> Any:
 # ---------------------------------------------------------------------------
 
 
-def scenario_mesh(min_devices: int = 2) -> Optional[Mesh]:
+def scenario_mesh(min_devices: int = 2,
+                  n_cells: Optional[int] = None) -> Optional[Mesh]:
     """1-D ``('data',)`` mesh over the local devices for sharding a
     scenario (deployment grid) axis — e.g. the stacked
-    :class:`repro.pathfinding.device.ScenarioEngine` scan. Returns
-    ``None`` when fewer than ``min_devices`` devices exist (sharding a
-    single device only adds dispatch overhead). On CPU, set
+    :class:`repro.pathfinding.device.ScenarioEngine` scan.
+
+    With ``n_cells`` the mesh takes the largest number of devices that
+    divides the cell count (10 cells on 4 devices -> 2 devices), so the
+    scenario axis is always really split. Returns ``None`` when that
+    leaves fewer than ``min_devices`` devices (sharding a single device
+    only adds dispatch overhead). On CPU, set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the
     first jax import to expose N virtual devices."""
     from repro.launch.mesh import _mesh_kwargs
 
-    n = len(jax.devices())
+    devices = jax.devices()
+    n = len(devices)
+    if n_cells is not None:
+        n = max(d for d in range(1, n + 1) if n_cells % d == 0)
     if n < min_devices:
         return None
-    return jax.make_mesh((n,), ("data",), **_mesh_kwargs(1))
+    return jax.make_mesh((n,), ("data",), devices=devices[:n],
+                         **_mesh_kwargs(1))
 
 
 def shard_scenarios(arrays: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
     """Place each array with its *leading* (scenario) axis split over the
-    mesh's data axes. Divisibility-aware via :func:`fit_spec`: an axis
-    that does not divide the scenario count is dropped (the array is
-    replicated) rather than erroring, so ragged grids still run."""
+    mesh's data axes. The split is required: a leading axis the mesh
+    does not divide raises instead of silently replicating every cell
+    on every device (:func:`scenario_mesh` sizes a mesh that divides)."""
     out = {}
     for k, x in arrays.items():
         spec = fit_spec(x.shape, (DATA,) + (None,) * (x.ndim - 1), mesh)
+        if mesh.size > 1 and spec[0] is None:
+            raise ValueError(
+                f"scenario axis of {k!r} ({x.shape[0]} cells) does not "
+                f"divide the {mesh.size}-device mesh")
         out[k] = jax.device_put(x, NamedSharding(mesh, spec))
     return out

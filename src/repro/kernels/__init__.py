@@ -5,12 +5,11 @@
                   (dataflow OS/WS/IS, split-K, tile shape).
   wkv6          — RWKV-6 data-dependent-decay recurrence.
   rglru         — RecurrentGemma gated linear recurrence.
-  prefix_gather — prefix-table gather + per-chiplet-slot segment reduction
+  prefix_gather — prefix-table gather + per-chiplet-slot split-K select
                   (the device pathfinder's stage-3 inner loop).
 """
 from repro.kernels.prefix_gather import (
-    prefix_segment_gather,
-    prefix_segment_ref,
+    pack_tables,
     prefix_select_gather,
     prefix_select_ref,
 )
@@ -22,6 +21,5 @@ __all__ = [
     "systolic_gemm", "gemm_ref",
     "wkv6", "wkv6_ref", "wkv6_ref_vmapped",
     "rglru", "rglru_ref", "rglru_assoc_ref",
-    "prefix_segment_gather", "prefix_segment_ref",
-    "prefix_select_gather", "prefix_select_ref",
+    "pack_tables", "prefix_select_gather", "prefix_select_ref",
 ]

@@ -1,11 +1,5 @@
-from repro.kernels.prefix_gather.ops import (
-    prefix_segment_gather,
-    prefix_select_gather,
-)
-from repro.kernels.prefix_gather.ref import (
-    prefix_segment_ref,
-    prefix_select_ref,
-)
+from repro.kernels.prefix_gather.kernel import pack_tables
+from repro.kernels.prefix_gather.ops import prefix_select_gather
+from repro.kernels.prefix_gather.ref import prefix_select_ref
 
-__all__ = ["prefix_segment_gather", "prefix_segment_ref",
-           "prefix_select_gather", "prefix_select_ref"]
+__all__ = ["pack_tables", "prefix_select_gather", "prefix_select_ref"]

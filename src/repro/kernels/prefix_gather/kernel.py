@@ -1,35 +1,40 @@
-"""Pallas kernel: prefix-table gather + per-chiplet-slot segment reduction.
+"""Pallas kernel: prefix-table gather + per-chiplet-slot split-K select.
 
 The hottest inner loop of the device evaluator's stage 3
 (:mod:`repro.pathfinding.device`): every system gathers, per chiplet
 slot, the difference of two entries of a per-(array, sram, dataflow)
 prefix-sum table — Algorithm 1 assigns contiguous tile ranges, so a
-core's ScaleSim aggregate is ``pref[row, end] - pref[row, start]`` — and
-reduces the slot values to a per-system total.
+core's ScaleSim aggregate is ``pref[row, end] - pref[row, start]`` — for
+the five sim metrics, from the split-K table the system's mapping picks.
 
-Layout: one grid step per system. The three index arrays ride in scalar
-prefetch (SMEM) — the canonical Pallas embedding-gather idiom — while the
-prefix table lives in (V)MEM as a single resident block; the slot loop is
-unrolled (``C`` = max chiplets, 6 by default), each iteration issuing two
-dynamically indexed scalar loads.
+Packed layout (:func:`pack_tables`). The int64 prefix values reach
+1.5e10 (WL2), past int32 and float32's exact range, and the TPU vector
+unit has no int64. Each value ``v`` is stored as the exact int32 pair
+``hi = v >> 31``, ``lo = v & (2**31 - 1)``. One *group* holds one
+(split, row, tile-boundary) entry: 16 int32 lanes, the F hi words then
+the F lo words. Groups of both split tables are laid end to end
+(``g = off[split] + row * (T_split + 1) + boundary``) and folded into a
+lane-dense ``[G / 8, 128]`` int32 array, 8 groups per 128-lane row. The
+caller turns each slot's clipped ``[start, end]`` range into two group
+indices, so the kernel does no clipping or split logic of its own.
 
-Two kernels share the idiom:
+Kernel. The packed table is one VMEM-resident block (a constant index
+map: copied in once). The group indices ride in SMEM blocks of ``B``
+systems. For each slot and each sub-tile of 8 systems, every system's
+two groups are fetched as whole 128-lane rows (a dynamic *sublane*
+offset, never a dynamic lane), the other 7 groups of each row are
+masked to zero, and the rows are staged into two ``[8, 128]`` scratch
+tiles. ``end - start`` of the two tiles, summed over the row's 8 group
+positions with three static lane rotations, leaves the slot's exact
+hi/lo differences in every group; the slot's group position is kept.
+After all slots the ``[8, 128]`` tile (slot ``c`` in lanes
+``16c .. 16c + 15``) is stored whole. int32 arithmetic wraps, so the
+differences are exact even where a partial sum overflows; the caller
+recombines ``dhi * 2**31 + dlo`` in int64, bit-equal to the int64
+reference gather.
 
-  ``_gather_kernel``  — one table, raw [start, end] differences (PR 2's
-                        original single-metric entry point).
-  ``_select_kernel``  — the fused tempering gather stage: both split-K
-                        table stacks resident at once, per-row clip
-                        bounds applied on the SMEM scalars, per-slot
-                        split select and per-metric segment reduction
-                        emitted in the same grid step. This is the one
-                        the device evaluator and the workload-stacked
-                        ScenarioEngine route through.
-
-CPU containers run this in interpreter mode, which is exact for the
-float64 tables the device evaluator feeds it (prefix magnitudes < 2^53).
-On TPU the same kernel compiles for float32/int32 tables; the f64 parity
-contract then requires rebased (per-range) tables, which is why the
-device evaluator only enables the kernel path explicitly.
+On CPU the same kernel runs in interpreter mode (tests); on TPU it is
+compiled, and interpret mode is never used there.
 """
 from __future__ import annotations
 
@@ -37,109 +42,107 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _gather_kernel(rows_ref, start_ref, end_ref, pref_ref, diff_ref,
-                   total_ref, *, nc: int):
-    i = pl.program_id(0)
-    tot = None
-    for c in range(nc):  # static unroll over chiplet slots
-        r = rows_ref[i, c]
-        s = start_ref[i, c]
-        e = end_ref[i, c]
-        d = pref_ref[r, e] - pref_ref[r, s]
-        diff_ref[0, c] = d
-        tot = d if tot is None else tot + d
-    total_ref[0, 0] = tot
+LANES = 128
+GROUP = 16                  # lanes per packed group
+GROUPS_PER_ROW = LANES // GROUP
+LO_BITS = 31
+BLOCK = 128                 # systems per grid step
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def prefix_segment(pref, rows, start, end, *, interpret: bool):
-    """(diff [P, C], total [P, 1]) via one grid step per system."""
-    P, C = rows.shape
-    R, T1 = pref.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(P,),
-        in_specs=[pl.BlockSpec((R, T1), lambda i, *_: (0, 0))],
-        out_specs=[pl.BlockSpec((1, C), lambda i, *_: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i, *_: (i, 0))],
-    )
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, nc=C),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((P, C), pref.dtype),
-                   jax.ShapeDtypeStruct((P, 1), pref.dtype)],
+def pack_tables(pref0, pref1):
+    """Pack two split-K prefix stacks into the kernel's int32 layout.
+
+    ``pref0``/``pref1`` are ``[F, R, L0]`` / ``[F, R, L1]`` non-negative
+    integer prefix tables (``L = T + 1``; the tile axes may differ).
+    Returns ``(table [rows, 128] int32, (R, L0, L1))``; the group of
+    ``(split, r, t)`` is ``split * R * L0 + r * L_split + t``."""
+    p0 = np.asarray(pref0, dtype=np.int64)
+    p1 = np.asarray(pref1, dtype=np.int64)
+    F, R, L0 = p0.shape
+    F1, R1, L1 = p1.shape
+    assert (F, R) == (F1, R1), (p0.shape, p1.shape)
+    assert 2 * F <= GROUP, f"{F} metrics do not fit a {GROUP}-lane group"
+    assert p0.min(initial=0) >= 0 and p1.min(initial=0) >= 0
+    assert max(p0.max(initial=0), p1.max(initial=0)) < 2 ** 62
+    # [F, G] with G = R*L0 + R*L1 groups, in group order
+    vals = np.concatenate([p0.reshape(F, -1), p1.reshape(F, -1)], axis=1)
+    G = vals.shape[1]
+    rows = -(-G // GROUPS_PER_ROW)
+    rows = -(-rows // 8) * 8          # whole (8, 128) tiles
+    packed = np.zeros((rows * GROUPS_PER_ROW, GROUP), dtype=np.int32)
+    packed[:G, :F] = (vals >> LO_BITS).T
+    packed[:G, F:2 * F] = (vals & ((1 << LO_BITS) - 1)).T
+    return packed.reshape(rows, LANES), (R, L0, L1)
+
+
+def _select_kernel(ge_ref, gs_ref, tab_ref, out_ref, e_scr, s_scr, *,
+                   nc: int):
+    # every constant is a typed int32: the callers trace under 64-bit
+    # types, where a bare Python int would become an int64 constant that
+    # Mosaic cannot lower
+    i32 = np.int32
+    lane_row = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // i32(GROUP)
+    lane_grp = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 1) // i32(GROUP)
+
+    def fetch(g):
+        # the row holding group g, with its other 7 groups zeroed
+        row = tab_ref[pl.ds(g // i32(GROUPS_PER_ROW), 1), :]
+        return jnp.where(lane_row == g % i32(GROUPS_PER_ROW), row, i32(0))
+
+    def sub_tile(j):
+        acc = jnp.zeros((8, LANES), jnp.int32)
+        for c in range(nc):
+            for b in range(8):
+                k = (j * i32(8) + i32(b)) * i32(GROUPS_PER_ROW) + i32(c)
+                e_scr[pl.ds(b, 1), :] = fetch(ge_ref[k])
+                s_scr[pl.ds(b, 1), :] = fetch(gs_ref[k])
+            d = e_scr[...] - s_scr[...]
+            for shift in (GROUP, 2 * GROUP, 4 * GROUP):
+                d = d + pltpu.roll(d, i32(shift), 1)
+            acc = jnp.where(lane_grp == i32(c), d, acc)
+        out_ref[pl.ds(pl.multiple_of(j * i32(8), 8), 8), :] = acc
+        return j + i32(1)
+
+    # a while loop: fori_loop's static trip count would become a scan
+    # with an int64 counter
+    n_sub = i32(out_ref.shape[0] // 8)
+    jax.lax.while_loop(lambda j: j < n_sub, sub_tile, i32(0))
+
+
+def select_groups(table, ge, gs, *, interpret: bool):
+    """``[N, 128]`` int32 packed differences of groups ``ge - gs``.
+
+    ``ge``/``gs`` are ``[N, C]`` group indices (``C <= 8``); row ``n``
+    holds slot ``c``'s hi/lo differences in lanes ``16c ..``."""
+    N, C = ge.shape
+    assert C <= GROUPS_PER_ROW, C
+    # 8 index slots per system and 128 systems per step: SMEM blocks of
+    # 1024 words, the 1-D SMEM tiling
+    block = min(BLOCK, -(-N // 8) * 8)
+    n_pad = -(-N // block) * block
+    pad = ((0, n_pad - N), (0, GROUPS_PER_ROW - C))
+    ge = jnp.pad(ge.astype(jnp.int32), pad).reshape(-1)
+    gs = jnp.pad(gs.astype(jnp.int32), pad).reshape(-1)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    zero = np.int32(0)  # typed: index maps are traced under 64-bit types
+    out = pl.pallas_call(
+        functools.partial(_select_kernel, nc=C),
+        grid=(n_pad // block,),
+        in_specs=[smem((block * GROUPS_PER_ROW,), lambda i: (i,)),
+                  smem((block * GROUPS_PER_ROW,), lambda i: (i,)),
+                  pl.BlockSpec(table.shape, lambda i: (zero, zero))],
+        out_specs=pl.BlockSpec((block, LANES), lambda i: (i, zero)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((8, LANES), jnp.int32),
+                        pltpu.VMEM((8, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(rows.astype(jnp.int32), start.astype(jnp.int32),
-      end.astype(jnp.int32), pref)
-
-
-def _select_kernel(rows_ref, start_ref, end_ref, split_ref, t0_ref, t1_ref,
-                   p0_ref, p1_ref, sel_ref, total_ref, *, nc: int, nf: int):
-    """Fused gather → per-slot split-K select → segment reduce.
-
-    One grid step per system: the six index/bound vectors ride in scalar
-    prefetch (SMEM); BOTH split-K table stacks (``[F, R, T+1]``, one
-    plane per sim metric) are resident (V)MEM blocks with a constant
-    index map, so Pallas's double-buffered block pipeline copies them in
-    once and every grid step reuses the same buffers. Clipping to the
-    per-row tile totals happens on the SMEM scalars, so bucket-padded
-    rows and ``T0 != T1`` split tables never leak padding into a gather.
-    """
-    i = pl.program_id(0)
-    sp = split_ref[i] == 1
-    t0 = t0_ref[i]
-    t1 = t1_ref[i]
-    tot = [None] * nf
-    for c in range(nc):  # static unroll over chiplet slots
-        r = rows_ref[i, c]
-        s = start_ref[i, c]
-        e = end_ref[i, c]
-        # clip against the true (unpadded) per-row tile totals
-        s0 = jnp.minimum(jnp.maximum(s, 0), t0)
-        e0 = jnp.minimum(jnp.maximum(e, 0), t0)
-        s1 = jnp.minimum(jnp.maximum(s, 0), t1)
-        e1 = jnp.minimum(jnp.maximum(e, 0), t1)
-        for f in range(nf):  # static unroll over sim metrics
-            d = jnp.where(sp, p1_ref[f, r, e1] - p1_ref[f, r, s1],
-                          p0_ref[f, r, e0] - p0_ref[f, r, s0])
-            sel_ref[0, c, f] = d
-            tot[f] = d if tot[f] is None else tot[f] + d
-    for f in range(nf):
-        total_ref[0, f] = tot[f]
-
-
-def prefix_select(pref0, pref1, rows, start, end, split, t0, t1, *,
-                  interpret: bool):
-    """(sel [P, C, F], total [P, F]) — the fused tempering gather stage.
-
-    ``pref0``/``pref1`` are the two split-K table stacks ``[F, R, T+1]``
-    (row counts match, tile axes may differ); ``rows``/``start``/``end``
-    are ``[P, C]``; ``split``/``t0``/``t1`` are per-system ``[P]`` split
-    selectors and clip bounds. Rows already carry any workload-stack
-    offset, so the same kernel serves the single-workload flat layout
-    and the scenario engine's ``[(Wk*A*S*3), T_bucket+1]`` layout.
-    """
-    P, C = rows.shape
-    F, R0, T0b = pref0.shape
-    F1, R1, T1b = pref1.shape
-    assert F == F1 and R0 == R1, (pref0.shape, pref1.shape)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(P,),
-        in_specs=[pl.BlockSpec((F, R0, T0b), lambda i, *_: (0, 0, 0)),
-                  pl.BlockSpec((F, R1, T1b), lambda i, *_: (0, 0, 0))],
-        out_specs=[pl.BlockSpec((1, C, F), lambda i, *_: (i, 0, 0)),
-                   pl.BlockSpec((1, F), lambda i, *_: (i, 0))],
-    )
-    return pl.pallas_call(
-        functools.partial(_select_kernel, nc=C, nf=F),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((P, C, F), pref0.dtype),
-                   jax.ShapeDtypeStruct((P, F), pref0.dtype)],
-        interpret=interpret,
-    )(rows.astype(jnp.int32), start.astype(jnp.int32),
-      end.astype(jnp.int32), split.astype(jnp.int32),
-      t0.astype(jnp.int32), t1.astype(jnp.int32), pref0, pref1)
+    )(ge, gs, table)
+    return out[:N]

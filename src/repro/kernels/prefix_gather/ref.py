@@ -1,38 +1,22 @@
-"""Pure-jnp oracle for the prefix-gather + segment-reduction kernel."""
+"""Pure-jnp oracle for the prefix-gather kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 
-def prefix_segment_ref(pref: jnp.ndarray, rows: jnp.ndarray,
-                       start: jnp.ndarray, end: jnp.ndarray):
-    """Per-slot prefix-sum differences and their per-row totals.
-
-    ``pref`` is a ``[R, T+1]`` prefix-sum table; ``rows``/``start``/``end``
-    are ``[P, C]`` index arrays. Returns ``(diff [P, C], total [P])`` with
-    ``diff[p, c] = pref[rows[p, c], end[p, c]] - pref[rows[p, c],
-    start[p, c]]`` — Algorithm 1 assigns each core a contiguous tile
-    range, so a core's simulation aggregate is exactly this difference —
-    and ``total`` the per-system (all-slot) segment reduction.
-    """
-    diff = (jnp.take_along_axis(pref[rows], end[..., None], axis=2)
-            - jnp.take_along_axis(pref[rows], start[..., None], axis=2)
-            )[..., 0]
-    return diff, diff.sum(axis=1)
-
-
 def prefix_select_ref(pref0: jnp.ndarray, pref1: jnp.ndarray,
                       rows: jnp.ndarray, start: jnp.ndarray,
                       end: jnp.ndarray, split: jnp.ndarray,
-                      t0: jnp.ndarray, t1: jnp.ndarray):
-    """Oracle for the fused gather → split-select → segment-reduce kernel.
+                      t0: jnp.ndarray, t1: jnp.ndarray) -> jnp.ndarray:
+    """Oracle for the fused gather → split-select kernel.
 
     ``pref0``/``pref1`` are ``[F, R, T+1]`` split-K table stacks (tile
     axes may differ and may be padded past the true totals);
     ``rows``/``start``/``end`` are ``[P, C]``; ``split``/``t0``/``t1``
     per-system ``[P]``. Gathers clip to the per-row true tile totals,
     then the split selector picks per system which table's difference
-    survives. Returns ``(sel [P, C, F], total [P, F])``.
+    survives: ``sel[p, c, f] = pref[f, rows[p, c], end] - pref[f,
+    rows[p, c], start]``. Returns ``sel [P, C, F]``.
     """
     def gather(pref, s, e):
         tab = pref[:, rows]  # [F, P, C, T+1]
@@ -45,6 +29,5 @@ def prefix_select_ref(pref0: jnp.ndarray, pref1: jnp.ndarray,
     e0 = jnp.clip(end, 0, t0[:, None])
     s1 = jnp.clip(start, 0, t1[:, None])
     e1 = jnp.clip(end, 0, t1[:, None])
-    sel = jnp.where((split == 1)[:, None, None],
-                    gather(pref1, s1, e1), gather(pref0, s0, e0))
-    return sel, sel.sum(axis=1)
+    return jnp.where((split == 1)[:, None, None],
+                     gather(pref1, s1, e1), gather(pref0, s0, e0))

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
 
 
 def _rglru_kernel(a_ref, b_ref, h_ref, carry_ref, *, ct: int):
@@ -49,7 +48,7 @@ def rglru_pallas(a, b, *, bc: int = 128, ct: int = 128,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((bsz, t, ch), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
 
 
 def _os_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -89,7 +88,7 @@ def os_gemm(a, b, *, bm, bk, bn, out_dtype, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -114,7 +113,7 @@ def os_gemm_splitk(a, b, *, splits, bm, bk, bn, out_dtype, interpret):
         out_specs=pl.BlockSpec((1, bm, bn), lambda s, i, j, kk: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((splits, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((1, bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -136,7 +135,7 @@ def ws_gemm_partials(a, b, *, bm, bk, bn, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, kk, i: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((k // bk, m, n), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a[None], b[None])
@@ -157,7 +156,7 @@ def is_gemm_partials(a, b, *, bm, bk, bn, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, kk, j: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((k // bk, m, n), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a[None], b[None])

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params as _compiler_params
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *, ct: int):
@@ -61,7 +60,7 @@ def wkv6_pallas(r, k, v, w, u, *, ct: int = 128, interpret: bool = True):
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((g, t, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

@@ -16,12 +16,8 @@ import jax
 
 
 def _mesh_kwargs(n: int) -> dict:
-    """``axis_types`` only exists on newer jax; omit it where unavailable
-    (older versions treat every axis as Auto anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    """Every mesh axis is Auto (compiler-chosen partitioning)."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,8 +22,8 @@ Three-stage pipeline:
    :mod:`repro.core.d2d` including its sorted-BFS tie-breaking.
 3. **Array arithmetic**: tile assignment, prefix gathers and the full
    latency/energy/area/dollar/CFP calculation as ``jax.numpy`` gathers
-   and arithmetic over ``[population, chiplet-slot]`` arrays (float64 via
-   ``jax.experimental.enable_x64``).
+   and arithmetic over ``[population, chiplet-slot]`` arrays (float64 under
+   ``repro.jaxenv.search_numerics``).
 """
 from __future__ import annotations
 
@@ -45,6 +45,7 @@ from repro.core.regions import as_region
 from repro.core.chiplet import Chiplet
 from repro.core.evaluate import Metrics
 from repro.core.scalesim import OPERAND_BYTES, PSUM_BYTES
+from repro.core.seqsum import seq_sum
 from repro.core.techdb import DEFAULT_DB, HOURS_PER_DAY, TechDB
 from repro.core.templates import Normalizer
 from repro.core.workload import DEFAULT_TILE, GEMMWorkload, _partition
@@ -213,7 +214,7 @@ def _lean_place(items, x, y, w, h, vertical, out):
 
 def _lean_floorplan(areas):
     """-> (rect tuples (x, y, w, h) in input order, bbox area)."""
-    total = sum(areas) * (1.0 + 0.10)
+    total = seq_sum(areas) * (1.0 + 0.10)
     side = math.sqrt(total)
     out = [None] * len(areas)
     _lean_place(list(enumerate(areas)), 0.0, 0.0, side, side, True, out)
@@ -450,7 +451,7 @@ class BatchEvaluator:
                     lke.append(ebit25)
                     adj[a].append(b)
                     adj[b].append(a)
-        tot = sum(ar[i] for i in planar)
+        tot = seq_sum(ar[i] for i in planar)
         for i in planar:
             share = memtot * ar[i] / tot
             bw_c.append(i)
@@ -539,7 +540,7 @@ class BatchEvaluator:
             n_bonds = max(0, len(chain) - 1)
             bond_y = bond_y * y3 ** n_bonds
             assembly = assembly + len(chain) * acost * scale3
-            p3_bonded = cfp3 * sum(ar[i] for i in chain[1:])
+            p3_bonded = cfp3 * seq_sum(ar[i] for i in chain[1:])
         return ((bw_c, bw_v), (de_c, de_v), (ho_c, ho_v, ho3_v), (lkbw, lke),
                 (in_l, in_c), bbox, bond_y, assembly, is_interp, cfp25,
                 p3_bonded)
@@ -777,9 +778,9 @@ class BatchEvaluator:
         topo = self._topology(v, areas)
 
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)
             mask = jnp.asarray(nmask)
             cyc, rd, wr = f8(sims["cycles"]), f8(sims["rd"]), f8(sims["wr"])

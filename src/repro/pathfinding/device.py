@@ -36,7 +36,7 @@ module moves the whole explore -> evaluate -> accept loop onto the device:
   draw so a host reference can replay the exact trajectory (the
   trajectory-equivalence tests).
 
-Numerics: everything runs in float64 (``jax.experimental.enable_x64``
+Numerics: everything runs in float64 (:func:`repro.jaxenv.search_numerics`
 scoped to this module's entry points) and replicates the host evaluator's
 operation order wherever floating-point ties matter (greedy floorplan
 accumulation order, Algorithm 1's sorted-order power summation), so the
@@ -52,17 +52,20 @@ jitted path stays within the 1e-6 relative parity contract of the scalar
   ``fold_in``-derived per-cell keys, and optional scenario-axis sharding
   over local devices.
 
-The hottest stage-3 inner loop (prefix-table gather + per-chiplet-slot
-segment reduction) can optionally run through the Pallas kernel in
+The hottest stage-3 inner loop (prefix-table gather + per-slot split-K
+select) can run through the Pallas kernel in
 :mod:`repro.kernels.prefix_gather` (``use_pallas=True`` or
-``REPRO_PATHFINDER_PALLAS=1``; default auto = TPU backends only — on CPU
-the kernel executes in interpreter mode, which is exact but slow).
+``REPRO_PATHFINDER_PALLAS=1``; default auto = TPU backends only). It
+reads a packed int32 hi/lo copy of the int64 prefix tables and is
+bit-equal to the jnp gathers; it compiles on TPU and runs in
+interpreter mode elsewhere (exact but slow).
 
 The scalar fallback (``Pathfinder(device=False)`` or any non-CarbonPATH
 objective backend, e.g. ChipletGym) preserves the PR-1 host path.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import warnings
@@ -153,7 +156,9 @@ class _Cfg:
     sched_col: int                    # first schedule column (window)
     n_sched: int                      # schedule-shape table rows
     sched_live: bool                  # schedule axes searchable
-    use_pallas: bool
+    # (rows, T0 + 1, T1 + 1) of the packed prefix-gather kernel table;
+    # None runs the stage as plain jnp gathers
+    pallas_layout: Optional[Tuple[int, int, int]]
 
 
 def _popcount(x, bits: int):
@@ -168,33 +173,122 @@ def _popcount(x, bits: int):
 # ---------------------------------------------------------------------------
 # Stage 1: Algorithm 1 tile assignment (exact jnp port of batch._assign)
 # ---------------------------------------------------------------------------
+#
+# Algorithm 1 floors ``p / psum * T`` and hands leftover tiles to the
+# largest fractional parts. The compute powers sit in simple ratios, so
+# those shares land exactly on (or an ulp beside) integers and each other
+# all the time, and the result turns on the last bit of IEEE float64
+# rounding. The TPU emulates float64 at ~48 bits and does not round
+# division correctly, which moved tile counts there. So the device
+# reproduces the IEEE float64 operations bit-for-bit in exact int64
+# arithmetic: the powers arrive as integers scaled by a power of two
+# (``_exact_ints``), and every add, divide and multiply below rounds to
+# 53 significant bits, half to even, exactly as the host does. The
+# floorplan's greedy area split (stage 2) compares IEEE sums of chiplet
+# areas the same way.
 
 
-def _assign_jax(powers, nmask, order, total, cfg: _Cfg):
+def _exact_ints(table: np.ndarray) -> np.ndarray:
+    """Non-negative float64 table values as exact int64 multiples of one
+    power of two (the smallest value's last mantissa bit is 1)."""
+    t = np.asarray(table, dtype=np.float64)
+    assert (t >= 0).all()
+    mant, exp = np.frexp(t)  # t = mant * 2**exp, 0.5 <= mant < 1
+    shift = int(53 - exp[t > 0].min())
+    ints = np.ldexp(mant, exp + shift).astype(np.int64)
+    assert (np.ldexp(ints.astype(np.float64), -shift) == t).all()
+    # sums of six values must stay clear of int64 overflow
+    assert ints.max() < 2 ** 59, "table values span too many octaves"
+    return ints
+
+
+def _bit_length(x):
+    """Bit length of non-negative int64 values (0 for 0): how many of
+    2**0 .. 2**62 ``x`` reaches, one compare and one reduce deep (these
+    ops sit on long dependency chains, and the TPU compiler recurses
+    along such chains)."""
+    import jax.numpy as jnp
+
+    pw = jnp.left_shift(jnp.int64(1), jnp.arange(63, dtype=jnp.int64))
+    return jnp.sum(x[..., None] >= pw, axis=-1, dtype=x.dtype)
+
+
+def _round53(x):
+    """Round non-negative int64 values to 53 significant bits, half to
+    even (the value stays an integer)."""
+    import jax.numpy as jnp
+
+    drop = jnp.maximum(_bit_length(x) - 53, 0)
+    q = x >> drop
+    rem = x - (q << drop)
+    half = jnp.where(drop > 0, jnp.left_shift(1, jnp.maximum(drop - 1, 0)),
+                     0).astype(x.dtype)
+    up = (rem > half) | ((rem == half) & (drop > 0) & ((q & 1) == 1))
+    return (q + up) << drop
+
+
+def _div_round(p, s):
+    """Correctly rounded ``p / s`` for int64 ``0 <= p <= s``, ``s > 0``:
+    ``(m, e)`` with value ``m * 2**e`` and ``2**52 <= m < 2**53`` (or
+    ``m == 0``)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    d = _bit_length(s) - _bit_length(p)
+    num = p << d
+    low = num < s
+    num = jnp.where(low, num << 1, num)
+    d = d + low
+
+    def step(_, mr):  # long division, one mantissa bit per step
+        m, r = mr
+        r = r << 1
+        b = r >= s
+        return (m << 1) + b, jnp.where(b, r - s, r)
+
+    m, r = lax.fori_loop(0, 52, step, (jnp.ones_like(p), num - s))
+    r2 = r << 1
+    m = m + ((r2 > s) | ((r2 == s) & ((m & 1) == 1)))
+    carry = m == (1 << 53)
+    m = jnp.where(carry, m >> 1, m)
+    e = -52 - d + carry
+    zero = p == 0
+    return jnp.where(zero, 0, m), jnp.where(zero, 0, e)
+
+
+def _assign_jax(powers_i, nmask, order, total, cfg: _Cfg):
+    """Per-core (start, count) into the canonical tile list, bit-equal to
+    the IEEE float64 ``batch._assign`` (see the section comment).
+    ``powers_i`` are the ``_exact_ints`` of the cores' compute powers."""
     import jax.numpy as jnp
 
     C = cfg.C
-    key = jnp.where((order == 0)[:, None], -powers, powers)
-    key = jnp.where(nmask, key, jnp.inf)  # padding sorts last either way
+    i64 = jnp.int64
+    powers_i = jnp.where(nmask, powers_i, 0).astype(i64)
+    key = jnp.where((order == 0)[:, None], -powers_i, powers_i)
+    key = jnp.where(nmask, key, jnp.iinfo(i64).max)  # padding sorts last
     pos = jnp.argsort(key, axis=1)  # stable
-    p_sorted = jnp.take_along_axis(powers, pos, axis=1)
-    # sequential fold in sorted order: equal-power cores make the
-    # fractional parts ulp-level ties, so summation order is part of the
-    # parity contract with the scalar/np assigner
-    psum = jnp.zeros(powers.shape[0])
+    p_sorted = jnp.take_along_axis(powers_i, pos, axis=1)
+    # sequential fold in sorted order, each partial sum rounded like a
+    # float64 add: equal-power cores make the fractional parts ulp-level
+    # ties, so summation order is part of the parity contract
+    psum = jnp.zeros(powers_i.shape[0], i64)
     for c in range(C):
-        psum = psum + p_sorted[:, c]
-    psum = jnp.where(psum > 0, psum, 1.0)
-    ideal = p_sorted / psum[:, None] * total.astype(jnp.float64)[:, None]
-    counts = jnp.floor(ideal)
-    csum = jnp.zeros_like(psum)
-    for c in range(C):
-        csum = csum + counts[:, c]
-    remaining = (total.astype(jnp.int64) - csum.astype(jnp.int64))
-    frac = ideal - counts
+        psum = _round53(psum + p_sorted[:, c])
+    psum = jnp.where(psum > 0, psum, 1)
+    # ideal = fl(fl(p / psum) * total) = m2 * 2**e
+    m, e = _div_round(p_sorted, psum[:, None])
+    m2 = _round53(m * total.astype(i64)[:, None])
+    sh = -e
+    counts = m2 >> sh
+    frac = m2 - (counts << sh)
+    # fractional parts on one scale (each is < 1, so < 2**62 there)
+    up = jnp.maximum(62 - sh, 0)
+    frac = (frac << up) >> jnp.maximum(sh - 62, 0)
+    remaining = total.astype(i64) - jnp.sum(counts, axis=1)
     frac_pos = jnp.argsort(-frac, axis=1)  # stable
     rank = jnp.argsort(frac_pos, axis=1)   # exact inverse permutation
-    counts_i = counts.astype(jnp.int64) + (rank < remaining[:, None])
+    counts_i = counts + (rank < remaining[:, None])
     starts = jnp.concatenate(
         [jnp.zeros_like(counts_i[:, :1]),
          jnp.cumsum(counts_i[:, :-1], axis=1)], axis=1)
@@ -210,7 +304,11 @@ def _assign_jax(powers, nmask, order, total, cfg: _Cfg):
 # ---------------------------------------------------------------------------
 
 
-def _topology_jax(v, areas, tb, cfg: _Cfg):
+def _topology_jax(v, areas, areas_i, tb, cfg: _Cfg):
+    """``areas`` are the slot areas in float64, ``areas_i`` the same
+    values as :func:`_exact_ints`: every discrete choice on areas (sort
+    orders, the destination die, the floorplan's greedy split) reads the
+    exact integers, so it matches the IEEE float64 host on any backend."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -243,8 +341,9 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
     member = jnp.where(ishyb[:, None], member & active,
                        jnp.where(is3d[:, None], active, False))
     chain_len = member.sum(axis=1).astype(jnp.int32)
+    i64_max = jnp.iinfo(jnp.int64).max
     chain_slots = jnp.argsort(
-        jnp.where(member, -areas, jnp.inf), axis=1).astype(jnp.int32)
+        jnp.where(member, -areas_i, i64_max), axis=1).astype(jnp.int32)
     a_chain = jnp.take_along_axis(areas, chain_slots, axis=1)
     base_slot = chain_slots[:, 0]
     tier = jnp.arange(C)
@@ -267,6 +366,7 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
     m_planar = n_nonmem + ishyb.astype(jnp.int32)
     pvalid = slot[None, :] < m_planar[:, None]
     ar_p = jnp.where(pvalid, jnp.take_along_axis(areas, porder, axis=1), 0.0)
+    ar_pi = jnp.where(pvalid, jnp.take_along_axis(areas_i, porder, axis=1), 0)
 
     # planar-order sequential sums (parity with Python sum())
     tot = jnp.zeros(P)
@@ -280,12 +380,14 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
     # groups are tiny (<= C members), so all per-group accumulation is
     # expressed as pairwise same-group comparisons — pure fusable
     # elementwise chains, no scatters (the dominant cost on CPU)
-    sorder = jnp.argsort(jnp.where(pvalid, -ar_p, jnp.inf),
+    sorder = jnp.argsort(jnp.where(pvalid, -ar_pi, i64_max),
                          axis=1).astype(jnp.int32)
     inv_sorder = jnp.argsort(sorder, axis=1)
     a_s = jnp.take_along_axis(ar_p, sorder, axis=1)       # sorted areas
     v_s = jnp.take_along_axis(pvalid, sorder, axis=1)
     contrib = [jnp.where(v_s[:, t], a_s[:, t], 0.0) for t in range(C)]
+    a_si = jnp.take_along_axis(ar_pi, sorder, axis=1)
+    contrib_i = [jnp.where(v_s[:, t], a_si[:, t], 0) for t in range(C)]
     g = jnp.zeros((P, C), dtype=jnp.int32)
     bx = jnp.zeros((P, C))
     by = jnp.zeros((P, C))
@@ -294,17 +396,24 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
     for level in range(max(C - 1, 1)):
         g_s = jnp.take_along_axis(g, sorder, axis=1)
         # greedy pass in sorted order: left iff al <= ar of the item's
-        # group so far (prefix sums in the exact scalar iteration order)
-        left_s = []
+        # group so far. The running sums are exact integers rounded like
+        # float64 adds in the scalar iteration order: areas whose exact
+        # sums tie are common, and the split turns on the last bit.
+        # ``run[t]`` holds (al, ar) of item t's group after item t; an
+        # item starts from the latest earlier item of its group
+        left_s, run = [], []
+        zero = jnp.zeros(P, jnp.int64)
         for t in range(C):
-            al_t = jnp.zeros(P)
-            ar_t = jnp.zeros(P)
+            al_t, ar_t = zero, zero
             for t2 in range(t):
                 same = g_s[:, t2] == g_s[:, t]
-                al_t = al_t + jnp.where(same & left_s[t2], contrib[t2], 0.0)
-                ar_t = ar_t + jnp.where(same & ~left_s[t2], contrib[t2],
-                                        0.0)
-            left_s.append(al_t <= ar_t)
+                al_t = jnp.where(same, run[t2][0], al_t)
+                ar_t = jnp.where(same, run[t2][1], ar_t)
+            left = al_t <= ar_t
+            grown = _round53(jnp.where(left, al_t, ar_t) + contrib_i[t])
+            run.append((jnp.where(left, grown, al_t),
+                        jnp.where(left, ar_t, grown)))
+            left_s.append(left)
         # final per-group totals / counts, accumulated per original
         # position in the same sorted order as the scalar greedy
         # (skipped other-group items add 0.0, which is exact)
@@ -414,7 +523,7 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
     eff_bw = eff_bw.at[:, 0].set(jnp.where(is2d, memtot, eff_bw[:, 0]))
 
     # -- reduction routes: BFS per source, queue-order tie-breaking --------
-    dest = jnp.argmax(jnp.where(active, areas, -1.0), axis=1
+    dest = jnp.argmax(jnp.where(active, areas_i, -1), axis=1
                       ).astype(jnp.int32)
     INF_I = jnp.int32(10 ** 6)
     eye = jnp.eye(C, dtype=bool)[None]
@@ -497,40 +606,37 @@ def _topology_jax(v, areas, tb, cfg: _Cfg):
 def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
     """Prefix-table gathers for both split-K tables + per-row select.
 
-    With ``cfg.use_pallas`` the whole stage — both split-K gathers for
-    all five sim metrics, the per-row clip to the true tile totals, the
-    split select and the per-slot segment reduction — is one fused
-    Pallas launch (:func:`repro.kernels.prefix_gather.
-    prefix_select_gather`); otherwise plain jnp gathers (the bit-pinned
-    reference path). ``rt`` (the stacked scenario engine's per-cell
-    runtime constants) switches the kernel to the workload-stacked
-    ``[(Wk*A*S*3), T_bucket+1]`` tables: the row index picks up the
-    per-workload offset ``wi*A*S*3`` and the clip bounds come from the
-    traced per-cell tile totals instead of ``cfg``.
+    With ``cfg.pallas_layout`` set, both split-K gathers for all five sim
+    metrics, the per-row clip to the true tile totals and the split
+    select run as one Pallas launch (:func:`repro.kernels.prefix_gather.
+    prefix_select_gather`) over the packed int32 hi/lo table
+    ``tb["pallas_table"]``; its int64 result is bit-equal to the plain
+    jnp gathers of the other branch (the reference path). ``rt`` (the
+    stacked scenario engine's per-cell runtime constants) selects the
+    workload-stacked table: the row index picks up the per-workload
+    offset ``wi*A*S*3`` and the clip bounds come from the traced per-cell
+    tile totals instead of ``cfg``.
     """
     import jax.numpy as jnp
 
     split1 = (v[:, COL_SPLITK] == 1)[:, None]
     sims = {}
-    if cfg.use_pallas:
+    if cfg.pallas_layout is not None:
         from repro.kernels.prefix_gather import prefix_select_gather
 
         P = v.shape[0]
         ridx = ((a_idx * cfg.S + s_idx) * 3 + di).astype(jnp.int32)
         if rt is None:
-            p0f, p1f = tb["pref0_flat"], tb["pref1_flat"]
             t0v = jnp.full((P,), cfg.T0, dtype=jnp.int32)
             t1v = jnp.full((P,), cfg.T1, dtype=jnp.int32)
         else:
-            p0f, p1f = tb["pref0_flatw"], tb["pref1_flatw"]
             ridx = ridx + jnp.int32(cfg.A * cfg.S * 3) * \
                 rt["wi"].astype(jnp.int32)
             t0v = jnp.broadcast_to(rt["T0"].astype(jnp.int32), (P,))
             t1v = jnp.broadcast_to(rt["T1"].astype(jnp.int32), (P,))
-        sel, _ = prefix_select_gather(p0f, p1f, ridx, start, end,
-                                      v[:, COL_SPLITK], t0v, t1v)
-        for fi, f in enumerate(_SIM_METRICS):
-            sims[f] = sel[..., fi]
+        sel = prefix_select_gather(
+            tb["pallas_table"], ridx, start, end, v[:, COL_SPLITK], t0v,
+            t1v, layout=cfg.pallas_layout, nf=len(_SIM_METRICS))
     else:
         s0 = jnp.clip(start, 0, cfg.T0)
         e0 = jnp.clip(end, 0, cfg.T0)
@@ -542,8 +648,8 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
         g0 = t0[a_idx, s_idx, di, e0] - t0[a_idx, s_idx, di, s0]
         g1 = t1[a_idx, s_idx, di, e1] - t1[a_idx, s_idx, di, s1]
         sel = jnp.where(split1[..., None], g1, g0)
-        for fi, f in enumerate(_SIM_METRICS):
-            sims[f] = sel[..., fi]
+    for fi, f in enumerate(_SIM_METRICS):
+        sims[f] = sel[..., fi]
     mn0 = tb["mn0"][jnp.clip(end, 0, cfg.T0)] - tb["mn0"][
         jnp.clip(start, 0, cfg.T0)]
     mn1 = tb["mn1"][jnp.clip(end, 0, cfg.T1)] - tb["mn1"][
@@ -592,9 +698,10 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
 
     cphys = tb["chiplet"][a_idx, t_idx, s_idx]  # [P, C, 4] physicals
     areas = jnp.where(nmask, cphys[:, :, 0], 0.0)
-    dest = jnp.argmax(jnp.where(nmask, areas, -1.0), axis=1)
+    areas_i = jnp.where(nmask, tb["t_area_i"][a_idx, t_idx, s_idx], 0)
+    dest = jnp.argmax(jnp.where(nmask, areas_i, -1), axis=1)
 
-    powers = jnp.where(nmask, tb["t_power"][a_idx, t_idx], 0.0)
+    powers = tb["t_power_i"][a_idx, t_idx]
     split = v[:, COL_SPLITK]
     t0 = cfg.T0 if rt is None else rt["T0"]
     t1 = cfg.T1 if rt is None else rt["T1"]
@@ -605,7 +712,7 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
     sims, mn_bits = _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg,
                                  rt)
 
-    topo = _topology_jax(v, areas, tb, cfg)
+    topo = _topology_jax(v, areas, areas_i, tb, cfg)
 
     f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)  # noqa: E731
     mask = nmask
@@ -1199,9 +1306,13 @@ def trace_count(name: str) -> int:
 
 
 def _base_cfg(sp: DesignSpace, db: TechDB, T0: int, T1: int,
-              wr_bits: float, use_pallas: bool) -> _Cfg:
+              wr_bits: float,
+              pallas_layout: Optional[Tuple[int, int, int]]) -> _Cfg:
     """The static trace-time constants shared by every fused program over
     one (TechDB, DesignSpace) — tile bounds and wr_bits vary per engine."""
+    # the exact tile assignment multiplies a 53-bit share by the tile
+    # count in int64
+    assert max(T0, T1) < 1024, f"tile counts {T0}/{T1} exceed 1023"
     return _Cfg(
         C=sp.max_chiplets, W=sp.width, A=len(sp.arrays),
         T_nodes=len(sp.nodes), S=int(sp.n_sram.max()),
@@ -1235,7 +1346,7 @@ def _base_cfg(sp: DesignSpace, db: TechDB, T0: int, T1: int,
         sched_col=sp.sched_col if sp.schedule == "window" else -1,
         n_sched=sched_mod.n_schedule_shapes(),
         sched_live=sp.sched_live,
-        use_pallas=use_pallas,
+        pallas_layout=pallas_layout,
     )
 
 
@@ -1244,7 +1355,7 @@ def _shared_tables(host, sp: DesignSpace) -> dict:
     memory energies, package info, move tables) — identical for every
     workload and every deployment region over one (db, space), so the
     single-workload evaluator and the stacked scenario engine share the
-    same builder. Call under ``enable_x64``."""
+    same table code. Call under ``search_numerics``."""
     import jax.numpy as jnp
 
     mt = sp.move_tables()
@@ -1260,7 +1371,8 @@ def _shared_tables(host, sp: DesignSpace) -> dict:
             axis=-1)),
         mem3=jnp.asarray(np.stack(
             [host.m_rd, host.m_wr, host.m_cost], axis=-1)),
-        t_power=jnp.asarray(host.t_power),
+        t_power_i=jnp.asarray(_exact_ints(host.t_power)),
+        t_area_i=jnp.asarray(_exact_ints(host.t_area)),
         m_bw=jnp.asarray(host.m_bw),
         p25=jnp.asarray([i[:7] for i in host.p25_info]),
         p25_interp=jnp.asarray([i[7] for i in host.p25_info]),
@@ -1282,7 +1394,7 @@ def _shared_tables(host, sp: DesignSpace) -> dict:
 
 
 def _tile_tables(host) -> dict:
-    """Per-workload prefix-sum tables. Call under ``enable_x64``."""
+    """Per-workload prefix-sum tables. Call under ``search_numerics``."""
     import jax.numpy as jnp
 
     return dict(
@@ -1297,45 +1409,29 @@ def _tile_tables(host) -> dict:
     )
 
 
-def _pallas_tables(host) -> dict:
-    """Flattened [5, (A*S*3), T+1] native-dtype (int64) copies for the
-    Pallas kernel. Interpret mode subtracts in int64 exactly like the
-    jnp reference gathers, so the kernel path is bit-identical on CPU;
-    the compiled TPU path needs rebased float32 tables instead (see the
-    kernel module docstring)."""
+def _pallas_table(hosts, tb0: int, tb1: int):
+    """The packed prefix-gather kernel table for a workload stack:
+    each workload's per-metric ``[5, A*S*3, T+1]`` prefix tables,
+    edge-padded to the shared tile buckets ``tb0``/``tb1`` and stacked
+    along the row axis, so the kernel row is ``((wi*A + a)*S + s)*3 +
+    d``; clip bounds stay at the true (unpadded) per-cell tile totals.
+    A single evaluator is the one-workload stack with unpadded buckets.
+    Returns ``(tables dict, layout)``. Call under ``search_numerics``."""
     import jax.numpy as jnp
 
-    out = {}
-    for sk, name in ((0, "pref0_flat"), (1, "pref1_flat")):
-        pref = np.stack(
-            [host.tiles[sk]["pref"][f] for f in _SIM_METRICS])
-        out[name] = jnp.asarray(
-            pref.reshape(len(_SIM_METRICS), -1, pref.shape[-1]))
-    return out
+    from repro.kernels.prefix_gather import pack_tables
 
-
-def _pallas_stacked_tables(hosts, tb0: int, tb1: int) -> dict:
-    """Workload-stacked flattened ``[5, (Wk*A*S*3), T_bucket+1]`` float64
-    tables for the fused Pallas kernel: each workload's per-metric prefix
-    tables are edge-padded to the shared tile bucket and concatenated
-    along the row axis, so the kernel indexes
-    ``row = ((wi*A + a)*S + s)*3 + d`` with per-cell clip bounds at the
-    true (unpadded) tile totals. Native (int64) dtype like
-    :func:`_pallas_tables`, for bit-exact interpret-mode subtraction.
-    Call under ``enable_x64``."""
-    import jax.numpy as jnp
-
-    out = {}
-    for sk, bucket, name in ((0, tb0, "pref0_flatw"),
-                             (1, tb1, "pref1_flatw")):
+    stacks = []
+    for sk, bucket in ((0, tb0), (1, tb1)):
         mats = []
         for h in hosts:
             pref = np.stack(
                 [h.tiles[sk]["pref"][f] for f in _SIM_METRICS])
             pref = _pad_tiles(pref, bucket, axis=-1)
             mats.append(pref.reshape(pref.shape[0], -1, bucket + 1))
-        out[name] = jnp.asarray(np.concatenate(mats, axis=1))
-    return out
+        stacks.append(np.concatenate(mats, axis=1))
+    table, layout = pack_tables(*stacks)
+    return dict(pallas_table=jnp.asarray(table)), layout
 
 
 # ---------------------------------------------------------------------------
@@ -1428,22 +1524,24 @@ class DeviceEvaluator:
                  space: Optional[DesignSpace] = None,
                  use_pallas: Optional[bool] = None):
         import jax
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
         self.wl, self.db, self.tile_sizes = wl, db, tile_sizes
         host = get_evaluator(wl, db, tile_sizes, space)
         self.host = host
         self.space = host.space
         sp = self.space
-        use_pallas = _resolve_pallas(use_pallas)
-        self.cfg = _base_cfg(
-            sp, db, T0=host.tiles[0]["T"], T1=host.tiles[1]["T"],
-            wr_bits=float(wl.M * wl.N * OPERAND_BYTES * 8),
-            use_pallas=use_pallas)
-        with enable_x64():
+        T0, T1 = host.tiles[0]["T"], host.tiles[1]["T"]
+        layout = None
+        with search_numerics():
             tb = {**_shared_tables(host, sp), **_tile_tables(host)}
-            if use_pallas:
-                tb.update(_pallas_tables(host))
+            if _resolve_pallas(use_pallas):
+                pal, layout = _pallas_table([host], T0, T1)
+                tb.update(pal)
+        self.cfg = _base_cfg(
+            sp, db, T0=T0, T1=T1,
+            wr_bits=float(wl.M * wl.N * OPERAND_BYTES * 8),
+            pallas_layout=layout)
         self.tables = tb
         cfg = self.cfg
         # donate the padded population buffer (no-op on CPU, where XLA
@@ -1480,9 +1578,6 @@ class DeviceEvaluator:
         Pads to a power-of-two bucket (>= 64) so repeated calls of any
         size reuse a handful of compiled programs; the padded buffer is
         donated to the program."""
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-
         mb, cost, _ = self.evaluate_cost_vector(encoded, norm, template)
         return mb, cost
 
@@ -1493,9 +1588,9 @@ class DeviceEvaluator:
         """Fused metrics + cost + ``(latency, dollar, total_cfp)`` vectors
         — all three outputs of one jitted program."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             v, n_real = self._pad(encoded)
             mins, medians = norm.weights_arrays()
             price, embf, profile, pprofile = _db_region_cols(self.db)
@@ -1520,9 +1615,9 @@ class DeviceEvaluator:
         """One vectorized hierarchical move per row (valid rows only)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             v = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
             out = self._propose_jit(jax.random.PRNGKey(seed),
                                     jnp.asarray(v))
@@ -1658,9 +1753,9 @@ class DeviceEvaluator:
         checkpointing)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             v0 = np.atleast_2d(np.asarray(v0, dtype=np.int32))
             n, width = v0.shape
             sweeps = int(sweeps)
@@ -1916,12 +2011,13 @@ class ScenarioEngine:
     Kernel fast path: like :class:`DeviceEvaluator`, the stacked engine
     takes ``use_pallas`` (default: the ``REPRO_PATHFINDER_PALLAS``
     resolution, see :func:`_resolve_pallas`). When enabled, the gather +
-    split-select + segment-reduce stage of every cell's tempering step
-    runs through the fused :func:`repro.kernels.prefix_gather.
-    prefix_select_gather` kernel on workload-stacked flattened tables —
-    its ``custom_vmap`` rule folds the scenario-cell axis into the
-    kernel grid, so the whole ``[S, n]`` population tile is one launch
-    per sweep. The jnp path stays the bit-pinned reference; the same
+    split-select stage of every cell's tempering step runs through the
+    :func:`repro.kernels.prefix_gather.prefix_select_gather` kernel on
+    the workload-stacked packed table — its ``custom_vmap`` rule folds
+    the scenario-cell axis into the kernel's system axis, so the whole
+    ``[S, n]`` population is one launch per sweep (one per device, under
+    ``shard_map``, when the scenario axis is sharded). The jnp path
+    stays the bit-pinned reference; the same
     ``scenario_pt``/``scenario_init`` programs are emitted either way,
     so segmentation, checkpoints and serving replay are unaffected."""
 
@@ -1931,7 +2027,7 @@ class ScenarioEngine:
                  space: Optional[DesignSpace] = None,
                  use_pallas: Optional[bool] = None):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
         self.workloads = tuple(workloads)
         if not self.workloads:
@@ -1945,13 +2041,12 @@ class ScenarioEngine:
         t0s = [h.tiles[0]["T"] for h in hosts]
         t1s = [h.tiles[1]["T"] for h in hosts]
         tb0, tb1 = _tile_bucket(max(t0s)), _tile_bucket(max(t1s))
-        use_pallas = _resolve_pallas(use_pallas)
-        self.cfg = _base_cfg(sp, db, T0=tb0, T1=tb1, wr_bits=0.0,
-                             use_pallas=use_pallas)
-        with enable_x64():
+        layout = None
+        with search_numerics():
             tb = _shared_tables(hosts[0], sp)
-            if use_pallas:
-                tb.update(_pallas_stacked_tables(hosts, tb0, tb1))
+            if _resolve_pallas(use_pallas):
+                pal, layout = _pallas_table(hosts, tb0, tb1)
+                tb.update(pal)
             tb.update(
                 pref0w=jnp.asarray(np.stack([
                     _pad_tiles(np.stack(
@@ -1973,6 +2068,8 @@ class ScenarioEngine:
                     [float(wl.M * wl.N * OPERAND_BYTES * 8)
                      for wl in self.workloads])),
             )
+        self.cfg = _base_cfg(sp, db, T0=tb0, T1=tb1, wr_bits=0.0,
+                             pallas_layout=layout)
         self.tables = tb
         self._fn_cache: Dict[tuple, object] = {}
 
@@ -2062,9 +2159,9 @@ class ScenarioEngine:
         padded to a power-of-two bucket so repeated calls share one
         program."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             v = np.asarray(encoded, dtype=np.int32)
             S, m, _ = v.shape
             mb = max(64, 1 << (m - 1).bit_length())
@@ -2315,9 +2412,9 @@ class ScenarioEngine:
         in place of returning ``.samples``."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             v0 = np.asarray(v0, dtype=np.int32)
             if v0.ndim != 3:
                 raise ValueError(f"v0 must be [S, n, width], got {v0.shape}")
@@ -2492,6 +2589,14 @@ class ScenarioEngine:
             def run_segment(carry, done, seg):
                 fn = self._pt_fn(S, n, seg, int(swap_every),
                                  bool(collect_samples))
+                if mesh is not None:
+                    # re-pin the carry to the scenario sharding: outputs
+                    # may come back laid out otherwise, and a second
+                    # layout would compile the segment program again
+                    from repro.distributed.sharding import shard_scenarios
+
+                    carry = tuple(shard_scenarios(
+                        dict(enumerate(carry)), mesh).values())
                 return fn(*carry, jnp.asarray(st["sweep_done"]), *args)
 
             def absorb(ys, seg):
@@ -2523,16 +2628,20 @@ class ScenarioEngine:
                     feed_cells(*st["seed_block"])
                     st["seed_block"] = None
 
-            carry, _ = run_segmented(
-                sweeps=sweeps, seg_size=seg_size, checkpoint=checkpoint,
-                resume=resume, fingerprint=fp, archives=archives,
-                carry_like=carry_like, fresh=fresh,
-                from_restored=from_restored, run_segment=run_segment,
-                absorb=absorb, carry_np=carry_np,
-                history_np=lambda: np.concatenate(
-                    st["hist_parts"], axis=1),
-                sweep_counter=lambda done: st["sweep_done"],
-                flush_seed=flush_seed)
+            # the mesh in context lets the gather kernel (which XLA
+            # cannot partition) run per device under a shard_map
+            with (jax.set_mesh(mesh) if mesh is not None
+                  else contextlib.nullcontext()):
+                carry, _ = run_segmented(
+                    sweeps=sweeps, seg_size=seg_size,
+                    checkpoint=checkpoint, resume=resume, fingerprint=fp,
+                    archives=archives, carry_like=carry_like, fresh=fresh,
+                    from_restored=from_restored, run_segment=run_segment,
+                    absorb=absorb, carry_np=carry_np,
+                    history_np=lambda: np.concatenate(
+                        st["hist_parts"], axis=1),
+                    sweep_counter=lambda done: st["sweep_done"],
+                    flush_seed=flush_seed)
             hist_parts, seed_block = st["hist_parts"], st["seed_block"]
 
             v_fin, costs_fin, best_v, best_c, _ = carry
@@ -2562,14 +2671,16 @@ _SCENARIO_ENGINE_CACHE_MAX = 4
 def get_scenario_engine(workloads: Sequence[GEMMWorkload],
                         db: TechDB = DEFAULT_DB,
                         tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
-                        space: Optional[DesignSpace] = None
+                        space: Optional[DesignSpace] = None,
+                        use_pallas: Optional[bool] = None
                         ) -> ScenarioEngine:
     """Cached :class:`ScenarioEngine` per (workload tuple, db, tiles,
     chiplet bound) — the stacked twin of :func:`get_device_evaluator`.
 
-    Like that twin, the resolved Pallas setting is part of the key, so
-    flipping ``REPRO_PATHFINDER_PALLAS`` mid-process builds a fresh
-    engine instead of silently returning the cached other-path one.
+    Like that twin, the resolved Pallas setting (``use_pallas``, else
+    :func:`_resolve_pallas`) is part of the key, so flipping
+    ``REPRO_PATHFINDER_PALLAS`` mid-process builds a fresh engine
+    instead of silently returning the cached other-path one.
 
     The db's ``_Cfg``-static lifecycle knobs (``load_profile``,
     ``router_area_frac``) are default-resolved into the key as values:
@@ -2579,7 +2690,7 @@ def get_scenario_engine(workloads: Sequence[GEMMWorkload],
     rest of the db)."""
     from repro.pathfinding.batch import cached_evaluator
 
-    use_pallas = _resolve_pallas(None)
+    use_pallas = _resolve_pallas(use_pallas)
     key = (tuple(workloads), id(db), tile_sizes,
            space.max_chiplets if space is not None else
            DEFAULT_MAX_CHIPLETS, use_pallas,

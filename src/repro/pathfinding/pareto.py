@@ -92,12 +92,12 @@ def non_dominated_mask_jnp(points) -> np.ndarray:
     """Vectorized ``jax.numpy`` non-dominated filter.
 
     Same exact comparisons as :func:`non_dominated_mask` (float64 under
-    ``enable_x64``), so the two agree bit-for-bit on any front. Supports
+    ``search_numerics``), so the two agree bit-for-bit on any front. Supports
     leading batch dimensions: ``[..., n, d] -> [..., n]``."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
-    with enable_x64():
+    with search_numerics():
         p = jnp.asarray(np.asarray(points, dtype=np.float64))
         if p.shape[-2] == 0:
             return np.zeros(p.shape[:-1], dtype=bool)
@@ -637,9 +637,11 @@ def fold_cell_key(base: int, idx: int) -> int:
     seed populations) use this helper."""
     import jax
 
-    folded = jax.random.fold_in(jax.random.PRNGKey(base), idx)
-    key_data = getattr(jax.random, "key_data", None)
-    data = key_data(folded) if key_data is not None else folded
+    from repro.jaxenv import search_numerics
+
+    with search_numerics():
+        folded = jax.random.fold_in(jax.random.PRNGKey(base), idx)
+        data = jax.random.key_data(folded)
     a, b = (int(x) for x in np.ravel(np.asarray(data))[-2:])
     # 63-bit result: folded keys are themselves valid PRNGKey seeds
     return ((a << 32) | b) & 0x7FFF_FFFF_FFFF_FFFF
@@ -740,10 +742,10 @@ class ScenarioSweep:
     ``budget`` is the *total* evaluation budget of the sweep, split
     evenly across cells (``budget // n_cells`` each; the remainder is
     left unspent — previously each cell silently consumed the full
-    budget). ``shard="auto"`` shards the scenario axis over the local
-    devices when more than one exists
-    (:func:`repro.distributed.sharding.scenario_mesh`); ``True`` forces
-    a mesh, ``False`` keeps everything on one device."""
+    budget). ``shard="auto"`` shards the scenario axis over the largest
+    number of local devices that divides the cell count, when that is
+    more than one (:func:`repro.distributed.sharding.scenario_mesh`);
+    ``True`` forces a mesh, ``False`` keeps everything on one device."""
 
     strategy: ScalarizationSweep = dataclasses.field(
         default_factory=lambda: ScalarizationSweep(directions=8,
@@ -762,6 +764,10 @@ class ScenarioSweep:
     # start-hour / duty-shape axes so every cell co-optimizes *when*
     # its designs run against the region's 24h grid profile)
     schedule: Optional[str] = None
+    # gather path of the device engine (None = the
+    # REPRO_PATHFINDER_PALLAS resolution: the Pallas kernel on TPU, plain
+    # jnp gathers elsewhere; True/False pin it)
+    use_pallas: Optional[bool] = None
 
     def run(self, workloads: Union[GEMMWorkload, Sequence[GEMMWorkload],
                                    "ScenarioSpec"],
@@ -884,12 +890,13 @@ class ScenarioSweep:
             results[sc.key] = res
         return ScenarioFrontier(scenarios, results)
 
-    def _mesh(self):
+    def _mesh(self, n_cells: int):
         if self.shard is False:
             return None
         from repro.distributed.sharding import scenario_mesh
 
-        return scenario_mesh(min_devices=1 if self.shard is True else 2)
+        return scenario_mesh(min_devices=1 if self.shard is True else 2,
+                             n_cells=n_cells)
 
     def _run_device(self, cells, workloads, tpl, db, space, norm_of,
                     cell_budget, base, checkpoint_dir=None, resume=True,
@@ -934,14 +941,15 @@ class ScenarioSweep:
                               db, space.max_chiplets)
                 for _ in range(nc)])
             for idx in range(S)])
-        engine = get_scenario_engine(tuple(workloads), db, space=space)
+        engine = get_scenario_engine(tuple(workloads), db, space=space,
+                                     use_pallas=self.use_pallas)
         archives = [ParetoArchive(max_size=strat.frontier_size)
                     for _ in range(S)]
         res = engine.parallel_tempering(
             v0, temps, sweeps, strat.swap_every, seed=base, mins=mins,
             medians=medians, weights=weights, pair_mask=pair, ci=ci,
             widx=widx, price=price, embf=embf, profile=profile,
-            pprofile=pprofile, mesh=self._mesh(), segment=segment,
+            pprofile=pprofile, mesh=self._mesh(S), segment=segment,
             archives=archives, checkpoint=_checkpointer(checkpoint_dir),
             resume=resume)
         # best-by-template per cell: ONE stacked re-evaluation of the

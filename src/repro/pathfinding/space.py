@@ -83,7 +83,7 @@ DEFAULT_MAX_CHIPLETS = 6  # paper Sec V-A chiplet-count bound
 class DesignSpace:
     """Canonical enumeration of the discrete HI space from a TechDB."""
 
-    db: TechDB = DEFAULT_DB
+    db: TechDB = dataclasses.field(default_factory=lambda: DEFAULT_DB)
     max_chiplets: int = DEFAULT_MAX_CHIPLETS
     # Communication model ("legacy" | "mesh_noc"). None resolves through
     # the REPRO_COMM_MODEL env var (default "legacy"). An env-forced

@@ -79,7 +79,7 @@ class Objective:
     wl: GEMMWorkload
     template: Template
     norm: Normalizer
-    db: TechDB = DEFAULT_DB
+    db: TechDB = dataclasses.field(default_factory=lambda: DEFAULT_DB)
     evaluate_fn: object = evaluate          # scalar backend
     cache: SimCache = dataclasses.field(default_factory=SimCache)
     # None -> derived: only the CarbonPATH scalar reference has a

@@ -447,13 +447,13 @@ class PathfinderService:
     def _run_bucket_segment(self, bkey: tuple) -> None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
         from repro.pathfinding.device import _key_from_np, _key_to_np
 
         b = self._buckets[bkey]
         seg = self.segment
-        with enable_x64():
+        with search_numerics():
             fn = b.engine.segment_runner(
                 self.slots, b.nc, seg, b.swap_every, collect_samples=True)
             args = (
@@ -572,7 +572,7 @@ class PathfinderService:
     def _admit(self, job: SearchJob, b: _Bucket, slot: int) -> None:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
         from repro.pathfinding.device import _key_to_np
         from repro.pathfinding.resume import (
@@ -677,7 +677,7 @@ class PathfinderService:
             # job id, so packing cannot change it)
             job.archive = job.archive or self._fresh_archive(job)
             b.v[slot] = v0
-            with enable_x64():
+            with search_numerics():
                 _, cost0, vec0 = b.engine._init_fn(self.slots, nc)(
                     jnp.asarray(b.v), jnp.asarray(b.mins),
                     jnp.asarray(b.med), jnp.asarray(b.w),
@@ -762,9 +762,9 @@ class PathfinderService:
         this replays from the jit cache."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
-        with enable_x64():
+        with search_numerics():
             keys0, cost0, _ = b.engine._init_fn(self.slots, b.nc)(
                 jnp.asarray(b.v), jnp.asarray(b.mins),
                 jnp.asarray(b.med), jnp.asarray(b.w), jnp.asarray(b.ci),
@@ -820,11 +820,11 @@ class PathfinderService:
 
     def _key_np(self, seed: int) -> np.ndarray:
         import jax
-        from jax.experimental import enable_x64
+        from repro.jaxenv import search_numerics
 
         from repro.pathfinding.device import _key_to_np
 
-        with enable_x64():
+        with search_numerics():
             return np.asarray(_key_to_np(jax.random.PRNGKey(seed)))
 
     # -- internals ----------------------------------------------------------

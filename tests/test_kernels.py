@@ -10,8 +10,7 @@ import pytest
 
 from repro.kernels import (
     gemm_ref,
-    prefix_segment_gather,
-    prefix_segment_ref,
+    pack_tables,
     prefix_select_gather,
     prefix_select_ref,
     rglru,
@@ -146,45 +145,69 @@ def test_rglru_identity_decay():
 # ---------------------------------------------------------------------------
 
 
+def _packed(p0, p1):
+    tab, layout = pack_tables(p0, p1)
+    return jnp.asarray(tab), layout
+
+
+def _gather_np(pref, rows, start, end):
+    """Plain numpy ``pref[f, rows, end] - pref[f, rows, start]``."""
+    pref = np.asarray(pref)
+    rows, start, end = (np.asarray(x) for x in (rows, start, end))
+    return (pref[:, rows, end] - pref[:, rows, start]).transpose(1, 2, 0)
+
+
 @pytest.mark.parametrize("shape", [(48, 91, 64, 6), (5, 13, 17, 3)])
 def test_prefix_gather_matches_ref(shape):
-    """Interpreter-mode kernel vs the pure-jnp oracle: bit-exact, the
-    values are prefix-sum differences of exact integers."""
-    from jax.experimental import enable_x64
+    """Interpreter-mode kernel vs the pure-jnp oracle on one split table:
+    bit-exact, the values are prefix-sum differences of exact integers."""
+    from repro.jaxenv import search_numerics
 
     R, T1, P, C = shape
-    with enable_x64():
+    with search_numerics():
         rng = np.random.default_rng(1)
-        pref = jnp.asarray(np.cumsum(
-            rng.integers(0, 10**9, (R, T1)), axis=1).astype(np.float64))
+        pref = np.cumsum(rng.integers(0, 10**9, (5, R, T1)), axis=2)
+        tab, layout = _packed(pref, pref)
         rows = jnp.asarray(rng.integers(0, R, (P, C)).astype(np.int32))
         start = rng.integers(0, T1, (P, C)).astype(np.int32)
         end = np.minimum(start + rng.integers(0, T1, (P, C)),
                          T1 - 1).astype(np.int32)
-        diff, total = prefix_segment_gather(
-            pref, rows, jnp.asarray(start), jnp.asarray(end))
-        diff_r, total_r = prefix_segment_ref(
-            pref, rows, jnp.asarray(start), jnp.asarray(end))
-        assert (np.asarray(diff) == np.asarray(diff_r)).all()
-        assert (np.asarray(total) == np.asarray(total_r)).all()
+        split = jnp.zeros((P,), jnp.int32)
+        tv = jnp.full((P,), T1 - 1, jnp.int32)
+        sel = prefix_select_gather(tab, rows, jnp.asarray(start),
+                                   jnp.asarray(end), split, tv, tv,
+                                   layout=layout)
+        sel_r = prefix_select_ref(jnp.asarray(pref), jnp.asarray(pref),
+                                  rows, jnp.asarray(start),
+                                  jnp.asarray(end), split, tv, tv)
+        assert sel.dtype == jnp.int64
+        assert (np.asarray(sel) == np.asarray(sel_r)).all()
+        assert (np.asarray(sel) == _gather_np(pref, rows, start, end)).all()
 
 
 def test_prefix_gather_int32_path():
-    """The kernel is dtype-generic: int32 tables round-trip exactly."""
-    rng = np.random.default_rng(2)
-    pref = jnp.asarray(np.cumsum(rng.integers(0, 100, (8, 20)),
-                                 axis=1).astype(np.int32))
-    rows = jnp.asarray(rng.integers(0, 8, (16, 4)).astype(np.int32))
-    start = jnp.asarray(np.full((16, 4), 2, dtype=np.int32))
-    end = jnp.asarray(np.full((16, 4), 10, dtype=np.int32))
-    diff, total = prefix_segment_gather(pref, rows, start, end)
-    diff_r, total_r = prefix_segment_ref(pref, rows, start, end)
-    assert (np.asarray(diff) == np.asarray(diff_r)).all()
-    assert (np.asarray(total) == np.asarray(total_r)).all()
+    """The packed int32 hi/lo words reproduce prefix differences far past
+    the int32 (and float32) exact range: values up to ~2^45."""
+    from repro.jaxenv import search_numerics
+
+    with search_numerics():
+        rng = np.random.default_rng(2)
+        pref = np.cumsum(rng.integers(0, 2**40, (5, 8, 33)), axis=2)
+        assert pref.max() > 2**44
+        tab, layout = _packed(pref, pref)
+        rows = jnp.asarray(rng.integers(0, 8, (16, 4)).astype(np.int32))
+        start = np.full((16, 4), 2, dtype=np.int32)
+        end = np.full((16, 4), 30, dtype=np.int32)
+        tv = jnp.full((16,), 32, jnp.int32)
+        sel = prefix_select_gather(tab, rows, jnp.asarray(start),
+                                   jnp.asarray(end),
+                                   jnp.zeros((16,), jnp.int32), tv, tv,
+                                   layout=layout)
+        assert (np.asarray(sel) == _gather_np(pref, rows, start, end)).all()
 
 
 # ---------------------------------------------------------------------------
-# prefix_select (fused stacked gather -> split-select -> segment reduce)
+# prefix_select (fused stacked gather -> split-select)
 # ---------------------------------------------------------------------------
 
 
@@ -201,13 +224,14 @@ def _select_tables(rng, F, R, t0, t1, tb0, tb1):
 def test_prefix_select_matches_ref_t0_ne_t1():
     """Fused kernel vs the jnp oracle with T0 != T1 split tables and
     per-row clip bounds: bit-exact integer prefix differences."""
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
-    with enable_x64():
+    with search_numerics():
         rng = np.random.default_rng(7)
         F, R, P, C = 5, 36, 48, 6
         t0, t1, tb0, tb1 = 37, 81, 64, 128
         p0, p1 = _select_tables(rng, F, R, t0, t1, tb0, tb1)
+        tab, layout = _packed(p0, p1)
         rows = jnp.asarray(rng.integers(0, R, (P, C)).astype(np.int32))
         # bounds deliberately overrun both true totals -> must clip
         start = jnp.asarray(rng.integers(0, tb1, (P, C)).astype(np.int32))
@@ -216,37 +240,33 @@ def test_prefix_select_matches_ref_t0_ne_t1():
         split = jnp.asarray(rng.integers(0, 2, (P,)).astype(np.int32))
         t0v = jnp.full((P,), t0, jnp.int32)
         t1v = jnp.full((P,), t1, jnp.int32)
-        sel, tot = prefix_select_gather(p0, p1, rows, start, end, split,
-                                        t0v, t1v)
-        sel_r, tot_r = prefix_select_ref(p0, p1, rows, start, end, split,
-                                         t0v, t1v)
+        sel = prefix_select_gather(tab, rows, start, end, split, t0v, t1v,
+                                   layout=layout)
+        sel_r = prefix_select_ref(p0, p1, rows, start, end, split, t0v,
+                                  t1v)
         assert (np.asarray(sel) == np.asarray(sel_r)).all()
-        assert (np.asarray(tot) == np.asarray(tot_r)).all()
-        # cross-check against the PR-2 single-table oracle: clip, gather
-        # each split table, select per row
-        for fi in range(F):
-            d0, _ = prefix_segment_ref(p0[fi], rows,
-                                       jnp.clip(start, 0, t0),
-                                       jnp.clip(end, 0, t0))
-            d1, _ = prefix_segment_ref(p1[fi], rows,
-                                       jnp.clip(start, 0, t1),
-                                       jnp.clip(end, 0, t1))
-            want = np.where(np.asarray(split)[:, None] == 1,
-                            np.asarray(d1), np.asarray(d0))
-            assert (np.asarray(sel)[:, :, fi] == want).all()
+        # cross-check against plain numpy: clip, gather each split
+        # table, select per row
+        d0 = _gather_np(p0, rows, np.clip(start, 0, t0),
+                        np.clip(end, 0, t0))
+        d1 = _gather_np(p1, rows, np.clip(start, 0, t1),
+                        np.clip(end, 0, t1))
+        want = np.where(np.asarray(split)[:, None, None] == 1, d1, d0)
+        assert (np.asarray(sel) == want).all()
 
 
 def test_prefix_select_empty_segments_and_padded_rows():
     """Bucket-padding boundaries: start == end slots contribute exactly
     zero, and ranges clipped into the edge-replicated padding match the
     unpadded tables bit-for-bit."""
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
-    with enable_x64():
+    with search_numerics():
         rng = np.random.default_rng(8)
         F, R, P, C = 5, 12, 16, 4
         t0, t1, tb0, tb1 = 19, 23, 64, 64
         p0, p1 = _select_tables(rng, F, R, t0, t1, tb0, tb1)
+        tab, layout = _packed(p0, p1)
         rows = jnp.asarray(rng.integers(0, R, (P, C)).astype(np.int32))
         base = rng.integers(0, tb0 + 1, (P, C)).astype(np.int32)
         start = jnp.asarray(base)
@@ -254,16 +274,15 @@ def test_prefix_select_empty_segments_and_padded_rows():
         split = jnp.asarray(rng.integers(0, 2, (P,)).astype(np.int32))
         t0v = jnp.full((P,), t0, jnp.int32)
         t1v = jnp.full((P,), t1, jnp.int32)
-        sel, tot = prefix_select_gather(p0, p1, rows, start, end, split,
-                                        t0v, t1v)
+        sel = prefix_select_gather(tab, rows, start, end, split, t0v, t1v,
+                                   layout=layout)
         assert (np.asarray(sel) == 0).all()
-        assert (np.asarray(tot) == 0).all()
         # whole-range gathers that overrun into the padded tail equal
         # the true totals of the unpadded tables
         start = jnp.zeros((P, C), jnp.int32)
         end = jnp.full((P, C), tb0, jnp.int32)  # beyond both true totals
-        sel, _ = prefix_select_gather(p0, p1, rows, start, end, split,
-                                      t0v, t1v)
+        sel = prefix_select_gather(tab, rows, start, end, split, t0v, t1v,
+                                   layout=layout)
         pick = np.where(np.asarray(split)[None, :, None] == 1,
                         np.asarray(p1)[:, np.asarray(rows), t1]
                         - np.asarray(p1)[:, np.asarray(rows), 0],
@@ -276,9 +295,9 @@ def test_prefix_select_empty_segments_and_padded_rows():
 def test_prefix_select_two_workload_stack():
     """A 2-workload stack with different true tile counts: rows offset
     by wi*R reproduce each workload's solo gather bit-for-bit."""
-    from jax.experimental import enable_x64
+    from repro.jaxenv import search_numerics
 
-    with enable_x64():
+    with search_numerics():
         rng = np.random.default_rng(9)
         F, R, P, C = 5, 10, 24, 5
         # workload a: 11/17 tiles, workload b: 45/29 -> shared buckets
@@ -286,8 +305,9 @@ def test_prefix_select_two_workload_stack():
         bk0, bk1 = 64, 64
         a0, a1 = _select_tables(rng, F, R, ta0, ta1, bk0, bk1)
         b0, b1 = _select_tables(rng, F, R, tb_0, tb_1, bk0, bk1)
-        s0 = jnp.concatenate([a0, b0], axis=1)  # [F, 2R, bk0+1]
-        s1 = jnp.concatenate([a1, b1], axis=1)
+        stacked_tab, stacked_layout = _packed(
+            jnp.concatenate([a0, b0], axis=1),  # [F, 2R, bk0+1]
+            jnp.concatenate([a1, b1], axis=1))
         rows = jnp.asarray(rng.integers(0, R, (P, C)).astype(np.int32))
         start = jnp.asarray(rng.integers(0, 50, (P, C)).astype(np.int32))
         end = start + jnp.asarray(
@@ -297,23 +317,26 @@ def test_prefix_select_two_workload_stack():
                 [(a0, a1, ta0, ta1), (b0, b1, tb_0, tb_1)]):
             t0v = jnp.full((P,), tt0, jnp.int32)
             t1v = jnp.full((P,), tt1, jnp.int32)
-            solo, _ = prefix_select_gather(w0, w1, rows, start, end,
-                                           split, t0v, t1v)
-            stacked, _ = prefix_select_gather(
-                s0, s1, rows + wi * R, start, end, split, t0v, t1v)
+            tab, layout = _packed(w0, w1)
+            solo = prefix_select_gather(tab, rows, start, end, split, t0v,
+                                        t1v, layout=layout)
+            stacked = prefix_select_gather(
+                stacked_tab, rows + wi * R, start, end, split, t0v, t1v,
+                layout=stacked_layout)
             assert (np.asarray(solo) == np.asarray(stacked)).all()
 
 
 def test_prefix_select_vmap_flattens_cell_axis():
-    """The custom_vmap rule (scenario cells -> kernel grid) matches a
-    per-cell loop bit-for-bit, tables shared across the mapped axis."""
-    from jax.experimental import enable_x64
+    """The custom_vmap rule (scenario cells -> kernel systems) matches a
+    per-cell loop bit-for-bit, the table shared across the mapped axis."""
+    from repro.jaxenv import search_numerics
 
-    with enable_x64():
+    with search_numerics():
         rng = np.random.default_rng(10)
         F, R, P, C, B = 5, 8, 6, 4, 3
         t0, t1 = 21, 13
         p0, p1 = _select_tables(rng, F, R, t0, t1, 64, 64)
+        tab, layout = _packed(p0, p1)
         rows = jnp.asarray(rng.integers(0, R, (B, P, C)).astype(np.int32))
         start = jnp.asarray(
             rng.integers(0, 30, (B, P, C)).astype(np.int32))
@@ -322,14 +345,13 @@ def test_prefix_select_vmap_flattens_cell_axis():
         split = jnp.asarray(rng.integers(0, 2, (B, P)).astype(np.int32))
         t0v = jnp.asarray(rng.integers(1, t0 + 1, (B, P)).astype(np.int32))
         t1v = jnp.asarray(rng.integers(1, t1 + 1, (B, P)).astype(np.int32))
-        sel_v, tot_v = jax.vmap(
+        sel_v = jax.vmap(
             lambda r, s, e, sp, a, b: prefix_select_gather(
-                p0, p1, r, s, e, sp, a, b))(
+                tab, r, s, e, sp, a, b, layout=layout))(
             rows, start, end, split, t0v, t1v)
         assert sel_v.shape == (B, P, C, F)
         for i in range(B):
-            sel_i, tot_i = prefix_select_gather(
-                p0, p1, rows[i], start[i], end[i], split[i], t0v[i],
-                t1v[i])
+            sel_i = prefix_select_gather(
+                tab, rows[i], start[i], end[i], split[i], t0v[i], t1v[i],
+                layout=layout)
             assert (np.asarray(sel_v[i]) == np.asarray(sel_i)).all()
-            assert (np.asarray(tot_v[i]) == np.asarray(tot_i)).all()

@@ -269,3 +269,70 @@ def test_topo_cache_lru_eviction(monkeypatch):
     ev(SPACE.sample(64, key=22))
     assert len(ev._topo_cache) == 8
     assert list(ev._topo_cache) != keys_after_first
+
+
+def test_exact_tile_assignment_matches_float64_host():
+    """The device's integer Algorithm 1 reproduces the host's IEEE
+    float64 tile counts bit-for-bit, ties included: powers drawn from a
+    few table entries put shares on and beside integers all the time."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.jaxenv import search_numerics
+    from repro.pathfinding.batch import get_evaluator
+    from repro.pathfinding.device import _assign_jax, _base_cfg, _exact_ints
+
+    ev = get_evaluator(WL, space=SPACE)
+    cfg = _base_cfg(SPACE, ev.db, 6, 12, 0.0, None)
+    rng = np.random.default_rng(0)
+    P, C = 4000, cfg.C
+    nmask = np.arange(C)[None] < rng.integers(1, C + 1, P)[:, None]
+    a = rng.integers(0, ev.t_power.shape[0], (P, C))
+    t = rng.integers(0, ev.t_power.shape[1], (P, C))
+    same = rng.random((P, C)) < 0.5
+    a, t = np.where(same, a[:, :1], a), np.where(same, t[:, :1], t)
+    order = rng.integers(0, 2, P)
+    total = rng.choice([1, 3, 5, 6, 7, 12, 64, 72, 144], P)
+    start_ref, count_ref = ev._assign(
+        np.where(nmask, ev.t_power[a, t], 0.0), nmask, order,
+        total.astype(np.int64))
+    with search_numerics():
+        start, count = jax.jit(lambda *x: _assign_jax(*x, cfg))(
+            jnp.asarray(_exact_ints(ev.t_power)[a, t]), jnp.asarray(nmask),
+            jnp.asarray(order), jnp.asarray(total))
+    assert (np.asarray(count)[nmask] == count_ref[nmask]).all()
+    assert (np.asarray(start)[nmask] == start_ref[nmask]).all()
+
+
+def test_floorplan_choices_ignore_float_rounding_of_areas(dev):
+    """The slicing floorplan's greedy split, sort orders and destination
+    die read the exact integer areas: float64 areas off by a few ulps (a
+    TPU's emulated float64) leave every route and hop count unchanged.
+    Sums of distinct areas tie exactly often enough that the float
+    comparisons flip on a few rows of this sample."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.jaxenv import search_numerics
+    from repro.pathfinding.device import COL_CHIP, COL_N, _topology_jax
+
+    tb, cfg = dev.tables, dev.cfg
+    P, C = 4000, cfg.C
+    v = SPACE.sample(P, key=3)
+    noise = np.random.default_rng(1).normal(size=(P, C)) * 4e-15
+
+    def topo(v, noise):
+        nmask = jnp.arange(C)[None] < v[:, COL_N][:, None]
+        chip = v[:, COL_CHIP:COL_CHIP + 3 * C].reshape(P, C, 3)
+        a, t, s = [jnp.where(nmask, chip[:, :, k], 0) for k in range(3)]
+        areas = jnp.where(nmask, tb["chiplet"][a, t, s][..., 0], 0.0)
+        areas_i = jnp.where(nmask, tb["t_area_i"][a, t, s], 0)
+        return _topology_jax(v, areas * (1.0 + noise), areas_i, tb, cfg)
+
+    with search_numerics():
+        f = jax.jit(topo)
+        exact = f(jnp.asarray(v), jnp.zeros((P, C)))
+        noisy = f(jnp.asarray(v), jnp.asarray(noise))
+    for k in ("hops", "hops3", "inc", "is2d", "interp"):
+        np.testing.assert_array_equal(np.asarray(noisy[k]),
+                                      np.asarray(exact[k]), err_msg=k)

@@ -328,3 +328,30 @@ def test_scenario_sweep_resume_subprocess_boundary_exit():
         assert set(a.files) == set(b.files)
         for k in a.files:
             np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir_is_fixed(env_dir, tmp_path):
+    """The resumed worker finds its predecessor's programs only if the
+    cache path does not move between runs: ``$JAX_COMPILATION_CACHE_DIR``
+    when set (JAX reads it; the helper sets no path), else ``.jax_cache/``
+    at the checkout root. Checked in a fresh interpreter, so this
+    process's JAX config is untouched and nothing compiles."""
+    from repro.jaxenv import DEFAULT_CACHE_DIR
+
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.abspath(src), env.get("PYTHONPATH", "")])
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; from repro.jaxenv import use_compile_cache; "
+         "p = use_compile_cache(); "
+         "print(p); print(jax.config.jax_compilation_cache_dir)"],
+        env=env, capture_output=True, text=True, check=True)
+    returned, configured = out.stdout.split()[-2:]
+    want = str(tmp_path) if env_dir else str(DEFAULT_CACHE_DIR)
+    assert returned == configured == want
