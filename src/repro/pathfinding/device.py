@@ -103,6 +103,7 @@ from repro.pathfinding.space import (
     S_3D,
     S_HYBRID,
 )
+from repro.tracing import nbytes, span
 
 P_APPLICATION = 0.35  # sa.propose's application-level move probability
 
@@ -1862,12 +1863,18 @@ class DeviceEvaluator:
                 return fn(*carry, np.int64(done), *args)
 
             def absorb(ys, seg):
-                st["history"].extend(np.asarray(ys[0]).tolist())
-                off = 2
+                # copy back only the outputs this call reads
+                off = 4 if collect_samples else 2
+                want = [0] + [2, 3] * collect_samples
+                if record_trace:
+                    want += [*range(off, off + 6), 1]
+                with span("repro.segment.fetch") as sp:
+                    h = {i: np.asarray(ys[i]) for i in want}
+                    sp.set_metadata(bytes=nbytes(h.values()))
+                st["history"].extend(h[0].tolist())
+                rows = 0
                 if collect_samples:
-                    enc_s = np.asarray(ys[off])
-                    vec_s = np.asarray(ys[off + 1])
-                    off += 2
+                    enc_s, vec_s = h[2], h[3]
                     if archive is not None:
                         if st["seed_block"] is not None:
                             enc_s = np.concatenate(
@@ -1880,10 +1887,11 @@ class DeviceEvaluator:
                     else:
                         enc_parts.append(enc_s)
                         vec_parts.append(vec_s)
+                    rows = enc_s.size // width
                 if record_trace:
                     trace_parts.append(
-                        tuple(np.asarray(y) for y in ys[off:off + 6])
-                        + (np.asarray(ys[1]),))
+                        tuple(h[i] for i in range(off, off + 6)) + (h[1],))
+                return rows
 
             def carry_np(carry):
                 return dict(v=np.asarray(carry[0]),
@@ -2485,18 +2493,14 @@ class ScenarioEngine:
 
                 arrays = shard_scenarios(arrays, mesh)
             key0 = jax.random.PRNGKey(seed)
-            args = (jnp.asarray(arrays["temps"]), jnp.asarray(arrays["mins"]),
-                    jnp.asarray(arrays["med"]), jnp.asarray(arrays["w"]),
-                    jnp.asarray(arrays["pair_ok"]),
-                    jnp.asarray(arrays["ci"]), jnp.asarray(arrays["price"]),
-                    jnp.asarray(arrays["embf"]),
-                    jnp.asarray(arrays["profile"]),
-                    jnp.asarray(arrays["pprofile"]),
-                    jnp.asarray(arrays["widx"]))
-            if mesh_comm:
-                args = args + (jnp.asarray(arrays["noc_on"]),)
-            if win_sched:
-                args = args + (jnp.asarray(arrays["sched_on"]),)
+            with span("repro.pt.prepare", cells=S, chains=n):
+                args = tuple(jnp.asarray(arrays[k]) for k in (
+                    "temps", "mins", "med", "w", "pair_ok", "ci", "price",
+                    "embf", "profile", "pprofile", "widx"))
+                if mesh_comm:
+                    args = args + (jnp.asarray(arrays["noc_on"]),)
+                if win_sched:
+                    args = args + (jnp.asarray(arrays["sched_on"]),)
 
             from repro.pathfinding.resume import (
                 run_segmented,
@@ -2600,9 +2604,14 @@ class ScenarioEngine:
                 return fn(*carry, jnp.asarray(st["sweep_done"]), *args)
 
             def absorb(ys, seg):
-                st["hist_parts"].append(np.asarray(ys[0]).T)
+                with span("repro.segment.fetch") as sp:
+                    h = [np.asarray(y) for y in
+                         (ys[:1] + ys[2:4] if collect_samples else ys[:1])]
+                    sp.set_metadata(bytes=nbytes(h))
+                st["hist_parts"].append(h[0].T)
+                rows = 0
                 if collect_samples:
-                    enc_s, vec_s = np.asarray(ys[2]), np.asarray(ys[3])
+                    enc_s, vec_s = h[1], h[2]
                     if st["seed_block"] is not None:
                         enc_s = np.concatenate(
                             [st["seed_block"][0], enc_s])
@@ -2614,7 +2623,9 @@ class ScenarioEngine:
                     else:
                         enc_parts.append(enc_s)
                         vec_parts.append(vec_s)
+                    rows = enc_s.size // width
                 st["sweep_done"] = st["sweep_done"] + seg
+                return rows
 
             def carry_np(carry):
                 return dict(v=np.asarray(carry[0]),

@@ -58,6 +58,7 @@ from repro.core.techdb import DEFAULT_DB, TechDB
 from repro.core.templates import TEMPLATES, Template
 from repro.core.workload import GEMMWorkload
 from repro.pathfinding.space import DesignSpace
+from repro.tracing import span
 
 N_AXES = len(OBJECTIVE_AXES)
 
@@ -265,28 +266,33 @@ class ParetoArchive:
 
     def insert(self, encoded: np.ndarray, vectors: np.ndarray) -> int:
         """Insert a batch; returns the archive size afterwards."""
-        enc = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
-        vec = np.atleast_2d(np.asarray(vectors, dtype=np.float64))
-        if enc.shape[0] != vec.shape[0]:
-            raise ValueError(
-                f"{enc.shape[0]} encodings vs {vec.shape[0]} vectors")
-        if vec.shape[1] != self.n_axes:
-            raise ValueError(
-                f"expected {self.n_axes} axes, got {vec.shape[1]}")
-        if self._enc.shape[1] == 0 and enc.shape[1] > 0:
-            self._enc = np.zeros((0, enc.shape[1]), dtype=np.int32)
-        if enc.shape[1] != self._enc.shape[1]:
-            raise ValueError(
-                f"row width {enc.shape[1]} != archive {self._enc.shape[1]}")
-        for lo in range(0, enc.shape[0], _INSERT_CHUNK):
-            self._insert_chunk(enc[lo:lo + _INSERT_CHUNK],
-                               vec[lo:lo + _INSERT_CHUNK])
+        with span("repro.archive.insert") as sp:
+            enc = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
+            vec = np.atleast_2d(np.asarray(vectors, dtype=np.float64))
+            if enc.shape[0] != vec.shape[0]:
+                raise ValueError(
+                    f"{enc.shape[0]} encodings vs {vec.shape[0]} vectors")
+            if vec.shape[1] != self.n_axes:
+                raise ValueError(
+                    f"expected {self.n_axes} axes, got {vec.shape[1]}")
+            if self._enc.shape[1] == 0 and enc.shape[1] > 0:
+                self._enc = np.zeros((0, enc.shape[1]), dtype=np.int32)
+            if enc.shape[1] != self._enc.shape[1]:
+                raise ValueError(
+                    f"row width {enc.shape[1]} != archive {self._enc.shape[1]}")
+            kept = 0
+            for lo in range(0, enc.shape[0], _INSERT_CHUNK):
+                kept += self._insert_chunk(enc[lo:lo + _INSERT_CHUNK],
+                                           vec[lo:lo + _INSERT_CHUNK])
+            sp.set_metadata(offered=enc.shape[0], prefiltered=kept,
+                            size=len(self))
         return len(self)
 
     def merge(self, other: "ParetoArchive") -> int:
         return self.insert(other._enc, other._vec)
 
-    def _insert_chunk(self, enc: np.ndarray, vec: np.ndarray) -> None:
+    def _insert_chunk(self, enc: np.ndarray, vec: np.ndarray) -> int:
+        """Merge one chunk; returns its rows left after the pre-reduce."""
         if vec.shape[0] > 64:
             # pre-reduce the incoming block alone: dominated rows can
             # never enter the archive, and dropping them first keeps the
@@ -312,6 +318,7 @@ class ParetoArchive:
             keep.sort()
             all_enc, all_vec = all_enc[keep], all_vec[keep]
         self._enc, self._vec = all_enc, all_vec
+        return vec.shape[0]
 
     # -- checkpointing ------------------------------------------------------
     # the repro.checkpoint protocol: archives ride inside checkpoint

@@ -46,9 +46,11 @@ import dataclasses
 import hashlib
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.checkpoint import ELASTIC, CheckpointManager
+from repro.tracing import span
 
 # bump when the checkpoint tree layout changes incompatibly: the version
 # participates in the fingerprint, so old trees are rejected, not
@@ -262,7 +264,7 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
                   fresh: Callable[[], Any],
                   from_restored: Callable[[RestoredSearch], Any],
                   run_segment: Callable[[Any, int, int], Tuple[Any, Any]],
-                  absorb: Callable[[Any, int], None],
+                  absorb: Callable[[Any, int], int],
                   carry_np: Callable[[Any], Dict[str, np.ndarray]],
                   history_np: Callable[[], np.ndarray],
                   sweep_counter: Callable[[int], Union[int, np.ndarray]],
@@ -289,7 +291,10 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
     2. Advance in chunks: ``run_segment(carry, done, seg)`` invokes the
        engine's compiled scan for ``seg = min(seg_size, sweeps - done)``
        sweeps; ``absorb(ys, seg)`` feeds history/archives (including the
-       engine's lazily-prepended seed block).
+       engine's lazily-prepended seed block) and returns the proposal
+       rows it absorbed. Spans (``repro.tracing``) mark the init, each
+       dispatch, each absorb and, inside it, the wait for the segment's
+       outputs.
     3. After every chunk, snapshot ``(sweep_counter(done),
        carry_np(carry), archives, history_np(), fingerprint)``.
     4. ``flush_seed()`` covers the zero-sweep / resumed-complete edge
@@ -302,7 +307,8 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
     if checkpoint is not None and resume:
         restored = checkpoint.restore(carry_like, archives, fingerprint)
     if restored is None:
-        carry = fresh()
+        with span("repro.segment.init"):
+            carry = fresh()
         done = 0
     else:
         carry = from_restored(restored)
@@ -310,8 +316,12 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
         check_not_shrunk(done, sweeps)
     while done < sweeps:
         seg = min(seg_size, sweeps - done)
-        carry, ys = run_segment(carry, done, seg)
-        absorb(ys, seg)
+        with span("repro.segment.dispatch", sweeps=seg):
+            carry, ys = run_segment(carry, done, seg)
+        with span("repro.segment.absorb") as sp:
+            with span("repro.segment.wait"):
+                jax.block_until_ready(ys)
+            sp.set_metadata(rows=absorb(ys, seg))
         done += seg
         if checkpoint is not None:
             checkpoint.save(sweep_counter(done), carry_np(carry),

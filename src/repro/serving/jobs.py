@@ -261,6 +261,12 @@ class SearchJob:
     checkpointer: Optional[object] = None
     result: Optional[JobResult] = None
     error: Optional[BaseException] = None
+    # perf_counter_ns stamps for the trace's spans (host-only, not
+    # checkpointed): submit(), the last entry into the queue (submit or
+    # resume_job), the first admission
+    submitted_ns: int = 0
+    queued_ns: int = 0
+    admitted_ns: Optional[int] = None
 
     @property
     def job_id(self) -> str:

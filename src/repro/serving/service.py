@@ -68,6 +68,7 @@ from repro.serving.jobs import (
     JobState,
     SearchJob,
 )
+from repro.tracing import nbytes, span
 
 
 class _Bucket:
@@ -260,6 +261,7 @@ class PathfinderService:
                 raise ValueError(f"job {spec.job_id!r} is already "
                                  f"{old.state.value}")
             job = SearchJob(spec=spec, widx=self._widx[spec.workload])
+            job.submitted_ns = job.queued_ns = time.perf_counter_ns()
             self._evicted.discard(spec.job_id)
             self._jobs[spec.job_id] = job
             self._queue.append(spec.job_id)
@@ -327,6 +329,7 @@ class PathfinderService:
                                  "not paused")
             job.state = JobState.PENDING
             job.want_pause = False
+            job.queued_ns = time.perf_counter_ns()
             self._queue.append(job.job_id)
             self._cond.notify_all()
 
@@ -412,15 +415,19 @@ class PathfinderService:
         """One scheduling quantum: admit what fits, then advance every
         bucket with live jobs by one segment. Returns whether anything
         happened."""
-        progressed = self._admit_pending()
-        for bkey in list(self._buckets):
-            if self._buckets[bkey].active_slots():
-                self._run_bucket_segment(bkey)
-                progressed = True
-        return progressed
+        with span("repro.service.tick") as sp:
+            admitted = self._admit_pending()
+            buckets = 0
+            for bkey in list(self._buckets):
+                if self._buckets[bkey].active_slots():
+                    self._run_bucket_segment(bkey)
+                    buckets += 1
+            sp.set_metadata(admitted=admitted, buckets=buckets)
+        return admitted + buckets > 0
 
-    def _admit_pending(self) -> bool:
-        admitted = False
+    def _admit_pending(self) -> int:
+        """Admit queued jobs into free slots; returns how many."""
+        admitted = 0
         blocked: set = set()
         for job_id in list(self._queue):
             job = self._jobs[job_id]
@@ -433,14 +440,21 @@ class PathfinderService:
                 blocked.add(bkey)
                 continue
             self._queue.remove(job_id)
-            try:
-                self._admit(job, bucket, slot)
-            except BaseException as e:  # noqa: BLE001 - surfaced via job
-                job.state = JobState.FAILED
-                job.error = e
-                bucket.clear_slot(slot)
-                self._note_terminal(job)
-            admitted = True
+            now = time.perf_counter_ns()
+            first = job.admitted_ns is None
+            if first:
+                job.admitted_ns = now
+            with span("repro.service.admit",
+                      queue_wait_us=(now - job.queued_ns) // 1000,
+                      first=int(first)):
+                try:
+                    self._admit(job, bucket, slot)
+                except BaseException as e:  # noqa: BLE001 - surfaced via job
+                    job.state = JobState.FAILED
+                    job.error = e
+                    bucket.clear_slot(slot)
+                    self._note_terminal(job)
+            admitted += 1
             self._cond.notify_all()
         return admitted
 
@@ -456,39 +470,54 @@ class PathfinderService:
         with search_numerics():
             fn = b.engine.segment_runner(
                 self.slots, b.nc, seg, b.swap_every, collect_samples=True)
-            args = (
-                jnp.asarray(b.v), jnp.asarray(b.costs),
-                jnp.asarray(b.best_v), jnp.asarray(b.best_c),
-                _key_from_np(b.keys, jax.random.PRNGKey(0)),
-                jnp.asarray(b.sweep0), jnp.asarray(b.temps),
-                jnp.asarray(b.mins), jnp.asarray(b.med),
-                jnp.asarray(b.w), jnp.asarray(b.pair),
-                jnp.asarray(b.ci), jnp.asarray(b.price),
-                jnp.asarray(b.embf), jnp.asarray(b.profile),
-                jnp.asarray(b.pprofile), jnp.asarray(b.widx))
-            if b.comm == "mesh_noc":
-                args = args + (jnp.asarray(b.noc_on),)
-            if b.schedule == "window":
-                args = args + (jnp.asarray(b.sched_on),)
-            carry, ys = fn(*args)
-            # np.array (not asarray): device outputs view as read-only
-            # numpy and the slot state is written in place at boundaries
-            b.v = np.array(carry[0])
-            b.costs = np.array(carry[1])
-            b.best_v = np.array(carry[2])
-            b.best_c = np.array(carry[3])
-            b.keys = np.array(_key_to_np(carry[4]))
-        hist = np.asarray(ys[0])          # [seg, S]
-        enc = np.asarray(ys[2])           # [seg, S, nc, width]
-        vec = np.asarray(ys[3])           # [seg, S, nc, 3]
+            with span("repro.service.upload") as sp:
+                args = (
+                    jnp.asarray(b.v), jnp.asarray(b.costs),
+                    jnp.asarray(b.best_v), jnp.asarray(b.best_c),
+                    _key_from_np(b.keys, jax.random.PRNGKey(0)),
+                    jnp.asarray(b.sweep0), jnp.asarray(b.temps),
+                    jnp.asarray(b.mins), jnp.asarray(b.med),
+                    jnp.asarray(b.w), jnp.asarray(b.pair),
+                    jnp.asarray(b.ci), jnp.asarray(b.price),
+                    jnp.asarray(b.embf), jnp.asarray(b.profile),
+                    jnp.asarray(b.pprofile), jnp.asarray(b.widx))
+                if b.comm == "mesh_noc":
+                    args = args + (jnp.asarray(b.noc_on),)
+                if b.schedule == "window":
+                    args = args + (jnp.asarray(b.sched_on),)
+                sp.set_metadata(bytes=nbytes(args))
+            with span("repro.service.dispatch"):
+                carry, ys = fn(*args)
+            with span("repro.service.wait"):
+                jax.block_until_ready((carry, ys))
+            with span("repro.service.fetch") as sp:
+                # np.array (not asarray): device outputs view as
+                # read-only numpy and the slot state is written in place
+                # at boundaries
+                b.v = np.array(carry[0])
+                b.costs = np.array(carry[1])
+                b.best_v = np.array(carry[2])
+                b.best_c = np.array(carry[3])
+                b.keys = np.array(_key_to_np(carry[4]))
+                hist = np.asarray(ys[0])          # [seg, S]
+                enc = np.asarray(ys[2])           # [seg, S, nc, width]
+                vec = np.asarray(ys[3])           # [seg, S, nc, 3]
+                sp.set_metadata(bytes=nbytes((b.v, b.costs, b.best_v,
+                                              b.best_c, b.keys, hist, enc,
+                                              vec)))
         b.sweep0 = b.sweep0 + seg
-        for s in b.active_slots():
-            job = b.slot_jobs[s]
-            job.sweep_done += seg
-            job.history.extend(hist[:, s].tolist())
-            job.archive.insert(enc[:, s].reshape(-1, enc.shape[-1]),
-                               vec[:, s].reshape(-1, vec.shape[-1]))
-            self._boundary(job, b, s)
+        active = b.active_slots()
+        with span("repro.service.boundary", jobs=len(active)) as sp:
+            finished = 0
+            for s in active:
+                job = b.slot_jobs[s]
+                job.sweep_done += seg
+                job.history.extend(hist[:, s].tolist())
+                job.archive.insert(enc[:, s].reshape(-1, enc.shape[-1]),
+                                   vec[:, s].reshape(-1, vec.shape[-1]))
+                self._boundary(job, b, s)
+                finished += job.state is JobState.DONE
+            sp.set_metadata(finished=finished)
         self._cond.notify_all()
 
     def _boundary(self, job: SearchJob, b: _Bucket, s: int) -> None:
@@ -566,6 +595,12 @@ class PathfinderService:
         job.state = JobState.DONE
         b.clear_slot(s)
         self._note_terminal(job)
+        now = time.perf_counter_ns()
+        with span("repro.service.finish",
+                  queue_us=(job.admitted_ns - job.submitted_ns) // 1000,
+                  run_us=(now - job.admitted_ns) // 1000,
+                  segments=job.sweep_done // self.segment):
+            pass
 
     # -- admission ----------------------------------------------------------
 
