@@ -69,6 +69,10 @@ N_AXES = len(OBJECTIVE_AXES)
 # on its own before the merge — search batches are mostly dominated)
 _INSERT_CHUNK = 512
 
+# candidate dominators per pass of the host filter: a pass holds two
+# [block, n] boolean planes (~0.3 MB each at n = 1,088)
+_FILTER_BLOCK = 256
+
 
 # ---------------------------------------------------------------------------
 # Non-dominated filtering: exact host reference + vectorized jnp rendering
@@ -80,13 +84,28 @@ def non_dominated_mask(points: np.ndarray) -> np.ndarray:
 
     Minimization on every axis. Row ``j`` is dominated iff some row ``i``
     is <= on all axes and < on at least one; exact duplicates do not
-    dominate each other (both survive — dedup is the archive's job)."""
+    dominate each other (both survive — dedup is the archive's job).
+
+    The comparisons run one axis at a time on contiguous ``[block, n]``
+    planes (``less_equal.outer`` ANDed, ``less.outer`` ORed) for
+    ``_FILTER_BLOCK`` candidate dominators per pass: no ``[n, n, axes]``
+    temporary and no reduction over a strided axis."""
     p = np.atleast_2d(np.asarray(points, dtype=np.float64))
-    if p.shape[0] == 0:
-        return np.zeros(0, dtype=bool)
-    le = np.all(p[:, None, :] <= p[None, :, :], axis=2)   # i <= j per pair
-    lt = np.any(p[:, None, :] < p[None, :, :], axis=2)    # i < j somewhere
-    return ~(le & lt).any(axis=0)
+    n, d = p.shape
+    dominated = np.zeros(n, dtype=bool)
+    if n < 2 or d == 0:
+        return ~dominated   # no pair to compare
+    cols = p.T
+    for lo in range(0, n, _FILTER_BLOCK):
+        src = cols[:, lo:lo + _FILTER_BLOCK]
+        le = np.less_equal.outer(src[0], cols[0])   # i <= j on every axis
+        lt = np.less.outer(src[0], cols[0])         # i < j on some axis
+        for a in range(1, d):
+            le &= np.less_equal.outer(src[a], cols[a])
+            lt |= np.less.outer(src[a], cols[a])
+        le &= lt
+        dominated |= le.any(axis=0)
+    return ~dominated
 
 
 def non_dominated_mask_jnp(points) -> np.ndarray:

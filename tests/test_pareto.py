@@ -27,6 +27,7 @@ from repro.pathfinding import (
     simplex_directions,
     workloads_from_configs,
 )
+from repro.pathfinding import pareto
 from repro.pathfinding.pareto import (
     FrontierFeed,
     directions_to_weights,
@@ -76,6 +77,57 @@ def test_filter_known_cases():
     assert m.tolist() == [True, False, True, True]
     assert (non_dominated_mask_jnp(pts) == m).all()
     assert non_dominated_mask(np.zeros((0, 3))).shape == (0,)
+    # no axes: no row is better anywhere, so none is dominated
+    assert non_dominated_mask(np.zeros((3, 0))).tolist() == [True] * 3
+
+
+def _broadcast_mask(points):
+    """The host filter as one ``[n, n, axes]`` broadcast reduced over its
+    last axis: the oracle the per-axis planes must match bit for bit."""
+    p = np.atleast_2d(np.asarray(points, dtype=np.float64))
+    if p.shape[0] == 0:
+        return np.zeros(0, dtype=bool)
+    le = np.all(p[:, None, :] <= p[None, :, :], axis=2)
+    lt = np.any(p[:, None, :] < p[None, :, :], axis=2)
+    return ~(le & lt).any(axis=0)
+
+
+def _hard_front(n, d, seed, whole_rows):
+    """A front that exercises every edge of the filter: a trade-off
+    surface with dominated rows behind it, per-axis ties (rounded
+    values), exact duplicates, and inf, -inf, NaN and signed zeros in
+    single cells; ``whole_rows`` also sets whole rows to those values
+    (one -inf row dominates nearly every other row)."""
+    rng = np.random.default_rng(seed)
+    p = rng.dirichlet(np.ones(d), n) if d > 1 else rng.random((n, 1))
+    # centred on 0, so that zeros fall inside the front, not before it
+    p = np.round(p * (1 + 0.5 * rng.exponential(1.0, (n, d))) - 1 / d, 2)
+    if n > 1:
+        p[rng.integers(0, n, n // 8)] = p[rng.integers(0, n, n // 8)]
+    specials = [np.inf, -np.inf, np.nan, 0.0, -0.0]
+    rows = rng.permutation(n)
+    for v, i in zip(specials, rows[:5]):
+        p[i, rng.integers(0, d)] = v
+    for v, i in zip(whole_rows, rows[5:]):
+        p[i] = v
+    return p
+
+
+@pytest.mark.parametrize("n", [0, 1, 63, 64, 65, 512, 1088])
+@pytest.mark.parametrize("d", [1, 2, 3, 4])
+def test_filter_matches_broadcast_oracle(n, d):
+    """The per-axis plane filter gives the broadcast oracle's mask
+    exactly: ties, duplicates, inf and NaN rows, any axis count, and
+    sizes on both sides of the insert's 64-row pre-reduce threshold and
+    the filter's row blocks."""
+    for seed, whole_rows in enumerate([
+            (), (np.inf, np.nan, 0.0, -0.0),
+            (np.inf, -np.inf, np.nan, 0.0, -0.0)]):
+        pts = _hard_front(n, d, 1000 * d + seed, whole_rows)
+        got = non_dominated_mask(pts)
+        want = _broadcast_mask(pts)
+        assert got.dtype == bool and got.shape == (n,)
+        assert np.array_equal(got, want)
 
 
 def test_hypervolume_exact_values():
@@ -191,6 +243,70 @@ def test_archive_backends_agree():
     b.insert(enc, vec)
     assert np.array_equal(a.vectors, b.vectors)
     assert np.array_equal(a.encoded, b.encoded)
+
+
+class _MetaLog:
+    """Stands in for ``repro.tracing.span`` in the archive: keeps each
+    span's name and the stats attached to it."""
+
+    def __init__(self):
+        self.entries = []
+
+    def __call__(self, name):
+        self.entries.append((name, {}))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        self.entries[-1][1].update(stats)
+
+
+def _insert_trail(monkeypatch, mask, batches):
+    """Archive contents after each insert of ``batches``, and the insert
+    spans' stats, with ``mask`` as the archive's host filter."""
+    log = _MetaLog()
+    with monkeypatch.context() as m:
+        m.setattr(pareto, "non_dominated_mask", mask)
+        m.setattr(pareto, "span", log)
+        arch = ParetoArchive(max_size=256)
+        trail = []
+        for enc, vec in batches:
+            arch.insert(enc, vec)
+            trail.append((arch.encoded, arch.vectors))
+    return trail, log.entries
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_archive_inserts_match_broadcast_oracle(monkeypatch, seed):
+    """A study cell's boundaries, 832 rows each (64 chains x 13 rows of
+    41-wide encodings), fed in a row to an archive of 256 whose crowding
+    prune engages: contents and span stats match, insert for insert, an
+    archive filtered by the broadcast oracle."""
+    rng = np.random.default_rng(seed)
+    batches = []
+    for _ in range(5):
+        vec = rng.dirichlet(np.ones(3), 832) * (
+            1 + 0.3 * rng.exponential(1.0, (832, 3)))
+        enc = rng.integers(0, 50, (832, 41)).astype(np.int32)
+        # stalled chains offer a row again
+        again, src = rng.integers(0, 832, (2, 80))
+        vec[again], enc[again] = vec[src], enc[src]
+        batches.append((enc, vec))
+    got, got_log = _insert_trail(monkeypatch, non_dominated_mask, batches)
+    want, want_log = _insert_trail(monkeypatch, _broadcast_mask, batches)
+    for (ge, gv), (we, wv) in zip(got, want):
+        assert np.array_equal(ge, we) and np.array_equal(gv, wv)
+    assert got_log == want_log
+    assert [name for name, _ in got_log] == ["repro.archive.insert"] * 5
+    # the prune engaged: an insert kept more rows than the bound holds
+    sizes = [0] + [st["size"] for _, st in got_log]
+    assert any(size + st["prefiltered"] > 256 and st["size"] == 256
+               for size, (_, st) in zip(sizes, got_log))
 
 
 def test_archive_project_2d_front():
