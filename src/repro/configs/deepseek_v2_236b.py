@@ -25,3 +25,13 @@ CONFIG = ModelConfig(
     first_dense=1,
     dense_d_ff=12288,
 )
+
+
+def serving_step(prefill: int = 512, decode: int = 128, context: int = 32768,
+                 ep: int = 8):
+    """One chunked-prefill serving step on one package of an
+    ``ep``-package expert-parallel group, as a network of GEMMs
+    (:func:`repro.core.network.deepseek_v2_step`)."""
+    from repro.core.network import deepseek_v2_step
+
+    return deepseek_v2_step(CONFIG, prefill, decode, context, ep)

@@ -76,16 +76,43 @@ def package_area_mm2(sys: HISystem, topo: d2d_mod.Topology,
     return topo.floorplan.bbox_area
 
 
-def evaluate(
+@dataclasses.dataclass(frozen=True)
+class GemmTerms:
+    """The per-GEMM terms of one workload on one system (Eq. 5's three
+    latency terms, the dynamic energies, D2D bits and MACs): everything
+    of :func:`evaluate` that depends on the GEMM. A network sums them
+    over its layers (:mod:`repro.core.network`)."""
+
+    l_compute_rd_s: float
+    l_d2d_s: float
+    l_dram_wr_s: float
+    e_compute_j: float
+    e_d2d_j: float
+    d2d_bits: int
+    macs: int
+
+    @property
+    def latency_s(self) -> float:
+        return self.l_compute_rd_s + self.l_d2d_s + self.l_dram_wr_s
+
+    @property
+    def e_dynamic_j(self) -> float:
+        return self.e_compute_j + self.e_d2d_j
+
+
+def gemm_terms(
     sys: HISystem,
     wl: GEMMWorkload,
+    topo: d2d_mod.Topology,
     db: TechDB = DEFAULT_DB,
     tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
     cache: Optional[SimCache] = None,
-) -> Metrics:
+) -> GemmTerms:
+    """Algorithm 1 tiling, the per-chiplet sims, reduction D2D,
+    write-back and the compute and D2D energies of ``wl`` on ``sys``
+    (whose topology is ``topo``)."""
     cache = cache if cache is not None else SimCache()
     assignments = tile_and_assign(wl, sys.chiplets, sys.mapping, tile_sizes, db)
-    topo = d2d_mod.build_topology(sys, db)
     mem = db.memories[sys.memory]
 
     # -- per-chiplet simulation (cached, Sec V-D) ---------------------------
@@ -126,9 +153,7 @@ def evaluate(
             if s.dram_wr_bits:
                 l_wr = max(l_wr, s.dram_wr_bits / topo.effective_dram_bw(i))
 
-    latency = l_cr + d2d.latency_s + l_wr
-
-    # -- energy (Eqs. 12-14) ------------------------------------------------
+    # -- dynamic energy (Eqs. 12-14) ----------------------------------------
     e_compute = 0.0
     e_mem_d2d_pj = 0.0
     for i, (a, s) in enumerate(zip(assignments, sims)):
@@ -141,12 +166,32 @@ def evaluate(
         e_mem_d2d_pj += ((s.dram_rd_bits + s.dram_wr_bits)
                          * topo.dram_path_energy_pj_bit(i))
     e_d2d_pj = d2d.energy_pj + e_mem_d2d_pj
-    e_compute_j = e_compute * 1e-12
-    e_d2d_j = e_d2d_pj * 1e-12
+    return GemmTerms(
+        l_compute_rd_s=l_cr,
+        l_d2d_s=d2d.latency_s,
+        l_dram_wr_s=l_wr,
+        e_compute_j=e_compute * 1e-12,
+        e_d2d_j=e_d2d_pj * 1e-12,
+        d2d_bits=d2d.total_bits,
+        macs=sum(s.macs for s in sims),
+    )
+
+
+def system_metrics(
+    sys: HISystem,
+    topo: d2d_mod.Topology,
+    latency: float,
+    e_dynamic: float,
+    parts: GemmTerms,
+    db: TechDB = DEFAULT_DB,
+) -> Metrics:
+    """The system terms over a run of ``latency`` seconds and
+    ``e_dynamic`` joules: static energy, area, cost, embodied and
+    operational CFP and the bill. ``parts`` fills the component fields."""
     # static power burns for the whole system latency — this is the term
     # through which faster execution lowers energy and operational CFP.
     e_static_j = seq_sum(c.static_power_w(db) for c in sys.chiplets) * latency
-    energy = e_compute_j + e_d2d_j + e_static_j
+    energy = e_dynamic + e_static_j
 
     # -- area, cost, carbon ---------------------------------------------------
     # Regional axes (all default-neutral, see repro.core.carbon): the
@@ -179,11 +224,23 @@ def evaluate(
         dollar=dollar,
         emb_cfp_kg=emb.total * db.emb_factor,
         ope_cfp_kg=ope,
-        l_compute_rd_s=l_cr,
-        l_d2d_s=d2d.latency_s,
-        l_dram_wr_s=l_wr,
-        e_compute_j=e_compute_j,
-        e_d2d_j=e_d2d_j,
-        d2d_bits=d2d.total_bits,
-        macs=sum(s.macs for s in sims),
+        l_compute_rd_s=parts.l_compute_rd_s,
+        l_d2d_s=parts.l_d2d_s,
+        l_dram_wr_s=parts.l_dram_wr_s,
+        e_compute_j=parts.e_compute_j,
+        e_d2d_j=parts.e_d2d_j,
+        d2d_bits=parts.d2d_bits,
+        macs=parts.macs,
     )
+
+
+def evaluate(
+    sys: HISystem,
+    wl: GEMMWorkload,
+    db: TechDB = DEFAULT_DB,
+    tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
+    cache: Optional[SimCache] = None,
+) -> Metrics:
+    topo = d2d_mod.build_topology(sys, db)
+    t = gemm_terms(sys, wl, topo, db, tile_sizes, cache)
+    return system_metrics(sys, topo, t.latency_s, t.e_dynamic_j, t, db)

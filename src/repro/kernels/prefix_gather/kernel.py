@@ -33,6 +33,15 @@ differences are exact even where a partial sum overflows; the caller
 recombines ``dhi * 2**31 + dlo`` in int64, bit-equal to the int64
 reference gather.
 
+Layer-blocked table (:func:`select_groups_grouped`). A network's
+distinct GEMMs each pack to a block of the same ``[rows, 128]`` shape,
+stacked ``[B, rows, 128]``; a whole stack can outgrow VMEM. The systems
+then come in groups, each gathering from one block: group ``g``'s
+systems fill whole grid steps of up to 128, and a scalar-prefetched
+block id per group picks the table block its steps copy in.
+Consecutive steps of one block copy it once. The kernel body is the
+same.
+
 On CPU the same kernel runs in interpreter mode (tests); on TPU it is
 compiled, and interpret mode is never used there.
 """
@@ -146,3 +155,55 @@ def select_groups(table, ge, gs, *, interpret: bool):
         interpret=interpret,
     )(ge, gs, table)
     return out[:N]
+
+
+def _grouped_kernel(gids_ref, ge_ref, gs_ref, tab_ref, out_ref, e_scr, s_scr,
+                    *, nc: int):
+    del gids_ref  # read by the table's index map only
+    row = np.int32(0)  # typed, as in _select_kernel
+    _select_kernel(ge_ref.at[row], gs_ref.at[row], tab_ref, out_ref, e_scr,
+                   s_scr, nc=nc)
+
+
+def select_groups_grouped(table, gids, ge, gs, *, interpret: bool):
+    """``[G, N, 128]`` int32 packed differences: group ``g``'s systems
+    gather ``ge[g] - gs[g]`` from table block ``gids[g]``.
+
+    ``table`` is a layer-blocked ``[B, rows, 128]`` stack of
+    :func:`pack_tables` blocks, ``gids`` ``[G]`` block ids, ``ge``/``gs``
+    ``[G, N, C]`` group indices local to a block (``C <= 8``)."""
+    G, N, C = ge.shape
+    assert C <= GROUPS_PER_ROW, C
+    # a group's systems fill whole blocks of up to 128; the index blocks
+    # are [1, 8 * block] rows of a 3-D operand, whose SMEM tiling takes
+    # any block that spans the whole last two dimensions
+    block = min(BLOCK, -(-N // 8) * 8)
+    n_pad = -(-N // block) * block
+    per = n_pad // block  # grid steps per group
+    pad = ((0, 0), (0, n_pad - N), (0, GROUPS_PER_ROW - C))
+    rows = (G * per, 1, block * GROUPS_PER_ROW)
+    ge = jnp.pad(ge.astype(jnp.int32), pad).reshape(rows)
+    gs = jnp.pad(gs.astype(jnp.int32), pad).reshape(rows)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    # typed: index maps are traced under 64-bit types
+    zero, per_i = np.int32(0), np.int32(per)
+    index = smem((pl.squeezed,) + rows[1:], lambda i, g: (i, zero, zero))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(G * per,),
+        in_specs=[index, index,
+                  pl.BlockSpec((pl.squeezed,) + table.shape[1:],
+                               lambda i, g: (g[i // per_i], zero, zero))],
+        out_specs=pl.BlockSpec((block, LANES), lambda i, g: (i, zero)),
+        scratch_shapes=[pltpu.VMEM((8, LANES), jnp.int32),
+                        pltpu.VMEM((8, LANES), jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, nc=C),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((G * n_pad, LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(gids.astype(jnp.int32), ge, gs, table)
+    return out.reshape(G, n_pad, LANES)[:, :N]

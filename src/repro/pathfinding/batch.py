@@ -44,6 +44,7 @@ from repro.core.carbon import (
 from repro.core.regions import as_region
 from repro.core.chiplet import Chiplet
 from repro.core.evaluate import Metrics
+from repro.core.network import Network, Workload
 from repro.core.scalesim import OPERAND_BYTES, PSUM_BYTES
 from repro.core.seqsum import seq_sum
 from repro.core.techdb import DEFAULT_DB, HOURS_PER_DAY, TechDB
@@ -245,9 +246,14 @@ _SIM_METRICS = ("cycles", "rd", "wr", "sram", "macs")
 
 
 class BatchEvaluator:
-    """Precomputed-table batched evaluator for one (workload, db, tiles)."""
+    """Precomputed-table batched evaluator for one (workload, db, tiles).
 
-    def __init__(self, wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
+    The workload is a GEMM or a :class:`~repro.core.network.Network`: a
+    network's layers each take their own prefix tables, run the per-GEMM
+    stage in turn and are summed with their counts in list order, as
+    :func:`repro.core.network.evaluate_network` does."""
+
+    def __init__(self, wl: Workload, db: TechDB = DEFAULT_DB,
                  tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                  space: Optional[DesignSpace] = None):
         self.wl = wl
@@ -260,7 +266,12 @@ class BatchEvaluator:
         self._build_chiplet_tables()
         self._build_memory_tables()
         self._build_package_info()
-        self._build_tile_tables()
+        if isinstance(wl, Network):
+            by_gemm = {g: self._tile_tables(g) for g in wl.gemms}
+            self.layers = [(g, by_gemm[g], c) for g, c in wl.layers]
+        else:
+            self.tiles = self._tile_tables(wl)
+            self.layers = [(wl, self.tiles, 1)]
 
     # -- table construction -------------------------------------------------
 
@@ -322,12 +333,13 @@ class BatchEvaluator:
         self.p3_hl = np.array([i[8] for i in self.p3_info])
         self.hop_uniform = db.uniform_hop_latency()
 
-    def _build_tile_tables(self) -> None:
+    def _tile_tables(self, wl: GEMMWorkload) -> Dict[int, dict]:
         """Canonical tile lists (Algorithm 1 lines 1-4) and prefix-sum sim
-        tables [array, sram, dataflow, tile+1] for both split-K settings."""
-        wl, db, sp = self.wl, self.db, self.space
+        tables [array, sram, dataflow, tile+1] of ``wl`` for both split-K
+        settings."""
+        db, sp = self.db, self.space
         t_m, t_k, t_n = self.tile_sizes
-        self.tiles: Dict[int, dict] = {}
+        tiles: Dict[int, dict] = {}
         for split_k in (0, 1):
             b_k = min(t_k, max(1, wl.K // 2)) if split_k else wl.K
             ms = _partition(wl.M, t_m)
@@ -355,7 +367,8 @@ class BatchEvaluator:
             width = PSUM_BYTES if partial else OPERAND_BYTES
             mn_pref = np.zeros(T + 1, dtype=np.int64)
             np.cumsum(mv * nv * width * 8, out=mn_pref[1:])
-            self.tiles[split_k] = dict(T=T, pref=pref, mn_pref=mn_pref)
+            tiles[split_k] = dict(T=T, pref=pref, mn_pref=mn_pref)
+        return tiles
 
     # -- Algorithm 1, vectorized --------------------------------------------
 
@@ -716,8 +729,30 @@ class BatchEvaluator:
 
     # -- stage 3: jax.numpy arithmetic over the population ------------------
 
+    def _gather(self, tiles: Dict[int, dict], a_idx, s_idx, df, split,
+                start, end):
+        """Prefix-table gathers of one GEMM's tables: the five sim
+        metrics and the reduction bits per slot, int64 ``[P, C]``."""
+        P, C = a_idx.shape
+        sims = {f: np.zeros((P, C), dtype=np.int64) for f in _SIM_METRICS}
+        mn_bits = np.zeros((P, C), dtype=np.int64)
+        for sk in (0, 1):
+            rows = np.nonzero(split == sk)[0]
+            if not len(rows):
+                continue
+            tab = tiles[sk]
+            ai, si = a_idx[rows], s_idx[rows]
+            di = np.broadcast_to(df[rows, None], ai.shape)
+            st_r, en_r = start[rows], end[rows]
+            for f in _SIM_METRICS:
+                pref = tab["pref"][f]
+                sims[f][rows] = (pref[ai, si, di, en_r]
+                                 - pref[ai, si, di, st_r])
+            mn_bits[rows] = tab["mn_pref"][en_r] - tab["mn_pref"][st_r]
+        return sims, mn_bits
+
     def __call__(self, encoded: np.ndarray) -> MetricsBatch:
-        sp, db, wl = self.space, self.db, self.wl
+        sp, db = self.space, self.db
         v = np.atleast_2d(np.asarray(encoded)).astype(np.int64)
         # pad the population to a power-of-two bucket: every row is
         # computed independently, and stable shapes keep jax's op cache
@@ -752,28 +787,19 @@ class BatchEvaluator:
             noc_h = np.where(nmask, h_tab[mi, ei], 0.0)
             noc_r = np.where(nmask, r_tab[mi], 1.0)
 
-        # Algorithm 1 + prefix-sum gathers of the cached simulations
+        # Algorithm 1 + prefix-sum gathers of the cached simulations, per
+        # layer (a GEMM is the one layer of count 1)
         powers = np.where(nmask, self.t_power[a_idx, t_idx], 0.0)
         split = v[:, COL_SPLITK]
-        total = np.where(split == 1, self.tiles[1]["T"], self.tiles[0]["T"])
-        start, count = self._assign(powers, nmask, v[:, COL_ORDER], total)
-        end = start + count
-        sims = {f: np.zeros((P, C), dtype=np.int64) for f in _SIM_METRICS}
-        mn_bits = np.zeros((P, C), dtype=np.int64)
-        df = v[:, COL_DATAFLOW]
-        for sk in (0, 1):
-            rows = np.nonzero(split == sk)[0]
-            if not len(rows):
-                continue
-            tab = self.tiles[sk]
-            ai, si = a_idx[rows], s_idx[rows]
-            di = np.broadcast_to(df[rows, None], ai.shape)
-            st_r, en_r = start[rows], end[rows]
-            for f in _SIM_METRICS:
-                pref = tab["pref"][f]
-                sims[f][rows] = (pref[ai, si, di, en_r]
-                                 - pref[ai, si, di, st_r])
-            mn_bits[rows] = tab["mn_pref"][en_r] - tab["mn_pref"][st_r]
+        gathered = []
+        for wl, tiles, cnt in self.layers:
+            total = np.where(split == 1, tiles[1]["T"], tiles[0]["T"])
+            start, count = self._assign(powers, nmask, v[:, COL_ORDER],
+                                        total)
+            sims, mn_bits = self._gather(tiles, a_idx, s_idx,
+                                         v[:, COL_DATAFLOW], split, start,
+                                         start + count)
+            gathered.append((wl, cnt, sims, mn_bits))
 
         topo = self._topology(v, areas)
 
@@ -783,23 +809,9 @@ class BatchEvaluator:
         with search_numerics():
             f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)
             mask = jnp.asarray(nmask)
-            cyc, rd, wr = f8(sims["cycles"]), f8(sims["rd"]), f8(sims["wr"])
-            sram_b, macs = f8(sims["sram"]), f8(sims["macs"])
             freq = jnp.where(mask, jnp.take(f8(self.t_freq), t_idx), 1.0)
             eff_bw = f8(topo["eff_bw"])
             den_bw = jnp.where(eff_bw > 0, eff_bw, 1.0)
-
-            # Eq. 5 term 1: max_i (L_compute,i + L_DRAM_RD,i)
-            l_comp = cyc / (freq * 1e9)
-            l_rd = jnp.where(rd > 0, rd / den_bw, 0.0)
-            l_cr = jnp.max(l_comp + l_rd, axis=1)
-
-            # Eq. 5 term 2: reduction-phase D2D over shared links (Fig. 4)
-            sbits = jnp.where(
-                jnp.arange(C)[None, :] == jnp.asarray(dest)[:, None],
-                0.0, f8(mn_bits))
-            loads = jnp.einsum("plc,pc->pl", f8(topo["inc"]), sbits)
-            l_link = jnp.max(loads / f8(topo["link_bw"]), axis=1)
             # per-source hop latency along the reduction path: uniform
             # per-hop latency collapses to the bit-pinned hops * h; mixed
             # protocol latencies split the count by link kind
@@ -817,37 +829,72 @@ class BatchEvaluator:
                     noc_hj, jnp.asarray(dest)[:, None], axis=1)
                 pair_noc = noc_hj + noc_dest
                 path_lat = path_lat + pair_noc * db.noc_hop_latency_s
-            hop_term = jnp.max(jnp.where(sbits > 0, path_lat, 0.0), axis=1)
-            l_d2d = l_link + hop_term
-
-            # Eq. 5 term 3: DRAM write-back (split-K dependent)
             eff_dest = jnp.take_along_axis(
                 eff_bw, jnp.asarray(dest)[:, None], axis=1)[:, 0]
-            wr_split = float(wl.M * wl.N * OPERAND_BYTES * 8) / eff_dest
-            wr_direct = jnp.max(jnp.where(wr > 0, wr / den_bw, 0.0), axis=1)
-            l_wr = jnp.where(jnp.asarray(split) == 1, wr_split, wr_direct)
-            latency = l_cr + l_d2d + l_wr
-
-            # energy (Eqs. 12-14)
             mem_idx = jnp.asarray(v[:, COL_MEM])
             m_rd = jnp.take(f8(self.m_rd), mem_idx)[:, None]
             m_wr = jnp.take(f8(self.m_wr), mem_idx)[:, None]
             sram_e = jnp.take(f8(self.t_sram_e), t_idx)
             mac_e = jnp.take(f8(self.t_mac_e), t_idx)
-            e_comp_pj = jnp.sum(rd * m_rd + wr * m_wr + sram_b * sram_e
-                                + macs * mac_e, axis=1)
-            e_mem_d2d_pj = jnp.sum((rd + wr) * f8(topo["dram_e"]), axis=1)
-            e_link_pj = jnp.sum(loads * f8(topo["link_e"]), axis=1)
-            if mesh_on:
-                # traffic-proportional NoC router energy (per bit-hop)
-                e_link_pj = e_link_pj + (jnp.sum(sbits * pair_noc, axis=1)
-                                         * db.noc_energy_pj_bit)
-            e_compute_j = e_comp_pj * 1e-12
-            e_d2d_j = (e_link_pj + e_mem_d2d_pj) * 1e-12
+
+            def gemm_terms(wl, sims, mn_bits):
+                cyc, rd, wr = (f8(sims["cycles"]), f8(sims["rd"]),
+                               f8(sims["wr"]))
+                sram_b, macs = f8(sims["sram"]), f8(sims["macs"])
+
+                # Eq. 5 term 1: max_i (L_compute,i + L_DRAM_RD,i)
+                l_comp = cyc / (freq * 1e9)
+                l_rd = jnp.where(rd > 0, rd / den_bw, 0.0)
+                l_cr = jnp.max(l_comp + l_rd, axis=1)
+
+                # Eq. 5 term 2: reduction-phase D2D over shared links
+                # (Fig. 4)
+                sbits = jnp.where(
+                    jnp.arange(C)[None, :] == jnp.asarray(dest)[:, None],
+                    0.0, f8(mn_bits))
+                loads = jnp.einsum("plc,pc->pl", f8(topo["inc"]), sbits)
+                l_link = jnp.max(loads / f8(topo["link_bw"]), axis=1)
+                hop_term = jnp.max(jnp.where(sbits > 0, path_lat, 0.0),
+                                   axis=1)
+                l_d2d = l_link + hop_term
+
+                # Eq. 5 term 3: DRAM write-back (split-K dependent)
+                wr_split = float(wl.M * wl.N * OPERAND_BYTES * 8) / eff_dest
+                wr_direct = jnp.max(jnp.where(wr > 0, wr / den_bw, 0.0),
+                                    axis=1)
+                l_wr = jnp.where(jnp.asarray(split) == 1, wr_split,
+                                 wr_direct)
+
+                # dynamic energy (Eqs. 12-14)
+                e_comp_pj = jnp.sum(rd * m_rd + wr * m_wr + sram_b * sram_e
+                                    + macs * mac_e, axis=1)
+                e_mem_d2d_pj = jnp.sum((rd + wr) * f8(topo["dram_e"]),
+                                       axis=1)
+                e_link_pj = jnp.sum(loads * f8(topo["link_e"]), axis=1)
+                if mesh_on:
+                    # traffic-proportional NoC router energy (per bit-hop)
+                    e_link_pj = e_link_pj + (
+                        jnp.sum(sbits * pair_noc, axis=1)
+                        * db.noc_energy_pj_bit)
+                e_compute_j = e_comp_pj * 1e-12
+                e_d2d_j = (e_link_pj + e_mem_d2d_pj) * 1e-12
+                return (l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j,
+                        jnp.sum(loads, axis=1), jnp.sum(macs, axis=1))
+
+            # the layers in list order, each term times its count (a
+            # lone count-1 layer adds to 0.0 exactly)
+            latency = e_dynamic = 0.0
+            parts = [0.0] * 7
+            for wl, cnt, sims, mn_bits in gathered:
+                t = gemm_terms(wl, sims, mn_bits)
+                latency = latency + cnt * (t[0] + t[1] + t[2])
+                e_dynamic = e_dynamic + cnt * (t[3] + t[4])
+                parts = [p + cnt * x for p, x in zip(parts, t)]
+            l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j, d2d_bits, macs = parts
             static_w = jnp.where(
                 mask, f8(self.t_static[a_idx, t_idx, s_idx]), 0.0)
             e_static_j = jnp.sum(static_w, axis=1) * latency
-            energy = e_compute_j + e_d2d_j + e_static_j
+            energy = e_dynamic + e_static_j
 
             # area, dollar cost (Eqs. 15-16)
             area = f8(topo["pkg_area"])
@@ -908,8 +955,7 @@ class BatchEvaluator:
             ope = energy * runs / 3.6e6 * jnp.asarray(eff_ci)
 
             out = [latency, energy, area, dollar, emb, ope, l_cr, l_d2d,
-                   l_wr, e_compute_j, e_d2d_j, jnp.sum(loads, axis=1),
-                   jnp.sum(macs, axis=1)]
+                   l_wr, e_compute_j, e_d2d_j, d2d_bits, macs]
             out = [np.asarray(x)[:n_real] for x in out]
         return MetricsBatch(*out)
 
@@ -970,7 +1016,7 @@ _EVALUATORS: Dict[tuple, Tuple[TechDB, object]] = {}
 _EVALUATOR_CACHE_MAX = 16
 
 
-def evaluator_cache_key(wl: GEMMWorkload, db: TechDB, tile_sizes,
+def evaluator_cache_key(wl: Workload, db: TechDB, tile_sizes,
                         space: Optional[DesignSpace]) -> tuple:
     """Key on the *resolved* chiplet bound so space=None and an
     equivalent default DesignSpace share one evaluator (tables + jax
@@ -1005,7 +1051,7 @@ def cached_evaluator(registry: Dict[tuple, Tuple[TechDB, object]],
     return ev
 
 
-def get_evaluator(wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
+def get_evaluator(wl: Workload, db: TechDB = DEFAULT_DB,
                   tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                   space: Optional[DesignSpace] = None) -> BatchEvaluator:
     return cached_evaluator(
@@ -1014,11 +1060,12 @@ def get_evaluator(wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
         _EVALUATOR_CACHE_MAX)
 
 
-def evaluate_batch(encoded: np.ndarray, wl: GEMMWorkload,
+def evaluate_batch(encoded: np.ndarray, wl: Workload,
                    db: TechDB = DEFAULT_DB,
                    tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                    space: Optional[DesignSpace] = None) -> MetricsBatch:
-    """Batched counterpart of :func:`repro.core.evaluate.evaluate`.
+    """Batched counterpart of :func:`repro.core.evaluate.evaluate` (of
+    :func:`repro.core.network.evaluate_network` for a network).
 
     ``encoded`` is an ``[P, width]`` int array from
     :class:`DesignSpace` (``encode``/``encode_many``/``sample``). Rows
@@ -1039,7 +1086,7 @@ def fit_normalizer_batched(wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
     return Normalizer.fit_arrays(mb.fields())
 
 
-def fit_region_normalizers(wl: GEMMWorkload, regions,
+def fit_region_normalizers(wl: Workload, regions,
                            db: TechDB = DEFAULT_DB,
                            samples: int = 400, seed: int = 1234,
                            space: Optional[DesignSpace] = None,

@@ -50,7 +50,9 @@ jitted path stays within the 1e-6 relative parity contract of the scalar
   whole region x workload :class:`~repro.pathfinding.pareto
   .ScenarioSweep` runs in one ``lax.scan`` with one XLA compile,
   ``fold_in``-derived per-cell keys, and optional scenario-axis sharding
-  over local devices.
+  over local devices. A workload may be a whole network of GEMMs
+  (:mod:`repro.core.network`): its layers ride a ``vmap``-ped layer
+  axis of the same program.
 
 The hottest stage-3 inner loop (prefix-table gather + per-slot split-K
 select) can run through the Pallas kernel in
@@ -67,6 +69,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -79,6 +82,7 @@ from repro.core.carbon import SECONDS_PER_YEAR
 from repro.core.scalesim import OPERAND_BYTES
 from repro.core.techdb import DEFAULT_DB, HOURS_PER_DAY, TechDB
 from repro.core.templates import Normalizer, Template
+from repro.core.network import Network, Workload, as_network
 from repro.core.workload import DEFAULT_TILE, GEMMWorkload
 from repro.pathfinding.batch import (
     MetricsBatch,
@@ -261,6 +265,14 @@ def _assign_jax(powers_i, nmask, order, total, cfg: _Cfg):
     """Per-core (start, count) into the canonical tile list, bit-equal to
     the IEEE float64 ``batch._assign`` (see the section comment).
     ``powers_i`` are the ``_exact_ints`` of the cores' compute powers."""
+    return _assign_counts(*_assign_shares(powers_i, nmask, order, cfg),
+                          total)
+
+
+def _assign_shares(powers_i, nmask, order, cfg: _Cfg):
+    """The design's part of :func:`_assign_jax`, shared by every GEMM of
+    a network: the cores' order and each one's share ``p / psum`` as a
+    53-bit mantissa and exponent."""
     import jax.numpy as jnp
 
     C = cfg.C
@@ -279,6 +291,15 @@ def _assign_jax(powers_i, nmask, order, total, cfg: _Cfg):
     psum = jnp.where(psum > 0, psum, 1)
     # ideal = fl(fl(p / psum) * total) = m2 * 2**e
     m, e = _div_round(p_sorted, psum[:, None])
+    return pos, m, e
+
+
+def _assign_counts(pos, m, e, total):
+    """The GEMM's part of :func:`_assign_jax`: ``total`` tiles dealt by
+    the shares of :func:`_assign_shares`."""
+    import jax.numpy as jnp
+
+    i64 = jnp.int64
     m2 = _round53(m * total.astype(i64)[:, None])
     sh = -e
     counts = m2 >> sh
@@ -617,13 +638,22 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
     workload-stacked table: the row index picks up the per-workload
     offset ``wi*A*S*3`` and the clip bounds come from the traced per-cell
     tile totals instead of ``cfg``.
+
+    A network engine's layer passes ``rt["gid"]``, the layer's GEMM id
+    into the per-GEMM stacks: the jnp gathers index the stacks directly
+    and the kernel reads block ``gid`` of the layer-blocked table
+    ``tb["pallas_blocks"]``.
     """
     import jax.numpy as jnp
 
     split1 = (v[:, COL_SPLITK] == 1)[:, None]
+    gid = None if rt is None else rt.get("gid")
     sims = {}
     if cfg.pallas_layout is not None:
-        from repro.kernels.prefix_gather import prefix_select_gather
+        from repro.kernels.prefix_gather import (
+            prefix_select_gather,
+            prefix_select_gather_blocked,
+        )
 
         P = v.shape[0]
         ridx = ((a_idx * cfg.S + s_idx) * 3 + di).astype(jnp.int32)
@@ -631,13 +661,20 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
             t0v = jnp.full((P,), cfg.T0, dtype=jnp.int32)
             t1v = jnp.full((P,), cfg.T1, dtype=jnp.int32)
         else:
-            ridx = ridx + jnp.int32(cfg.A * cfg.S * 3) * \
-                rt["wi"].astype(jnp.int32)
+            if gid is None:
+                ridx = ridx + jnp.int32(cfg.A * cfg.S * 3) * \
+                    rt["wi"].astype(jnp.int32)
             t0v = jnp.broadcast_to(rt["T0"].astype(jnp.int32), (P,))
             t1v = jnp.broadcast_to(rt["T1"].astype(jnp.int32), (P,))
-        sel = prefix_select_gather(
-            tb["pallas_table"], ridx, start, end, v[:, COL_SPLITK], t0v,
-            t1v, layout=cfg.pallas_layout, nf=len(_SIM_METRICS))
+        if gid is None:
+            sel = prefix_select_gather(
+                tb["pallas_table"], ridx, start, end, v[:, COL_SPLITK],
+                t0v, t1v, layout=cfg.pallas_layout, nf=len(_SIM_METRICS))
+        else:
+            sel = prefix_select_gather_blocked(
+                tb["pallas_blocks"], gid, ridx, start, end,
+                v[:, COL_SPLITK], t0v, t1v, layout=cfg.pallas_layout,
+                nf=len(_SIM_METRICS))
     else:
         s0 = jnp.clip(start, 0, cfg.T0)
         e0 = jnp.clip(end, 0, cfg.T0)
@@ -645,18 +682,176 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
         e1 = jnp.clip(end, 0, cfg.T1)
         # tables carry the 5 sim metrics in the trailing axis, so each
         # (split, bound) pair is a single gather of [P, C, 5]
-        t0, t1 = tb["pref0"], tb["pref1"]
-        g0 = t0[a_idx, s_idx, di, e0] - t0[a_idx, s_idx, di, s0]
-        g1 = t1[a_idx, s_idx, di, e1] - t1[a_idx, s_idx, di, s1]
+        if gid is None:
+            t0, t1 = tb["pref0"], tb["pref1"]
+            g0 = t0[a_idx, s_idx, di, e0] - t0[a_idx, s_idx, di, s0]
+            g1 = t1[a_idx, s_idx, di, e1] - t1[a_idx, s_idx, di, s1]
+        else:
+            t0, t1 = tb["pref0w"], tb["pref1w"]
+            g0 = (t0[gid, a_idx, s_idx, di, e0]
+                  - t0[gid, a_idx, s_idx, di, s0])
+            g1 = (t1[gid, a_idx, s_idx, di, e1]
+                  - t1[gid, a_idx, s_idx, di, s1])
         sel = jnp.where(split1[..., None], g1, g0)
     for fi, f in enumerate(_SIM_METRICS):
         sims[f] = sel[..., fi]
-    mn0 = tb["mn0"][jnp.clip(end, 0, cfg.T0)] - tb["mn0"][
-        jnp.clip(start, 0, cfg.T0)]
-    mn1 = tb["mn1"][jnp.clip(end, 0, cfg.T1)] - tb["mn1"][
-        jnp.clip(start, 0, cfg.T1)]
+    if gid is None:
+        mn0t, mn1t = tb["mn0"], tb["mn1"]
+    else:
+        mn0t, mn1t = tb["mn0w"][gid], tb["mn1w"][gid]
+    mn0 = mn0t[jnp.clip(end, 0, cfg.T0)] - mn0t[jnp.clip(start, 0, cfg.T0)]
+    mn1 = mn1t[jnp.clip(end, 0, cfg.T1)] - mn1t[jnp.clip(start, 0, cfg.T1)]
     mn_bits = jnp.where(split1, mn1, mn0)
     return sims, mn_bits
+
+
+class _Design:
+    """The design's own arrays that the per-GEMM terms read (node rates,
+    DRAM bandwidths, reduction paths, memory energies), each computed
+    where it is first read: a network computes them once for all its
+    layers, and a single GEMM's program keeps one straight order."""
+
+    def __init__(self, v, tb, cfg: _Cfg, slot, nmask, t_idx, dest, topo):
+        self.v, self.tb, self.cfg, self.slot = v, tb, cfg, slot
+        self.nmask, self.t_idx, self.dest, self.topo = (nmask, t_idx, dest,
+                                                         topo)
+
+    @functools.cached_property
+    def nphys(self):  # [P, C, 4] node-scaled rates
+        return self.tb["node"][self.t_idx]
+
+    @functools.cached_property
+    def freq(self):
+        import jax.numpy as jnp
+
+        return jnp.where(self.nmask, self.nphys[:, :, 0], 1.0)
+
+    @functools.cached_property
+    def den_bw(self):
+        import jax.numpy as jnp
+
+        eff_bw = self.topo["eff_bw"]
+        return jnp.where(eff_bw > 0, eff_bw, 1.0)
+
+    @functools.cached_property
+    def is_dest(self):
+        return self.slot[None, :] == self.dest[:, None]
+
+    @functools.cached_property
+    def noc(self):
+        """Per-slot NoC hop counts and router counts (mesh_noc)."""
+        import jax.numpy as jnp
+
+        C, v, nmask = self.cfg.C, self.v, self.nmask
+        nocv = v[:, self.cfg.noc_col:self.cfg.noc_col + 2 * C].reshape(
+            v.shape[0], C, 2)
+        mi = jnp.where(nmask, nocv[:, :, 0], 0)
+        ei = jnp.where(nmask, nocv[:, :, 1], 0)
+        return (jnp.where(nmask, self.tb["noc_hops"][mi, ei], 0.0),
+                jnp.where(nmask, self.tb["noc_routers"][mi], 1.0))
+
+    @functools.cached_property
+    def paths(self):
+        """(path latency per source slot, src + dest NoC hops or None)."""
+        import jax.numpy as jnp
+
+        cfg, tb, v, topo = self.cfg, self.tb, self.v, self.topo
+        f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)  # noqa: E731
+        # per-source path latency: package hops x per-hop latency. With a
+        # uniform hop latency the product commutes with the masked max
+        # bit-exactly (h > 0 is monotone and the winning element is the
+        # same), so the legacy hops * HOP_LATENCY_S program is reproduced
+        # verbatim; heterogeneous protocol latencies split the hop count
+        # by link kind (2.5D plane vs 3D bond) instead.
+        mesh_on = cfg.comm == "mesh_noc"
+        if mesh_on:
+            noc_h, _ = self.noc
+        if cfg.hop_uniform is not None:
+            path_lat = f8(topo["hops"]) * cfg.hop_uniform
+        else:
+            h25 = tb["p25_hl"][jnp.maximum(v[:, COL_PAIR25], 0)]
+            h3 = tb["p3_hl"][jnp.maximum(v[:, COL_PAIR3], 0)]
+            path_lat = (f8(topo["hops"] - topo["hops3"]) * h25[:, None]
+                        + f8(topo["hops3"]) * h3[:, None])
+        if not mesh_on:
+            return path_lat, None
+        # on-chiplet mesh traversal: source egress + destination ingress
+        # mean hop counts (closed-form Manhattan distances to the NoI
+        # entry router), per NoC hop latency
+        noc_dest = jnp.take_along_axis(noc_h, self.dest[:, None], axis=1)
+        pair_noc = noc_h + noc_dest
+        return path_lat + pair_noc * cfg.noc_hop_latency_s, pair_noc
+
+    @functools.cached_property
+    def eff_dest(self):
+        import jax.numpy as jnp
+
+        return jnp.take_along_axis(self.topo["eff_bw"], self.dest[:, None],
+                                   axis=1)[:, 0]
+
+    @functools.cached_property
+    def mrow(self):  # [P, 3]: rd/wr energy + cost
+        import jax.numpy as jnp
+
+        return self.tb["mem3"][jnp.clip(self.v[:, COL_MEM], 0,
+                                        self.cfg.M - 1)]
+
+    def all(self) -> "_Design":
+        for name in ("nphys", "freq", "den_bw", "is_dest", "paths",
+                     "eff_dest", "mrow"):
+            getattr(self, name)
+        return self
+
+
+def _gemm_terms_jax(sims, mn_bits, split, wr_bits, d: _Design, cfg: _Cfg):
+    """The per-GEMM terms of one workload from its gathered sims, each
+    ``[P]`` but the last two: Eq. 5's latency and its three terms
+    (``l_cr``, ``l_d2d``, ``l_wr``), the compute and D2D energies (J),
+    and the per-link reduction loads ``[P, L]`` and per-slot MACs
+    ``[P, C]``."""
+    import jax.numpy as jnp
+
+    topo = d.topo
+    f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)  # noqa: E731
+    cyc, rd, wr = f8(sims["cycles"]), f8(sims["rd"]), f8(sims["wr"])
+    sram_b, macs = f8(sims["sram"]), f8(sims["macs"])
+    freq, den_bw = d.freq, d.den_bw
+
+    # Eq. 5 term 1: max_i (L_compute,i + L_DRAM_RD,i)
+    l_comp = cyc / (freq * 1e9)
+    l_rd = jnp.where(rd > 0, rd / den_bw, 0.0)
+    l_cr = jnp.max(l_comp + l_rd, axis=1)
+
+    # Eq. 5 term 2: reduction-phase D2D over shared links (Fig. 4)
+    sbits = jnp.where(d.is_dest, 0.0, f8(mn_bits))
+    loads = jnp.einsum("plc,pc->pl", topo["inc"], sbits)
+    l_link = jnp.max(loads / topo["link_bw"], axis=1)
+    path_lat, pair_noc = d.paths
+    hop_term = jnp.max(jnp.where(sbits > 0, path_lat, 0.0), axis=1)
+    l_d2d = l_link + hop_term
+
+    # Eq. 5 term 3: DRAM write-back (split-K dependent)
+    wr_split = wr_bits / d.eff_dest
+    wr_direct = jnp.max(jnp.where(wr > 0, wr / den_bw, 0.0), axis=1)
+    l_wr = jnp.where(split == 1, wr_split, wr_direct)
+    latency = l_cr + l_d2d + l_wr
+
+    # dynamic energy (Eqs. 12-14)
+    m_rd = d.mrow[:, 0][:, None]
+    m_wr = d.mrow[:, 1][:, None]
+    sram_e = d.nphys[:, :, 1]
+    mac_e = d.nphys[:, :, 2]
+    e_comp_pj = jnp.sum(rd * m_rd + wr * m_wr + sram_b * sram_e
+                        + macs * mac_e, axis=1)
+    e_mem_d2d_pj = jnp.sum((rd + wr) * topo["dram_e"], axis=1)
+    e_link_pj = jnp.sum(loads * topo["link_e"], axis=1)
+    if pair_noc is not None:
+        # NoC traversal energy: routed reduction bits x mesh hops x pJ/bit
+        e_link_pj = e_link_pj + (jnp.sum(sbits * pair_noc, axis=1)
+                                 * cfg.noc_energy_pj_bit)
+    e_compute_j = e_comp_pj * 1e-12
+    e_d2d_j = (e_link_pj + e_mem_d2d_pj) * 1e-12
+    return latency, l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j, loads, macs
 
 
 def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
@@ -684,7 +879,17 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
     overrides the per-workload compile-time constants (``T0``/``T1``
     tile totals, ``wr_bits``) with traced values — the stacked scenario
     engine's workload axis; ``cfg.T0``/``cfg.T1`` then only bound the
-    (padded) prefix-table gathers."""
+    (padded) prefix-table gathers.
+
+    A network engine's ``rt`` holds the cell's layer table instead:
+    ``gid`` (``[L]`` GEMM ids into the per-GEMM stacks) and ``cnt``
+    (``[L]`` counts, 0 on padding). The per-GEMM stage (Algorithm 1's
+    deal, the gathers, the per-GEMM terms) then runs over the layers as
+    a ``vmap``-ped axis, the design's stages (topology, floorplan,
+    static power, package) once, and the terms are summed with their
+    counts in list order (:func:`repro.core.network.evaluate_network`).
+    """
+    import jax
     import jax.numpy as jnp
 
     C = cfg.C
@@ -704,93 +909,54 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
 
     powers = tb["t_power_i"][a_idx, t_idx]
     split = v[:, COL_SPLITK]
-    t0 = cfg.T0 if rt is None else rt["T0"]
-    t1 = cfg.T1 if rt is None else rt["T1"]
-    total = jnp.where(split == 1, t1, t0)
-    start, count = _assign_jax(powers, nmask, v[:, COL_ORDER], total, cfg)
-    end = start + count
-    di = jnp.broadcast_to(v[:, COL_DATAFLOW][:, None], (P, C))
-    sims, mn_bits = _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg,
-                                 rt)
+    network = rt is not None and "gid" in rt
+
+    def gemm_sims(rt_g, shares=None):
+        t0 = cfg.T0 if rt_g is None else rt_g["T0"]
+        t1 = cfg.T1 if rt_g is None else rt_g["T1"]
+        total = jnp.where(split == 1, t1, t0)
+        if shares is None:
+            shares = _assign_shares(powers, nmask, v[:, COL_ORDER], cfg)
+        start, count = _assign_counts(*shares, total)
+        end = start + count
+        di = jnp.broadcast_to(v[:, COL_DATAFLOW][:, None], (P, C))
+        return _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg, rt_g)
+
+    if network:
+        shares = _assign_shares(powers, nmask, v[:, COL_ORDER], cfg)
+    else:
+        sims, mn_bits = gemm_sims(rt)
 
     topo = _topology_jax(v, areas, areas_i, tb, cfg)
-
-    f8 = lambda x: jnp.asarray(x, dtype=jnp.float64)  # noqa: E731
+    d = _Design(v, tb, cfg, slot, nmask, t_idx, dest, topo)
     mask = nmask
-    cyc, rd, wr = f8(sims["cycles"]), f8(sims["rd"]), f8(sims["wr"])
-    sram_b, macs = f8(sims["sram"]), f8(sims["macs"])
-    nphys = tb["node"][t_idx]  # [P, C, 4] node-scaled rates
-    freq = jnp.where(mask, nphys[:, :, 0], 1.0)
-    eff_bw = topo["eff_bw"]
-    den_bw = jnp.where(eff_bw > 0, eff_bw, 1.0)
 
-    # Eq. 5 term 1: max_i (L_compute,i + L_DRAM_RD,i)
-    l_comp = cyc / (freq * 1e9)
-    l_rd = jnp.where(rd > 0, rd / den_bw, 0.0)
-    l_cr = jnp.max(l_comp + l_rd, axis=1)
-
-    # Eq. 5 term 2: reduction-phase D2D over shared links (Fig. 4)
-    sbits = jnp.where(slot[None, :] == dest[:, None], 0.0, f8(mn_bits))
-    loads = jnp.einsum("plc,pc->pl", topo["inc"], sbits)
-    l_link = jnp.max(loads / topo["link_bw"], axis=1)
-    # per-source path latency: package hops x per-hop latency. With a
-    # uniform hop latency the product commutes with the masked max
-    # bit-exactly (h > 0 is monotone and the winning element is the
-    # same), so the legacy hops * HOP_LATENCY_S program is reproduced
-    # verbatim; heterogeneous protocol latencies split the hop count by
-    # link kind (2.5D plane vs 3D bond) instead.
-    mesh_on = cfg.comm == "mesh_noc"
-    if mesh_on:
-        nocv = v[:, cfg.noc_col:cfg.noc_col + 2 * C].reshape(P, C, 2)
-        mi = jnp.where(nmask, nocv[:, :, 0], 0)
-        ei = jnp.where(nmask, nocv[:, :, 1], 0)
-        noc_h = jnp.where(nmask, tb["noc_hops"][mi, ei], 0.0)
-        noc_r = jnp.where(nmask, tb["noc_routers"][mi], 1.0)
-    if cfg.hop_uniform is not None:
-        path_lat = f8(topo["hops"]) * cfg.hop_uniform
+    if not network:
+        wr_bits = cfg.wr_bits if rt is None else rt["wr_bits"]
+        (latency, l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j, loads,
+         macs) = _gemm_terms_jax(sims, mn_bits, split, wr_bits, d, cfg)
     else:
-        h25 = tb["p25_hl"][jnp.maximum(v[:, COL_PAIR25], 0)]
-        h3 = tb["p3_hl"][jnp.maximum(v[:, COL_PAIR3], 0)]
-        path_lat = (f8(topo["hops"] - topo["hops3"]) * h25[:, None]
-                    + f8(topo["hops3"]) * h3[:, None])
-    if mesh_on:
-        # on-chiplet mesh traversal: source egress + destination ingress
-        # mean hop counts (closed-form Manhattan distances to the NoI
-        # entry router), per NoC hop latency
-        noc_dest = jnp.take_along_axis(noc_h, dest[:, None], axis=1)
-        pair_noc = noc_h + noc_dest
-        path_lat = path_lat + pair_noc * cfg.noc_hop_latency_s
-    hop_term = jnp.max(jnp.where(sbits > 0, path_lat, 0.0), axis=1)
-    l_d2d = l_link + hop_term
+        d.all()  # the design's arrays, once for every layer
 
-    # Eq. 5 term 3: DRAM write-back (split-K dependent)
-    eff_dest = jnp.take_along_axis(eff_bw, dest[:, None], axis=1)[:, 0]
-    wr_bits = cfg.wr_bits if rt is None else rt["wr_bits"]
-    wr_split = wr_bits / eff_dest
-    wr_direct = jnp.max(jnp.where(wr > 0, wr / den_bw, 0.0), axis=1)
-    l_wr = jnp.where(split == 1, wr_split, wr_direct)
-    latency = l_cr + l_d2d + l_wr
+        def layer(gid):
+            rt_g = dict(T0=tb["t0w"][gid], T1=tb["t1w"][gid], gid=gid)
+            t = _gemm_terms_jax(*gemm_sims(rt_g, shares), split,
+                                tb["wrw"][gid], d, cfg)
+            return (t[0], t[4] + t[5]) + t[1:6] + (
+                jnp.sum(t[6], axis=1), jnp.sum(t[7], axis=1))
 
-    # energy (Eqs. 12-14)
-    mem_idx = jnp.clip(v[:, COL_MEM], 0, cfg.M - 1)
-    mrow = tb["mem3"][mem_idx]  # [P, 3]: rd/wr energy + cost
-    m_rd = mrow[:, 0][:, None]
-    m_wr = mrow[:, 1][:, None]
-    sram_e = nphys[:, :, 1]
-    mac_e = nphys[:, :, 2]
-    e_comp_pj = jnp.sum(rd * m_rd + wr * m_wr + sram_b * sram_e
-                        + macs * mac_e, axis=1)
-    e_mem_d2d_pj = jnp.sum((rd + wr) * topo["dram_e"], axis=1)
-    e_link_pj = jnp.sum(loads * topo["link_e"], axis=1)
-    if mesh_on:
-        # NoC traversal energy: routed reduction bits x mesh hops x pJ/bit
-        e_link_pj = e_link_pj + (jnp.sum(sbits * pair_noc, axis=1)
-                                 * cfg.noc_energy_pj_bit)
-    e_compute_j = e_comp_pj * 1e-12
-    e_d2d_j = (e_link_pj + e_mem_d2d_pj) * 1e-12
+        def add(acc, lx):  # list order, each term times its count
+            c, xs = lx
+            return tuple(a + c * x for a, x in zip(acc, xs)), None
+
+        per = jax.vmap(layer)(rt["gid"])
+        sums, _ = jax.lax.scan(add, tuple(jnp.zeros(P) for _ in per),
+                               (rt["cnt"], per))
+        (latency, e_dynamic, l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j,
+         d2d_bits, macs_sum) = sums
     static_w = jnp.where(mask, cphys[:, :, 1], 0.0)
     e_static_j = jnp.sum(static_w, axis=1) * latency
-    energy = e_compute_j + e_d2d_j + e_static_j
+    energy = (e_dynamic if network else e_compute_j + e_d2d_j) + e_static_j
 
     # area, dollar cost (Eqs. 15-16)
     area = topo["pkg_area"]
@@ -818,13 +984,13 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
     else:
         load = jnp.broadcast_to(tb["sched_tab"][0], (P, HOURS_PER_DAY))
     eff_price = price + jnp.sum((pprofile - price) * load, axis=-1)
-    dollar = ((chip_cost + icost + package) / bond_y + mrow[:, 2]
+    dollar = ((chip_cost + icost + package) / bond_y + d.mrow[:, 2]
               + energy * runs / 3.6e6 * eff_price)
 
     # embodied + operational CFP (Eqs. 2-3)
     mfg_pc = jnp.where(mask, cphys[:, :, 3], 0.0)
     mfg = jnp.sum(mfg_pc, axis=1)
-    des = jnp.sum(jnp.where(mask, nphys[:, :, 3], 0.0), axis=1)
+    des = jnp.sum(jnp.where(mask, d.nphys[:, :, 3], 0.0), axis=1)
     icfp = jnp.where(
         topo["interp"],
         area * cfg.interposer_cpa / _nb_yield(
@@ -834,20 +1000,21 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
                      + topo["p3_bonded"]) / bond_y
     pkg_cfp = jnp.where(topo["is2d"], cfg.substrate_cfp_mm2 * area,
                         pkg_cfp_multi)
-    if mesh_on:
+    if cfg.comm == "mesh_noc":
         # router carbon scales with each die's physical router count
         # (mx * my) instead of the flat per-die share
         pkg_cfp = pkg_cfp + cfg.router_area_frac * jnp.sum(
-            mfg_pc * noc_r, axis=1)
+            mfg_pc * d.noc[1], axis=1)
     else:
         pkg_cfp = pkg_cfp + cfg.router_area_frac * mfg
     emb = (mfg + des + pkg_cfp) * embf
     eff_ci = ci + jnp.sum((profile - ci) * load, axis=-1)
     ope = energy * runs / 3.6e6 * eff_ci
 
+    if not network:
+        d2d_bits, macs_sum = jnp.sum(loads, axis=1), jnp.sum(macs, axis=1)
     return (latency, energy, area, dollar, emb, ope, l_cr, l_d2d, l_wr,
-            e_compute_j, e_d2d_j, jnp.sum(loads, axis=1),
-            jnp.sum(macs, axis=1))
+            e_compute_j, e_d2d_j, d2d_bits, macs_sum)
 
 
 def _interposer_cost(area, cfg: _Cfg):
@@ -1433,6 +1600,19 @@ def _pallas_table(hosts, tb0: int, tb1: int):
         stacks.append(np.concatenate(mats, axis=1))
     table, layout = pack_tables(*stacks)
     return dict(pallas_table=jnp.asarray(table)), layout
+
+
+def _pallas_blocks(hosts, tb0: int, tb1: int):
+    """The layer-blocked kernel table of a network engine: each GEMM's
+    prefix tables packed alone (:func:`_pallas_table` of one host at the
+    shared buckets), stacked ``[G, rows, 128]`` by GEMM id, so the
+    kernel copies one GEMM's block into VMEM at a time (the whole stack
+    of a network can outgrow it). Call under ``search_numerics``."""
+    import jax.numpy as jnp
+
+    packed = [_pallas_table([h], tb0, tb1) for h in hosts]
+    blocks = jnp.stack([t["pallas_table"] for t, _ in packed])
+    return dict(pallas_blocks=blocks), packed[0][1]
 
 
 # ---------------------------------------------------------------------------
@@ -2027,9 +2207,19 @@ class ScenarioEngine:
     ``shard_map``, when the scenario axis is sharded). The jnp path
     stays the bit-pinned reference; the same
     ``scenario_pt``/``scenario_init`` programs are emitted either way,
-    so segmentation, checkpoints and serving replay are unaffected."""
+    so segmentation, checkpoints and serving replay are unaffected.
 
-    def __init__(self, workloads: Sequence[GEMMWorkload],
+    Networks (:class:`repro.core.network.Network`): when any workload is
+    a network, the tables stack by distinct GEMM instead of by workload
+    and each workload gets a runtime layer table (GEMM ids and counts,
+    padded with count 0 to the longest network; a plain GEMM is a
+    one-layer network). Every design is then evaluated on ``layers``
+    layers: the per-GEMM stage over a ``vmap``-ped layer axis, the
+    design stages once (:func:`_metrics_jax`); the kernel reads a
+    table blocked by GEMM (:func:`_pallas_blocks`). An engine of single
+    GEMMs has ``layers == 1`` and no layer axis."""
+
+    def __init__(self, workloads: Sequence[Workload],
                  db: TechDB = DEFAULT_DB,
                  tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                  space: Optional[DesignSpace] = None,
@@ -2041,8 +2231,18 @@ class ScenarioEngine:
         if not self.workloads:
             raise ValueError("ScenarioEngine needs >= 1 workload")
         self.db, self.tile_sizes = db, tile_sizes
-        hosts = [get_evaluator(wl, db, tile_sizes, space)
-                 for wl in self.workloads]
+        # a network engine stacks its tables by distinct GEMM and gives
+        # each workload a layer table of GEMM ids and counts; an engine
+        # of single GEMMs stacks by workload and has no layer axis
+        networks = None
+        gemms = self.workloads
+        if any(isinstance(w, Network) for w in self.workloads):
+            networks = [as_network(w) for w in self.workloads]
+            gemms = tuple(dict.fromkeys(
+                g for net in networks for g in net.gemms))
+        self.layers = (1 if networks is None
+                       else max(len(net.layers) for net in networks))
+        hosts = [get_evaluator(wl, db, tile_sizes, space) for wl in gemms]
         self.hosts = hosts
         self.space = hosts[0].space
         sp = self.space
@@ -2050,10 +2250,14 @@ class ScenarioEngine:
         t1s = [h.tiles[1]["T"] for h in hosts]
         tb0, tb1 = _tile_bucket(max(t0s)), _tile_bucket(max(t1s))
         layout = None
-        with search_numerics():
+        with search_numerics(), span("repro.network.tables",
+                                     layers=self.layers,
+                                     gemms=len(gemms)) as sp_tables:
             tb = _shared_tables(hosts[0], sp)
             if _resolve_pallas(use_pallas):
-                pal, layout = _pallas_table(hosts, tb0, tb1)
+                pal, layout = (_pallas_table(hosts, tb0, tb1)
+                               if networks is None
+                               else _pallas_blocks(hosts, tb0, tb1))
                 tb.update(pal)
             tb.update(
                 pref0w=jnp.asarray(np.stack([
@@ -2074,8 +2278,21 @@ class ScenarioEngine:
                 t1w=jnp.asarray(np.asarray(t1s, dtype=np.int32)),
                 wrw=jnp.asarray(np.asarray(
                     [float(wl.M * wl.N * OPERAND_BYTES * 8)
-                     for wl in self.workloads])),
+                     for wl in gemms])),
             )
+            if networks is not None:
+                gid = {g: i for i, g in enumerate(gemms)}
+                lg = np.zeros((len(networks), self.layers), np.int32)
+                lc = np.zeros((len(networks), self.layers), np.float64)
+                for w, net in enumerate(networks):
+                    for l, (g, c) in enumerate(net.layers):
+                        lg[w, l], lc[w, l] = gid[g], c
+                tb.update(layer_gemm=jnp.asarray(lg),
+                          layer_count=jnp.asarray(lc))
+            sp_tables.set_metadata(bytes=nbytes(
+                tb[k] for k in ("pref0w", "pref1w", "mn0w", "mn1w",
+                                "pallas_table", "pallas_blocks",
+                                "layer_gemm", "layer_count") if k in tb))
         self.cfg = _base_cfg(sp, db, T0=tb0, T1=tb1, wr_bits=0.0,
                              pallas_layout=layout)
         self.tables = tb
@@ -2085,6 +2302,9 @@ class ScenarioEngine:
 
     def _cell_tables(self, wi):
         tb = self.tables
+        if "layer_gemm" in tb:
+            return tb, dict(gid=tb["layer_gemm"][wi],
+                            cnt=tb["layer_count"][wi])
         tbc = dict(tb, pref0=tb["pref0w"][wi], pref1=tb["pref1w"][wi],
                    mn0=tb["mn0w"][wi], mn1=tb["mn1w"][wi])
         rt = dict(T0=tb["t0w"][wi], T1=tb["t1w"][wi],
@@ -2493,7 +2713,8 @@ class ScenarioEngine:
 
                 arrays = shard_scenarios(arrays, mesh)
             key0 = jax.random.PRNGKey(seed)
-            with span("repro.pt.prepare", cells=S, chains=n):
+            with span("repro.pt.prepare", cells=S, chains=n,
+                      layers=self.layers):
                 args = tuple(jnp.asarray(arrays[k]) for k in (
                     "temps", "mins", "med", "w", "pair_ok", "ci", "price",
                     "embf", "profile", "pprofile", "widx"))
@@ -2679,7 +2900,7 @@ _SCENARIO_ENGINES: Dict[tuple, Tuple[TechDB, "ScenarioEngine"]] = {}
 _SCENARIO_ENGINE_CACHE_MAX = 4
 
 
-def get_scenario_engine(workloads: Sequence[GEMMWorkload],
+def get_scenario_engine(workloads: Sequence[Workload],
                         db: TechDB = DEFAULT_DB,
                         tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                         space: Optional[DesignSpace] = None,
@@ -2687,6 +2908,7 @@ def get_scenario_engine(workloads: Sequence[GEMMWorkload],
                         ) -> ScenarioEngine:
     """Cached :class:`ScenarioEngine` per (workload tuple, db, tiles,
     chiplet bound) — the stacked twin of :func:`get_device_evaluator`.
+    Workloads may be GEMMs or networks.
 
     Like that twin, the resolved Pallas setting (``use_pallas``, else
     :func:`_resolve_pallas`) is part of the key, so flipping

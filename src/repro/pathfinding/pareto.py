@@ -56,6 +56,7 @@ from repro.core.regions import Region, RegionLike, as_region
 from repro.core.sa import OBJECTIVE_AXES, random_system
 from repro.core.techdb import DEFAULT_DB, TechDB
 from repro.core.templates import TEMPLATES, Template
+from repro.core.network import Network, Workload
 from repro.core.workload import GEMMWorkload
 from repro.pathfinding.space import DesignSpace
 from repro.tracing import span
@@ -639,9 +640,14 @@ REGION_INTENSITIES: Dict[str, float] = {
 
 def workloads_from_configs(names: Sequence[str],
                            tokens: int = 512) -> List[GEMMWorkload]:
-    """MLP up-projection GEMMs (``tokens x d_model x d_ff``) for model
-    configs from :mod:`repro.configs` — the dominant GEMM shape of each
-    architecture, usable anywhere a Table IV workload is."""
+    """One MLP up-projection GEMM (``tokens x d_model x d_ff``) per model
+    config from :mod:`repro.configs`, usable anywhere a Table IV
+    workload is. It is one GEMM of the model, not the model: for
+    DeepSeek-V2's ``d_ff`` (one routed expert) it is ~0.002% of a
+    serving step's MACs. A whole network is a
+    :class:`~repro.core.network.Network` (e.g.
+    :func:`repro.configs.deepseek_v2_236b.serving_step`, built by
+    :func:`repro.core.network.deepseek_v2_step`)."""
     from repro.configs import get_config
 
     out = []
@@ -698,7 +704,7 @@ class Scenario:
     24h grid profile); ``carbon_intensity`` stays a plain float for
     backward-compatible reporting (it equals ``spec.carbon_intensity``)."""
 
-    workload: GEMMWorkload
+    workload: Workload
     region: str
     carbon_intensity: float
     spec: Optional[Region] = None
@@ -795,7 +801,7 @@ class ScenarioSweep:
     # jnp gathers elsewhere; True/False pin it)
     use_pallas: Optional[bool] = None
 
-    def run(self, workloads: Union[GEMMWorkload, Sequence[GEMMWorkload],
+    def run(self, workloads: Union[Workload, Sequence[Workload],
                                    "ScenarioSpec"],
             template: Union[str, Template] = "T1",
             db: TechDB = DEFAULT_DB, device: bool = True,
@@ -817,7 +823,10 @@ class ScenarioSweep:
         frozen description of the whole sweep. The spec then supplies
         the workloads, regions, comm/schedule models and the
         budget/segment/checkpoint knobs; passing any of those loose
-        kwargs alongside a spec is an error (one source of truth)."""
+        kwargs alongside a spec is an error (one source of truth).
+
+        A workload may be a :class:`~repro.core.network.Network`: its
+        cells search designs for the whole network (device path only)."""
         from repro.pathfinding.batch import fit_region_normalizers
         from repro.pathfinding.pathfinder import Pathfinder
         from repro.pathfinding.scenario import ScenarioSpec
@@ -846,9 +855,12 @@ class ScenarioSweep:
                 "checkpoint_dir requires the device path "
                 "(ScenarioSweep.run(device=True)); the per-cell host "
                 "fallback cannot checkpoint")
-        if isinstance(workloads, GEMMWorkload):
+        if isinstance(workloads, (GEMMWorkload, Network)):
             workloads = [workloads]
         workloads = list(workloads)
+        if not device and any(isinstance(w, Network) for w in workloads):
+            raise ValueError("network workloads run on the device path "
+                             "(ScenarioSweep.run(device=True))")
         tpl = TEMPLATES[template] if isinstance(template, str) else template
         base = _resolve_key(key)
         # regions accept floats (historical scalar-CI cells) or Region
@@ -927,7 +939,7 @@ class ScenarioSweep:
     def _run_device(self, cells, workloads, tpl, db, space, norm_of,
                     cell_budget, base, checkpoint_dir=None, resume=True,
                     segment=None) -> ScenarioFrontier:
-        from repro.core.evaluate import evaluate
+        from repro.core.network import as_network, evaluate_network
         from repro.core.scalesim import SimCache
         from repro.pathfinding.device import get_scenario_engine
         from repro.pathfinding.strategies import (
@@ -1001,7 +1013,8 @@ class ScenarioSweep:
             i = int(np.argmin(cc))
             best = space.decode(arch.encoded[i])
             db_s = dataclasses.replace(db, **reg.db_overrides())
-            best_m = evaluate(best, wl, db_s, cache=cache)
+            best_m = evaluate_network(best, as_network(wl), db_s,
+                                      cache=cache)
             sc = Scenario(wl, region, reg.carbon_intensity, reg)
             scenarios.append(sc)
             results[sc.key] = SearchResult(

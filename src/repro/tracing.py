@@ -12,14 +12,23 @@ this list.
 
 Host segment loop (``resume.run_segmented`` under the tempering engines):
 
-- ``repro.pt.prepare`` (``cells``, ``chains``): the scenario engine's
-  upload of its per-cell columns before the loop.
+- ``repro.pt.prepare`` (``cells``, ``chains``, ``layers``): the scenario
+  engine's upload of its per-cell columns before the loop; ``layers`` is
+  the length of its layer axis, the layers each design is evaluated on
+  (1 for an engine of single GEMMs).
 - ``repro.segment.init``: the seed-population program and its read-back.
 - ``repro.segment.dispatch`` (``sweeps``): enqueueing one segment.
 - ``repro.segment.absorb`` (``rows``): feeding one segment's outputs to
   the histories and archives; holds the next two.
 - ``repro.segment.wait``: the host waiting for the segment's outputs.
 - ``repro.segment.fetch`` (``bytes``): copying them to the host.
+
+Scenario engine (``ScenarioEngine`` construction):
+
+- ``repro.network.tables`` (``layers``, ``gemms``, ``bytes``): building
+  the per-GEMM prefix tables, stacked by distinct GEMM (by workload in
+  an engine of single GEMMs), and a network engine's layer tables; the
+  layer axis's length, the stacked GEMMs, the stacks' bytes.
 
 Archive (``ParetoArchive.insert``):
 

@@ -20,6 +20,7 @@ from repro.kernels import (
     wkv6,
     wkv6_ref_vmapped,
 )
+from repro.kernels.prefix_gather import prefix_select_gather_blocked
 
 KEY = jax.random.PRNGKey(0)
 
@@ -355,3 +356,48 @@ def test_prefix_select_vmap_flattens_cell_axis():
                 tab, rows[i], start[i], end[i], split[i], t0v[i], t1v[i],
                 layout=layout)
             assert (np.asarray(sel_v[i]) == np.asarray(sel_i)).all()
+
+
+@pytest.mark.parametrize("P", [20, 136])
+def test_prefix_select_blocked_under_two_vmaps(P):
+    """A layer-blocked table (one block per GEMM, each with its own true
+    totals) read under a vmap over layers inside a vmap over cells, as a
+    network engine reads it: bit-equal to the oracle on each block,
+    for a population under one kernel block and one spanning two."""
+    from repro.jaxenv import search_numerics
+
+    with search_numerics():
+        rng = np.random.default_rng(11)
+        F, R, C, G, S, L = 5, 12, 6, 3, 2, 4
+        totals = [(19, 47), (64, 128), (7, 3)]
+        tables = [_select_tables(rng, F, R, a, b, 64, 128)
+                  for a, b in totals]
+        packed = [_packed(p0, p1) for p0, p1 in tables]
+        blocks = jnp.stack([t for t, _ in packed])
+        layout = packed[0][1]
+        gids = rng.integers(0, G, (S, L)).astype(np.int32)
+        rows = rng.integers(0, R, (S, L, P, C)).astype(np.int32)
+        start = rng.integers(0, 140, (S, L, P, C)).astype(np.int32)
+        end = start + rng.integers(0, 40, (S, L, P, C)).astype(np.int32)
+        split = rng.integers(0, 2, (S, P)).astype(np.int32)
+        t0v = np.asarray([[totals[g][0]] * P for g in gids.ravel()],
+                         np.int32).reshape(S, L, P)
+        t1v = np.asarray([[totals[g][1]] * P for g in gids.ravel()],
+                         np.int32).reshape(S, L, P)
+
+        def cell(g, r, s, e, sp, a, b):
+            return jax.vmap(
+                lambda gg, rr, ss, ee, aa, bb: prefix_select_gather_blocked(
+                    blocks, gg, rr, ss, ee, sp, aa, bb, layout=layout))(
+                g, r, s, e, a, b)
+
+        sel = np.asarray(jax.vmap(cell)(gids, rows, start, end, split, t0v,
+                                        t1v))
+        assert sel.shape == (S, L, P, C, F)
+        for i in range(S):
+            for j in range(L):
+                p0, p1 = tables[gids[i, j]]
+                want = prefix_select_ref(p0, p1, rows[i, j], start[i, j],
+                                         end[i, j], split[i], t0v[i, j],
+                                         t1v[i, j])
+                assert (sel[i, j] == np.asarray(want)).all()

@@ -84,3 +84,28 @@ def test_eval_cost_jnp_program_compiles_at_4096_rows(one_chip):
         ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_layer_blocked_kernel_compiles_at_network_size(one_chip):
+    """The grouped gather kernel over a network's layer-blocked table:
+    18 GEMM blocks of ``[5, 48, 65]`` / ``[5, 48, 513]`` int64 (packed,
+    ~1.8 MB each; ~32 MB whole, past what a double-buffered VMEM copy
+    allows), for 18 layers x 24 cells of 64 systems x 6 slots."""
+    from repro.jaxenv import search_numerics
+    from repro.kernels.prefix_gather import kernel as K
+
+    with search_numerics():
+        block, (R, L0, L1) = K.pack_tables(np.zeros((5, 48, 65), np.int64),
+                                           np.zeros((5, 48, 513), np.int64))
+        assert block.shape == (3472, 128) and (R, L0, L1) == (48, 65, 513)
+        groups = 18 * 24
+        compiled = jax.jit(
+            lambda t, g, ge, gs: K.select_groups_grouped(
+                t, g, ge, gs, interpret=False)
+        ).lower(_spec((18,) + block.shape, jnp.int32, one_chip),
+                _spec((groups,), jnp.int32, one_chip),
+                _spec((groups, 64, SLOTS), jnp.int32, one_chip),
+                _spec((groups, 64, SLOTS), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == groups * 64 * K.LANES * 4

@@ -1,5 +1,6 @@
 """The program's profiler spans (``repro.tracing``): a scenario-grid
-search with archives and one service quantum, recorded under
+search with archives, one service quantum and the build of a network
+engine, recorded under
 ``jax.profiler``, must leave every span the module documents, each with
 exactly its documented stats.
 
@@ -19,9 +20,11 @@ import pytest
 
 from repro import tracing
 from repro.core import workload
+from repro.core.network import Network
 from repro.pathfinding import (
     ParetoArchive,
     ScalarizationSweep,
+    ScenarioEngine,
     fit_normalizer_batched,
 )
 from repro.serving import JobSpec, JobState, PathfinderService
@@ -95,6 +98,8 @@ def recorded(svc, tmp_path_factory):
         assert svc.status("paused") is JobState.PAUSED
         svc.resume_job("paused")
         svc.step()
+        ScenarioEngine((Network("pair", ((WL, 2), (workload(4), 1))),),
+                       space=svc.space, use_pallas=False)
     finally:
         jax.profiler.stop_trace()
     assert not tracing.enabled()
@@ -118,7 +123,11 @@ def test_span_values(recorded, svc):
     # 4 sweeps in 2-sweep segments, then the service's one segment
     assert [st["sweeps"] for _, _, st in r["repro.segment.dispatch"]] \
         == [2, 2]
-    assert r["repro.pt.prepare"][0][2] == {"cells": 4, "chains": 4}
+    assert r["repro.pt.prepare"][0][2] == {"cells": 4, "chains": 4,
+                                          "layers": 1}
+    (_, _, tables), = r["repro.network.tables"]
+    assert tables["layers"] == 2 and tables["gemms"] == 2
+    assert tables["bytes"] > 0
     for _, _, st in r["repro.archive.insert"]:
         assert st["offered"] >= st["prefiltered"] >= 1
         assert 1 <= st["size"] <= STRAT.frontier_size
