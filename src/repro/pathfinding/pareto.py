@@ -6,13 +6,16 @@ frontier a first-class search output instead of an ad-hoc rescan:
 
 * :func:`non_dominated_mask` / :func:`non_dominated_mask_jnp` — exact
   host reference and vectorized ``jax.numpy`` renderings of the
-  non-dominated (minimization) filter. Both use exact float comparisons,
+  non-dominated (minimization) filter; the host one is
+  :func:`dominated_by` (which rows of one set another set dominates) of
+  the rows against themselves. Both use exact float comparisons,
   so they agree *exactly* on any input (asserted over 1k random fronts by
   ``benchmarks/pareto_frontier.py``).
 * :class:`ParetoArchive` — a bounded archive of non-dominated
   ``(encoded design, objective vector)`` pairs over the
   :data:`repro.core.sa.OBJECTIVE_AXES` axes ``(latency_s, dollar,
-  total_cfp)``. Inserts are chunked (pairwise filtering stays cheap),
+  total_cfp)``. Inserts are chunked and screened against the archive
+  before any pairwise filter (only the few survivors are merged),
   storage order is canonical (lexicographic), duplicates are dropped, and
   the archive is pruned to ``max_size`` by NSGA-II crowding distance —
   all deterministic, so inserting an archive into itself is a no-op.
@@ -63,11 +66,9 @@ from repro.tracing import span
 
 N_AXES = len(OBJECTIVE_AXES)
 
-# pairwise-filter block size: chunked inserts keep the O(n^2) dominance
-# comparison bounded at (chunk + max_size)^2 regardless of how many
-# samples a sweep feeds in; total work scales as n_samples * chunk, so
-# smaller chunks are *cheaper* for bulk feeds (each chunk is pre-filtered
-# on its own before the merge — search batches are mostly dominated)
+# insert block size: each chunk is screened against the archive (at most
+# max_size x chunk comparisons) and merged, then the archive is pruned,
+# before the next chunk; pruning between chunks is part of the result
 _INSERT_CHUNK = 512
 
 # candidate dominators per pass of the host filter: a pass holds two
@@ -80,33 +81,44 @@ _FILTER_BLOCK = 256
 # ---------------------------------------------------------------------------
 
 
-def non_dominated_mask(points: np.ndarray) -> np.ndarray:
-    """Exact host reference: boolean mask of non-dominated rows.
+def dominated_by(src: np.ndarray, dst: np.ndarray) -> np.ndarray:
+    """Exact host reference: boolean mask over the rows of ``dst``, true
+    where some row of ``src`` dominates the row.
 
-    Minimization on every axis. Row ``j`` is dominated iff some row ``i``
-    is <= on all axes and < on at least one; exact duplicates do not
-    dominate each other (both survive — dedup is the archive's job).
+    Minimization on every axis. Row ``i`` dominates row ``j`` iff it is
+    <= on all axes and < on at least one; exact duplicates do not
+    dominate each other, and a row with a NaN neither dominates nor is
+    dominated (every comparison with NaN is false).
 
     The comparisons run one axis at a time on contiguous ``[block, n]``
     planes (``less_equal.outer`` ANDed, ``less.outer`` ORed) for
-    ``_FILTER_BLOCK`` candidate dominators per pass: no ``[n, n, axes]``
+    ``_FILTER_BLOCK`` rows of ``src`` per pass: no ``[m, n, axes]``
     temporary and no reduction over a strided axis."""
-    p = np.atleast_2d(np.asarray(points, dtype=np.float64))
-    n, d = p.shape
-    dominated = np.zeros(n, dtype=bool)
-    if n < 2 or d == 0:
-        return ~dominated   # no pair to compare
-    cols = p.T
-    for lo in range(0, n, _FILTER_BLOCK):
-        src = cols[:, lo:lo + _FILTER_BLOCK]
-        le = np.less_equal.outer(src[0], cols[0])   # i <= j on every axis
-        lt = np.less.outer(src[0], cols[0])         # i < j on some axis
-        for a in range(1, d):
-            le &= np.less_equal.outer(src[a], cols[a])
-            lt |= np.less.outer(src[a], cols[a])
+    s = np.atleast_2d(np.asarray(src, dtype=np.float64))
+    p = np.atleast_2d(np.asarray(dst, dtype=np.float64))
+    dominated = np.zeros(p.shape[0], dtype=bool)
+    if s.shape[0] == 0 or p.shape[0] == 0 or p.shape[1] == 0:
+        return dominated    # no pair to compare
+    rows, cols = s.T, p.T
+    for lo in range(0, s.shape[0], _FILTER_BLOCK):
+        blk = rows[:, lo:lo + _FILTER_BLOCK]
+        le = np.less_equal.outer(blk[0], cols[0])   # i <= j on every axis
+        lt = np.less.outer(blk[0], cols[0])         # i < j on some axis
+        for a in range(1, p.shape[1]):
+            le &= np.less_equal.outer(blk[a], cols[a])
+            lt |= np.less.outer(blk[a], cols[a])
         le &= lt
         dominated |= le.any(axis=0)
-    return ~dominated
+    return dominated
+
+
+def non_dominated_mask(points: np.ndarray) -> np.ndarray:
+    """Exact host reference: boolean mask of non-dominated rows, the rows
+    that no row of ``points`` dominates (:func:`dominated_by` of the
+    rows against themselves; duplicates both survive, dedup is the
+    archive's job)."""
+    p = np.atleast_2d(np.asarray(points, dtype=np.float64))
+    return ~dominated_by(p, p)
 
 
 def non_dominated_mask_jnp(points) -> np.ndarray:
@@ -230,15 +242,40 @@ def _lattice(h: int, d: int):
 # ---------------------------------------------------------------------------
 
 
+def _order_keys(enc: np.ndarray, vec: np.ndarray) -> np.ndarray:
+    """One structured key per row whose order is the archive's canonical
+    lexicographic ``(vector, encoding)`` order: a float64 field per axis
+    (NaN sorts last, ``-0.0 == 0.0``), then the int32 encoding as
+    big-endian bytes with the sign bit flipped, which compare as bytes
+    in the order of the ints. Keys are equal iff the rows are exact
+    duplicates (a NaN never equals)."""
+    n, d = vec.shape
+    key = np.empty(n, dtype=[*((f"v{a}", "f8") for a in range(d)),
+                             ("e", f"V{4 * enc.shape[1]}")])
+    for a in range(d):
+        key[f"v{a}"] = vec[:, a]
+    flipped = np.ascontiguousarray(enc).view(np.uint32) ^ np.uint32(1 << 31)
+    key.view(np.uint8).reshape(n, key.itemsize)[:, 8 * d:] = (
+        flipped.astype(">u4").view(np.uint8))
+    return key
+
+
 class ParetoArchive:
     """Bounded deterministic archive of non-dominated designs.
 
-    Stores ``(encoded row, objective vector)`` pairs; every insert
-    re-filters to the non-dominated set (``backend="jnp"`` uses the
-    vectorized filter, ``"numpy"`` the exact host reference — they agree
-    exactly), drops duplicate rows, prunes to ``max_size`` by largest
-    crowding distance (stable index tie-break) and canonicalizes storage
-    to lexicographic ``(vector, encoding)`` order.
+    Stores ``(encoded row, objective vector)`` pairs. Invariant: after
+    every insert the archive is deduped, in canonical lexicographic
+    ``(vector, encoding)`` order, mutually non-dominated and at most
+    ``max_size`` rows (:meth:`load_checkpoint_arrays` restores what
+    :meth:`checkpoint_arrays` saved, so it holds there too). Each insert
+    chunk relies on it: the incoming rows an archive row dominates are
+    dropped first (:func:`dominated_by`), the survivors are pre-reduced
+    against each other (``backend="jnp"`` uses the vectorized filter for
+    that, ``"numpy"`` the exact host reference — they agree exactly),
+    the archive rows a survivor dominates are retired, and the rest is
+    deduped, ordered and pruned to ``max_size`` by largest crowding
+    distance (stable index tie-break). The result is the non-dominated
+    set of the deduped union, as a full re-filter would give it.
 
     Determinism: the same insert sequence always yields the identical
     archive, re-inserting the archive into itself is a no-op, and while
@@ -300,45 +337,65 @@ class ParetoArchive:
             if enc.shape[1] != self._enc.shape[1]:
                 raise ValueError(
                     f"row width {enc.shape[1]} != archive {self._enc.shape[1]}")
-            kept = 0
+            screened = kept = 0
             for lo in range(0, enc.shape[0], _INSERT_CHUNK):
-                kept += self._insert_chunk(enc[lo:lo + _INSERT_CHUNK],
-                                           vec[lo:lo + _INSERT_CHUNK])
-            sp.set_metadata(offered=enc.shape[0], prefiltered=kept,
-                            size=len(self))
+                out, into = self._insert_chunk(enc[lo:lo + _INSERT_CHUNK],
+                                               vec[lo:lo + _INSERT_CHUNK])
+                screened += out
+                kept += into
+            sp.set_metadata(offered=enc.shape[0], screened_out=screened,
+                            prefiltered=kept, size=len(self))
         return len(self)
 
     def merge(self, other: "ParetoArchive") -> int:
         return self.insert(other._enc, other._vec)
 
-    def _insert_chunk(self, enc: np.ndarray, vec: np.ndarray) -> int:
-        """Merge one chunk; returns its rows left after the pre-reduce."""
-        if vec.shape[0] > 64:
-            # pre-reduce the incoming block alone: dominated rows can
-            # never enter the archive, and dropping them first keeps the
-            # merge pairwise tiny
-            pre = (non_dominated_mask_jnp(vec) if self.backend == "jnp"
-                   else non_dominated_mask(vec))
-            enc, vec = enc[pre], vec[pre]
-        all_enc = np.vstack([self._enc, enc])
-        all_vec = np.vstack([self._vec, vec])
-        # canonical order + exact-duplicate dedup in one pass (int32
-        # encodings are exact in float64, so the combined key is lossless)
-        key = np.hstack([all_vec, all_enc.astype(np.float64)])
-        # np.unique returns first-occurrence indices in sorted-key order:
-        # dedup + canonical lexicographic order in one pass
-        _, uniq = np.unique(key, axis=0, return_index=True)
-        all_enc, all_vec = all_enc[uniq], all_vec[uniq]
-        mask = (non_dominated_mask_jnp(all_vec) if self.backend == "jnp"
-                else non_dominated_mask(all_vec))
-        all_enc, all_vec = all_enc[mask], all_vec[mask]
+    def _insert_chunk(self, enc: np.ndarray, vec: np.ndarray
+                      ) -> Tuple[int, int]:
+        """Merge one chunk; returns its rows that the archive screen
+        dropped and its rows that entered the merge.
+
+        The archive's invariant makes the merge incremental: the
+        non-dominated set of the union is the archive rows that no
+        incoming row dominates plus the incoming rows that nothing in the
+        union dominates, and an incoming row that an incoming row
+        dominates is dominated by the archive or by a row that passes the
+        screen (dominance is transitive)."""
+        # screen: archive rows dominate most of a search batch
+        new = ~dominated_by(self._vec, vec)
+        screened = vec.shape[0] - int(new.sum())
+        enc, vec = enc[new], vec[new]
+        # pre-reduce the few survivors against each other
+        pre = (non_dominated_mask_jnp(vec) if self.backend == "jnp"
+               else non_dominated_mask(vec))
+        enc, vec = enc[pre], vec[pre]
+        if vec.shape[0] == 0:
+            return screened, 0    # the archive is its own merge
+        # retire the archive rows a survivor dominates
+        stay = ~dominated_by(vec, self._vec)
+        arch_enc, arch_vec = self._enc[stay], self._vec[stay]
+        # dedup + canonical order: the kept archive rows are in order and
+        # distinct already, so the survivors are sorted (stably), deduped
+        # and merged in after their equals; an archive row wins over an
+        # incoming duplicate, as the first occurrence of the union would
+        arch_key, key = _order_keys(arch_enc, arch_vec), _order_keys(enc, vec)
+        order = np.argsort(key, kind="stable")
+        key = key[order]
+        fresh = np.ones(len(order), dtype=bool)
+        fresh[1:] = key[1:] != key[:-1]
+        at = np.searchsorted(arch_key, key, side="right")
+        after = at > 0
+        fresh[after] &= arch_key[at[after] - 1] != key[after]
+        order, at = order[fresh], at[fresh]
+        all_enc = np.insert(arch_enc, at, enc[order], axis=0)
+        all_vec = np.insert(arch_vec, at, vec[order], axis=0)
         if all_vec.shape[0] > self.max_size:
             cd = crowding_distance(all_vec)
             keep = np.argsort(-cd, kind="stable")[:self.max_size]
             keep.sort()
             all_enc, all_vec = all_enc[keep], all_vec[keep]
         self._enc, self._vec = all_enc, all_vec
-        return vec.shape[0]
+        return screened, vec.shape[0]
 
     # -- checkpointing ------------------------------------------------------
     # the repro.checkpoint protocol: archives ride inside checkpoint

@@ -32,10 +32,12 @@ Scenario engine (``ScenarioEngine`` construction):
 
 Archive (``ParetoArchive.insert``):
 
-- ``repro.archive.insert`` (``offered``, ``prefiltered``, ``size``): rows
-  in; rows left after each chunk's own non-dominance pre-reduce (a chunk
-  of 64 rows or fewer is not pre-reduced and counts whole); archive rows
-  after.
+- ``repro.archive.insert`` (``offered``, ``screened_out``,
+  ``prefiltered``, ``size``): rows in; rows an archive row dominated,
+  dropped by each chunk's screen against the archive; rows that entered
+  the merge, left after the screen and the survivors' own non-dominance
+  pre-reduce (0 when the archive dominates the whole batch); archive
+  rows after.
 
 Service tick (``PathfinderService._tick``):
 

@@ -92,6 +92,17 @@ def _broadcast_mask(points):
     return ~(le & lt).any(axis=0)
 
 
+def _broadcast_dominated_by(src, dst):
+    """:func:`pareto.dominated_by` as one ``[m, n, axes]`` broadcast."""
+    s = np.atleast_2d(np.asarray(src, dtype=np.float64))
+    p = np.atleast_2d(np.asarray(dst, dtype=np.float64))
+    if s.shape[0] == 0 or p.shape[0] == 0:
+        return np.zeros(p.shape[0], dtype=bool)
+    le = np.all(s[:, None, :] <= p[None, :, :], axis=2)
+    lt = np.any(s[:, None, :] < p[None, :, :], axis=2)
+    return (le & lt).any(axis=0)
+
+
 def _hard_front(n, d, seed, whole_rows):
     """A front that exercises every edge of the filter: a trade-off
     surface with dominated rows behind it, per-axis ties (rounded
@@ -118,8 +129,7 @@ def _hard_front(n, d, seed, whole_rows):
 def test_filter_matches_broadcast_oracle(n, d):
     """The per-axis plane filter gives the broadcast oracle's mask
     exactly: ties, duplicates, inf and NaN rows, any axis count, and
-    sizes on both sides of the insert's 64-row pre-reduce threshold and
-    the filter's row blocks."""
+    sizes on both sides of the filter's row blocks."""
     for seed, whole_rows in enumerate([
             (), (np.inf, np.nan, 0.0, -0.0),
             (np.inf, -np.inf, np.nan, 0.0, -0.0)]):
@@ -128,6 +138,28 @@ def test_filter_matches_broadcast_oracle(n, d):
         want = _broadcast_mask(pts)
         assert got.dtype == bool and got.shape == (n,)
         assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("m, n", [(0, 5), (5, 0), (1, 1), (7, 300),
+                                  (255, 512), (256, 320), (257, 64)])
+@pytest.mark.parametrize("d", [1, 3])
+def test_dominated_by_matches_broadcast_oracle(m, n, d):
+    """The archive screen's comparison gives the broadcast oracle's mask
+    exactly between two different sets (ties, duplicates, inf and NaN
+    cells and rows, one or several blocks of dominators), and shares
+    the filter's code path: a set screened against itself is the
+    complement of its non-dominated mask."""
+    for seed, whole_rows in enumerate([(), (np.inf, np.nan, 0.0, -0.0)]):
+        src = _hard_front(m, d, 2000 * d + seed, whole_rows)
+        dst = _hard_front(n, d, 3000 * d + seed, whole_rows)
+        # shared cells, so some rows of the two sets tie or repeat
+        if m and n:
+            dst[: n // 3] = src[np.arange(n // 3) % m]
+        got = pareto.dominated_by(src, dst)
+        assert got.dtype == bool and got.shape == (n,)
+        assert np.array_equal(got, _broadcast_dominated_by(src, dst))
+        assert np.array_equal(~pareto.dominated_by(dst, dst),
+                              non_dominated_mask(dst))
 
 
 def test_hypervolume_exact_values():
@@ -266,19 +298,28 @@ class _MetaLog:
         self.entries[-1][1].update(stats)
 
 
-def _insert_trail(monkeypatch, mask, batches):
-    """Archive contents after each insert of ``batches``, and the insert
-    spans' stats, with ``mask`` as the archive's host filter."""
+def _insert_trail(monkeypatch, mask, dominated, batches):
+    """Archive contents after each insert of ``batches``, the insert
+    spans' stats, and how many crowding prunes ran, with ``mask`` as the
+    archive's host filter and ``dominated`` as its screen."""
     log = _MetaLog()
+    prunes = []
+
+    def crowding(points):
+        prunes.append(len(points))
+        return crowding_distance(points)
+
     with monkeypatch.context() as m:
         m.setattr(pareto, "non_dominated_mask", mask)
+        m.setattr(pareto, "dominated_by", dominated)
+        m.setattr(pareto, "crowding_distance", crowding)
         m.setattr(pareto, "span", log)
         arch = ParetoArchive(max_size=256)
         trail = []
         for enc, vec in batches:
             arch.insert(enc, vec)
             trail.append((arch.encoded, arch.vectors))
-    return trail, log.entries
+    return trail, log.entries, prunes
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -286,7 +327,7 @@ def test_archive_inserts_match_broadcast_oracle(monkeypatch, seed):
     """A study cell's boundaries, 832 rows each (64 chains x 13 rows of
     41-wide encodings), fed in a row to an archive of 256 whose crowding
     prune engages: contents and span stats match, insert for insert, an
-    archive filtered by the broadcast oracle."""
+    archive whose filter and screen are the broadcast oracle."""
     rng = np.random.default_rng(seed)
     batches = []
     for _ in range(5):
@@ -297,16 +338,166 @@ def test_archive_inserts_match_broadcast_oracle(monkeypatch, seed):
         again, src = rng.integers(0, 832, (2, 80))
         vec[again], enc[again] = vec[src], enc[src]
         batches.append((enc, vec))
-    got, got_log = _insert_trail(monkeypatch, non_dominated_mask, batches)
-    want, want_log = _insert_trail(monkeypatch, _broadcast_mask, batches)
+    got, got_log, got_prunes = _insert_trail(
+        monkeypatch, non_dominated_mask, pareto.dominated_by, batches)
+    want, want_log, want_prunes = _insert_trail(
+        monkeypatch, _broadcast_mask, _broadcast_dominated_by, batches)
     for (ge, gv), (we, wv) in zip(got, want):
         assert np.array_equal(ge, we) and np.array_equal(gv, wv)
     assert got_log == want_log
     assert [name for name, _ in got_log] == ["repro.archive.insert"] * 5
-    # the prune engaged: an insert kept more rows than the bound holds
-    sizes = [0] + [st["size"] for _, st in got_log]
-    assert any(size + st["prefiltered"] > 256 and st["size"] == 256
-               for size, (_, st) in zip(sizes, got_log))
+    # the prune engaged: a merge held more rows than the bound
+    assert got_prunes == want_prunes
+    assert any(n > 256 for n in got_prunes)
+    assert any(st["size"] == 256 for _, st in got_log)
+
+
+def _recompute_insert(enc_state, vec_state, max_size, enc, vec):
+    """The archive insert as a full re-filter of each chunk's union with
+    the archive: the chunk pre-reduced alone (over 64 rows), stacked
+    with the archive, deduped and ordered by ``np.unique``, filtered
+    whole, pruned by crowding. The reference the incremental merge has
+    to match bit for bit; also says whether a prune ran."""
+    pruned = False
+    if enc_state.shape[1] == 0 and enc.shape[1] > 0:
+        enc_state = np.zeros((0, enc.shape[1]), dtype=np.int32)
+    for lo in range(0, enc.shape[0], pareto._INSERT_CHUNK):
+        ce = enc[lo:lo + pareto._INSERT_CHUNK]
+        cv = vec[lo:lo + pareto._INSERT_CHUNK]
+        if cv.shape[0] > 64:
+            pre = _broadcast_mask(cv)
+            ce, cv = ce[pre], cv[pre]
+        all_enc = np.vstack([enc_state, ce])
+        all_vec = np.vstack([vec_state, cv])
+        key = np.hstack([all_vec, all_enc.astype(np.float64)])
+        _, uniq = np.unique(key, axis=0, return_index=True)
+        all_enc, all_vec = all_enc[uniq], all_vec[uniq]
+        mask = _broadcast_mask(all_vec)
+        all_enc, all_vec = all_enc[mask], all_vec[mask]
+        if all_vec.shape[0] > max_size:
+            pruned = True
+            cd = crowding_distance(all_vec)
+            keep = np.argsort(-cd, kind="stable")[:max_size]
+            keep.sort()
+            all_enc, all_vec = all_enc[keep], all_vec[keep]
+        enc_state, vec_state = all_enc, all_vec
+    return enc_state, vec_state, pruned
+
+
+def _study_batch(rng, n=832, width=41):
+    """A search boundary's rows: a trade-off surface with dominated rows
+    behind it, encodings drawn independently."""
+    vec = rng.dirichlet(np.ones(3), n) * (1 + 0.3 * rng.exponential(1.0,
+                                                                    (n, 3)))
+    return rng.integers(0, 50, (n, width)).astype(np.int32), vec
+
+
+def _feed(case, rng):
+    """``(archive kwargs, batches, inserts before a checkpoint round trip
+    or None)`` for one case of the merge oracle test."""
+    kw, restore = dict(max_size=256), None
+    if case == "full_832":
+        batches = [_study_batch(rng) for _ in range(6)]
+    elif case == "stalled_repeats":
+        batches = []
+        for _ in range(5):
+            enc, vec = _study_batch(rng)
+            # stalled chains offer the same row within a batch, and rows
+            # the archive already holds
+            again, src = rng.integers(0, 832, (2, 120))
+            vec[again], enc[again] = vec[src], enc[src]
+            if batches:
+                pe, pv = batches[-1]
+                old = rng.integers(0, 832, 200)
+                enc[:200], vec[:200] = pe[old], pv[old]
+            batches.append((enc, vec))
+    elif case == "equal_vectors":
+        batches = []
+        for _ in range(4):
+            enc, vec = _study_batch(rng)
+            enc -= 25     # signed encodings: their order decides ties
+            # equal vectors under other encodings, in the batch and
+            # against the archive's rows
+            same = rng.integers(0, 832, (2, 150))
+            vec[same[0]] = vec[same[1]]
+            if batches:
+                vec[:100] = batches[-1][1][rng.integers(0, 832, 100)]
+            batches.append((enc, vec))
+        kw = dict(max_size=96)
+    elif case == "nan_row":
+        batches = []
+        for i in range(4):
+            enc, vec = _study_batch(rng, 520)
+            vec[3 + i, i % 3] = np.nan        # a NaN cell
+            vec[400] = np.nan                 # a whole NaN row
+            vec[401], enc[401] = vec[3 + i], enc[3 + i]   # and its twin
+            batches.append((enc, vec))
+    elif case == "signed_zeros":
+        batches = []
+        for _ in range(3):
+            enc, vec = _study_batch(rng, 300)
+            vec[:, 0] -= np.median(vec[:, 0])
+            vec[:40, 0] = 0.0
+            vec[40:80] = vec[:40]
+            vec[40:80, 0] = -0.0              # the same rows with -0.0
+            enc[40:80] = enc[:40]
+            batches.append((enc, vec))
+    elif case == "empty_width0":
+        # the archive's row width is set by its first insert
+        batches = [_study_batch(rng, 1), _study_batch(rng, 40),
+                   _study_batch(rng, 700)]
+    elif case == "zero_width_rows":
+        # rows with no encoding: vectors alone tell duplicates apart
+        batches = []
+        for _ in range(3):
+            enc, vec = _study_batch(rng, 600, width=0)
+            vec[300:400] = vec[:100]
+            batches.append((enc, vec))
+        kw = dict(max_size=64)
+    elif case == "small_batches":
+        # a service job's boundary: 4 chains x 2 sweeps
+        batches = [_study_batch(rng, 8) for _ in range(40)]
+        kw = dict(max_size=16)
+    elif case == "checkpoint":
+        batches = [_study_batch(rng) for _ in range(5)]
+        restore = 3
+    elif case == "jnp":
+        batches = [_study_batch(rng, 600) for _ in range(3)]
+        kw = dict(max_size=64, backend="jnp")
+    return kw, batches, restore
+
+
+_FEED_CASES = ("full_832", "stalled_repeats", "equal_vectors", "nan_row",
+               "signed_zeros", "empty_width0", "zero_width_rows",
+               "small_batches", "checkpoint", "jnp")
+
+
+@pytest.mark.parametrize("case", _FEED_CASES)
+def test_archive_merge_matches_full_recompute(case):
+    """The incremental merge (archive screen, pre-reduce of the
+    survivors, retirement, sorted merge) leaves ``_enc`` and ``_vec``
+    bit-identical to a full re-filter of the union, after every insert
+    of each feed."""
+    rng = np.random.default_rng(_FEED_CASES.index(case))
+    kw, batches, restore = _feed(case, rng)
+    arch = ParetoArchive(**kw)
+    ref_enc = np.zeros((0, 0), dtype=np.int32)
+    ref_vec = np.zeros((0, 3))
+    pruned = False
+    for i, (enc, vec) in enumerate(batches):
+        if i == restore:
+            arch = arch.from_checkpoint_arrays(arch.checkpoint_arrays())
+        arch.insert(enc, vec)
+        ref_enc, ref_vec, ref_pruned = _recompute_insert(
+            ref_enc, ref_vec, arch.max_size, enc, vec)
+        assert arch._enc.dtype == ref_enc.dtype
+        assert arch._enc.shape == ref_enc.shape
+        assert arch._enc.tobytes() == ref_enc.tobytes()
+        assert arch._vec.tobytes() == ref_vec.tobytes()
+        pruned |= ref_pruned
+    if case in ("full_832", "checkpoint", "small_batches",
+                "zero_width_rows"):
+        assert pruned
 
 
 def test_archive_project_2d_front():

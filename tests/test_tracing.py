@@ -109,8 +109,8 @@ def recorded(svc, tmp_path_factory):
 
 def test_every_span_with_its_stats(recorded):
     documented = _documented()
-    assert documented["repro.archive.insert"] == {"offered", "prefiltered",
-                                                  "size"}
+    assert documented["repro.archive.insert"] == {
+        "offered", "screened_out", "prefiltered", "size"}
     for name, stats in documented.items():
         assert recorded.get(name), f"{name} not recorded"
         for _, _, got in recorded[name]:
@@ -129,7 +129,8 @@ def test_span_values(recorded, svc):
     assert tables["layers"] == 2 and tables["gemms"] == 2
     assert tables["bytes"] > 0
     for _, _, st in r["repro.archive.insert"]:
-        assert st["offered"] >= st["prefiltered"] >= 1
+        assert st["offered"] >= st["prefiltered"] + st["screened_out"]
+        assert st["prefiltered"] >= 0 and st["screened_out"] >= 0
         assert 1 <= st["size"] <= STRAT.frontier_size
     # each absorb holds its wait and fetch, and offers its rows to the
     # archives: the seed block and 2 sweeps of 4 x 4 chains, then 2 sweeps
