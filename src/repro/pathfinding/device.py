@@ -1,40 +1,49 @@
-"""Device-resident pathfinding: jitted fused evaluate+cost, vectorized
-moves, and a ``lax.scan`` parallel-tempering engine.
+"""Device-resident pathfinding: the fused evaluate+cost program,
+vectorized moves, and one ``lax.scan`` parallel-tempering engine.
 
-PR 1's :func:`repro.pathfinding.batch.evaluate_batch` vectorized the
-metric *arithmetic* but kept the search loop host-bound: a per-row Python
+The batched :func:`repro.pathfinding.batch.evaluate_batch` vectorizes
+the metric *arithmetic* but keeps the search loop host-bound: a per-row Python
 topology pass (``_topo_one``), an un-jitted ``jax.numpy`` stage 3, Python
 ``propose()`` per chain and a host<->device round-trip every sweep. This
 module moves the whole explore -> evaluate -> accept loop onto the device:
 
-* :class:`DeviceEvaluator` — a single ``jax.jit``-compiled
-  ``evaluate_cost`` that fuses stages 1-3 of the batched evaluator *and*
-  the Eq. 17 ``sa_cost`` into one XLA program. The per-row Python
-  floorplan/BFS pass is replaced by an exact vectorized rendering (the
-  slicing-floorplan recursion unrolled level-by-level over fixed
-  ``max_chiplets`` slots, BFS with queue-order tie-breaking as a masked
-  fixed-point, link tables in a fixed ``(C*(C-1)/2 + C-1)``-slot layout),
-  so stage 2 becomes gathers + elementwise arithmetic with no data-
-  dependent Python. Populations are padded to power-of-two buckets
-  (>= 64) and the encoded buffer is donated, so repeated sweeps of any
-  size hit the jit compile cache and never re-trace.
-* :func:`propose_batch` / :meth:`DeviceEvaluator.propose` — the
-  hierarchical move distribution of :func:`repro.core.sa.propose`
-  (application / chip-architecture / chiplet / package levels, style
-  repair, hierarchical package-then-protocol draws) applied to encoded
-  ``int32`` rows with ``jax.random``; candidates that fail the vectorized
-  validity rules keep the incumbent row (the batched rendering of the
-  scalar retry loop).
-* :meth:`DeviceEvaluator.parallel_tempering` — the full ParallelTempering
-  sweep (propose, evaluate, Metropolis accept, sequential adjacent-pair
-  replica exchange) fused into ``jax.lax.scan`` chunks advanced by a
-  host loop (``segment=`` sweeps per chunk; default one chunk). The
-  chunking is bit-invisible — same key stream, same sweep indices — and
-  its boundaries are where long searches snapshot carry + frontier
-  archive for checkpoint/resume (:mod:`repro.pathfinding.resume`).
-  ``record_trace=True`` additionally returns every proposal and uniform
-  draw so a host reference can replay the exact trajectory (the
-  trajectory-equivalence tests).
+* The fused evaluator (:func:`_eval_cost_jax`) — stages 1-3 of the
+  batched evaluator *and* the Eq. 17 ``sa_cost`` in one XLA program. The
+  per-row Python floorplan/BFS pass is replaced by an exact vectorized
+  rendering (the slicing-floorplan recursion unrolled level-by-level over
+  fixed ``max_chiplets`` slots, BFS with queue-order tie-breaking as a
+  masked fixed-point, link tables in a fixed ``(C*(C-1)/2 + C-1)``-slot
+  layout), so stage 2 becomes gathers + elementwise arithmetic with no
+  data-dependent Python.
+* The hierarchical move generator (:func:`_propose_jax`) — the move
+  distribution of :func:`repro.core.sa.propose` (application /
+  chip-architecture / chiplet / package levels, style repair,
+  hierarchical package-then-protocol draws) applied to encoded ``int32``
+  rows with ``jax.random``; candidates that fail the vectorized validity
+  rules keep the incumbent row (the batched rendering of the scalar
+  retry loop).
+* :class:`ScenarioEngine` — the one tempering engine. Propose, evaluate,
+  Metropolis accept and sequential adjacent-pair replica exchange run in
+  one ``lax.scan`` over a ``vmap``-ped cell axis: the grid carbon
+  intensity, per-cell normalizer/weight rows and the per-workload tile
+  totals are *runtime* data of the program (tile prefix tables ride in a
+  bucket-padded per-workload stack), so a whole region x workload
+  :class:`~repro.pathfinding.pareto.ScenarioSweep` runs with one XLA
+  compile, ``fold_in``-derived per-cell keys and optional scenario-axis
+  sharding over local devices. A workload may be a whole network of
+  GEMMs (:mod:`repro.core.network`): its layers ride a ``vmap``-ped layer
+  axis of the same program. The scan is advanced in host-driven chunks
+  (``segment=`` sweeps each); the chunking is bit-invisible — same key
+  stream, same sweep indices — and its boundaries are where long
+  searches snapshot carry + frontier archives for checkpoint/resume
+  (:mod:`repro.pathfinding.resume`).
+* :class:`DeviceEvaluator` — one workload's view of that engine: a
+  one-cell grid whose cell key is the caller's key, with the
+  single-workload surface (``evaluate_cost``, ``metrics``, ``propose``,
+  ``parallel_tempering``) and checkpoint layout. ``record_trace=True``
+  additionally returns every proposal and uniform draw so a host
+  reference can replay the exact trajectory (the trajectory-equivalence
+  tests).
 
 Numerics: everything runs in float64 (:func:`repro.jaxenv.search_numerics`
 scoped to this module's entry points) and replicates the host evaluator's
@@ -42,17 +51,6 @@ operation order wherever floating-point ties matter (greedy floorplan
 accumulation order, Algorithm 1's sorted-order power summation), so the
 jitted path stays within the 1e-6 relative parity contract of the scalar
 :func:`repro.core.evaluate.evaluate` — in practice ~1e-15.
-
-* :class:`ScenarioEngine` — the stacked twin for deployment grids: the
-  grid carbon intensity, per-cell normalizer/weight rows and the
-  per-workload tile totals are *runtime* data of the same fused program
-  (tile prefix tables ride in a bucket-padded per-workload stack), so a
-  whole region x workload :class:`~repro.pathfinding.pareto
-  .ScenarioSweep` runs in one ``lax.scan`` with one XLA compile,
-  ``fold_in``-derived per-cell keys, and optional scenario-axis sharding
-  over local devices. A workload may be a whole network of GEMMs
-  (:mod:`repro.core.network`): its layers ride a ``vmap``-ped layer
-  axis of the same program.
 
 The hottest stage-3 inner loop (prefix-table gather + per-slot split-K
 select) can run through the Pallas kernel in
@@ -63,7 +61,7 @@ bit-equal to the jnp gathers; it compiles on TPU and runs in
 interpreter mode elsewhere (exact but slow).
 
 The scalar fallback (``Pathfinder(device=False)`` or any non-CarbonPATH
-objective backend, e.g. ChipletGym) preserves the PR-1 host path.
+objective backend, e.g. ChipletGym) keeps that host path.
 """
 from __future__ import annotations
 
@@ -127,9 +125,8 @@ class _Cfg:
     n_pkg25: int
     n_pkg3: int
     L: int            # fixed link slots: C*(C-1)/2 plane + C-1 chain
-    T0: int           # tiles without split-K
-    T1: int           # tiles with split-K
-    wr_bits: float    # wl.M * wl.N * OPERAND_BYTES * 8
+    T0: int           # tile bucket without split-K (prefix-table bound)
+    T1: int           # tile bucket with split-K
     acost: float
     substrate_cost_mm2: float
     substrate_cfp_mm2: float
@@ -625,7 +622,7 @@ def _topology_jax(v, areas, areas_i, tb, cfg: _Cfg):
 # ---------------------------------------------------------------------------
 
 
-def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
+def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt):
     """Prefix-table gathers for both split-K tables + per-row select.
 
     With ``cfg.pallas_layout`` set, both split-K gathers for all five sim
@@ -634,10 +631,9 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
     prefix_select_gather`) over the packed int32 hi/lo table
     ``tb["pallas_table"]``; its int64 result is bit-equal to the plain
     jnp gathers of the other branch (the reference path). ``rt`` (the
-    stacked scenario engine's per-cell runtime constants) selects the
-    workload-stacked table: the row index picks up the per-workload
-    offset ``wi*A*S*3`` and the clip bounds come from the traced per-cell
-    tile totals instead of ``cfg``.
+    engine's per-cell runtime constants) selects the workload-stacked
+    table: the row index picks up the per-workload offset ``wi*A*S*3``
+    and the clip bounds are the traced per-cell tile totals.
 
     A network engine's layer passes ``rt["gid"]``, the layer's GEMM id
     into the per-GEMM stacks: the jnp gathers index the stacks directly
@@ -647,7 +643,7 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
     import jax.numpy as jnp
 
     split1 = (v[:, COL_SPLITK] == 1)[:, None]
-    gid = None if rt is None else rt.get("gid")
+    gid = rt.get("gid")
     sims = {}
     if cfg.pallas_layout is not None:
         from repro.kernels.prefix_gather import (
@@ -657,15 +653,11 @@ def _gather_sims(v, a_idx, s_idx, di, start, end, tb, cfg: _Cfg, rt=None):
 
         P = v.shape[0]
         ridx = ((a_idx * cfg.S + s_idx) * 3 + di).astype(jnp.int32)
-        if rt is None:
-            t0v = jnp.full((P,), cfg.T0, dtype=jnp.int32)
-            t1v = jnp.full((P,), cfg.T1, dtype=jnp.int32)
-        else:
-            if gid is None:
-                ridx = ridx + jnp.int32(cfg.A * cfg.S * 3) * \
-                    rt["wi"].astype(jnp.int32)
-            t0v = jnp.broadcast_to(rt["T0"].astype(jnp.int32), (P,))
-            t1v = jnp.broadcast_to(rt["T1"].astype(jnp.int32), (P,))
+        if gid is None:
+            ridx = ridx + jnp.int32(cfg.A * cfg.S * 3) * \
+                rt["wi"].astype(jnp.int32)
+        t0v = jnp.broadcast_to(rt["T0"].astype(jnp.int32), (P,))
+        t1v = jnp.broadcast_to(rt["T1"].astype(jnp.int32), (P,))
         if gid is None:
             sel = prefix_select_gather(
                 tb["pallas_table"], ridx, start, end, v[:, COL_SPLITK],
@@ -854,8 +846,7 @@ def _gemm_terms_jax(sims, mn_bits, split, wr_bits, d: _Design, cfg: _Cfg):
     return latency, l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j, loads, macs
 
 
-def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
-                 rt=None):
+def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile, rt):
     """The 13 MetricsBatch arrays for an encoded population, fully jitted.
 
     Mirrors ``BatchEvaluator.__call__`` stage by stage (same operation
@@ -875,11 +866,10 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
     trace-constant ``tb["sched_tab"]``: fixed-schedule programs read
     row 0 (= ``db.load_profile`` verbatim), window programs gather the
     per-design encoded (start_hour, shape_idx) columns — schedules are
-    data, not shapes. ``rt`` optionally
-    overrides the per-workload compile-time constants (``T0``/``T1``
-    tile totals, ``wr_bits``) with traced values — the stacked scenario
-    engine's workload axis; ``cfg.T0``/``cfg.T1`` then only bound the
-    (padded) prefix-table gathers.
+    data, not shapes. ``rt`` holds the cell's per-workload constants as
+    traced values (``T0``/``T1`` tile totals, ``wr_bits``, the workload
+    id ``wi``) — the engine's workload axis; ``cfg.T0``/``cfg.T1`` only
+    bound the (padded) prefix-table gathers.
 
     A network engine's ``rt`` holds the cell's layer table instead:
     ``gid`` (``[L]`` GEMM ids into the per-GEMM stacks) and ``cnt``
@@ -909,12 +899,10 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
 
     powers = tb["t_power_i"][a_idx, t_idx]
     split = v[:, COL_SPLITK]
-    network = rt is not None and "gid" in rt
+    network = "gid" in rt
 
     def gemm_sims(rt_g, shares=None):
-        t0 = cfg.T0 if rt_g is None else rt_g["T0"]
-        t1 = cfg.T1 if rt_g is None else rt_g["T1"]
-        total = jnp.where(split == 1, t1, t0)
+        total = jnp.where(split == 1, rt_g["T1"], rt_g["T0"])
         if shares is None:
             shares = _assign_shares(powers, nmask, v[:, COL_ORDER], cfg)
         start, count = _assign_counts(*shares, total)
@@ -932,9 +920,9 @@ def _metrics_jax(v, tb, cfg: _Cfg, ci, price, embf, profile, pprofile,
     mask = nmask
 
     if not network:
-        wr_bits = cfg.wr_bits if rt is None else rt["wr_bits"]
         (latency, l_cr, l_d2d, l_wr, e_compute_j, e_d2d_j, loads,
-         macs) = _gemm_terms_jax(sims, mn_bits, split, wr_bits, d, cfg)
+         macs) = _gemm_terms_jax(sims, mn_bits, split, rt["wr_bits"], d,
+                                 cfg)
     else:
         d.all()  # the design's arrays, once for every layer
 
@@ -1034,7 +1022,7 @@ def _nb_yield(area, d0: float, alpha: float):
 
 
 def _eval_cost_jax(v, mins, medians, w, ci, price, embf, profile,
-                   pprofile, tb, cfg: _Cfg, rt=None):
+                   pprofile, tb, cfg: _Cfg, rt):
     """Fused metrics + Eq. 17 cost (METRIC_FIELDS column order) + the
     ``OBJECTIVE_AXES`` vector ``(latency_s, dollar, total_cfp)``.
 
@@ -1386,9 +1374,8 @@ def _propose_jax(key, v, tb, cfg: _Cfg, noc_on=None, sched_on=None):
 
 
 def _exchange_fn(inv_t, us, pair_ok):
-    """Adjacent-pair replica-exchange step for ``lax.fori_loop``, shared
-    verbatim by the single-scenario scan and the stacked scenario engine
-    (one definition => the two cannot drift apart).
+    """Adjacent-pair replica-exchange step for ``lax.fori_loop`` (each
+    cell's exchange in the engine's tempering step).
 
     ``d >= 0`` short-circuits in the host loop, so only exp of
     non-positive ``d`` is ever compared; ``pair_ok`` gates swaps across
@@ -1435,18 +1422,6 @@ def _key_from_np(data: np.ndarray, like_key):
         return jax.random.wrap_key_data(jnp.asarray(data))
 
 
-# trailing shapes of the per-sweep trace fields (the zero-sweep edge)
-_TRACE_TAILS = (
-    lambda n, w: (n, w),             # proposals
-    lambda n, w: (n,),               # proposal_costs
-    lambda n, w: (n,),               # u_accept
-    lambda n, w: (max(n - 1, 1),),   # u_swap
-    lambda n, w: (n,),               # accepted
-    lambda n, w: (n,),               # costs
-    lambda n, w: (),                 # best_per_sweep
-)
-
-
 # ---------------------------------------------------------------------------
 # Compile accounting + shared table/cfg builders
 # ---------------------------------------------------------------------------
@@ -1465,19 +1440,18 @@ def _count_trace(name: str) -> None:
 
 def trace_count(name: str) -> int:
     """Traces (= XLA compiles) of the named fused-program family in this
-    process: ``"eval_cost"`` (fused evaluate+cost), ``"pt"`` (the
-    single-scenario tempering scan — one compile per distinct segment
-    length), ``"pt_init"`` (its seed-population eval),
-    ``"scenario_pt"`` / ``"scenario_init"`` (the stacked scenario
-    twins), ``"scenario_eval"`` (the stacked one-shot eval)."""
+    process: ``"scenario_pt"`` (the tempering scan — one compile per
+    distinct shape and segment length), ``"scenario_init"`` (its
+    seed-population eval) and ``"scenario_eval"`` (the one-shot eval).
+    A single-workload :class:`DeviceEvaluator` runs these programs too,
+    as a one-cell grid."""
     return _TRACE_COUNTS.get(name, 0)
 
 
 def _base_cfg(sp: DesignSpace, db: TechDB, T0: int, T1: int,
-              wr_bits: float,
               pallas_layout: Optional[Tuple[int, int, int]]) -> _Cfg:
     """The static trace-time constants shared by every fused program over
-    one (TechDB, DesignSpace) — tile bounds and wr_bits vary per engine."""
+    one (TechDB, DesignSpace) — the tile buckets vary per engine."""
     # the exact tile assignment multiplies a 53-bit share by the tile
     # count in int64
     assert max(T0, T1) < 1024, f"tile counts {T0}/{T1} exceed 1023"
@@ -1489,7 +1463,7 @@ def _base_cfg(sp: DesignSpace, db: TechDB, T0: int, T1: int,
         n_pkg25=len(sp.pkg25_pairs), n_pkg3=len(sp.pkg3_pairs),
         L=sp.max_chiplets * (sp.max_chiplets - 1) // 2
         + sp.max_chiplets - 1,
-        T0=T0, T1=T1, wr_bits=wr_bits,
+        T0=T0, T1=T1,
         acost=db.assembly_cost,
         substrate_cost_mm2=db.substrate_cost_mm2,
         substrate_cfp_mm2=db.substrate_cfp_mm2,
@@ -1521,9 +1495,8 @@ def _base_cfg(sp: DesignSpace, db: TechDB, T0: int, T1: int,
 def _shared_tables(host, sp: DesignSpace) -> dict:
     """Workload-independent jnp tables (chiplet physicals, node rates,
     memory energies, package info, move tables) — identical for every
-    workload and every deployment region over one (db, space), so the
-    single-workload evaluator and the stacked scenario engine share the
-    same table code. Call under ``search_numerics``."""
+    workload and every deployment region over one (db, space). Call
+    under ``search_numerics``."""
     import jax.numpy as jnp
 
     mt = sp.move_tables()
@@ -1561,29 +1534,12 @@ def _shared_tables(host, sp: DesignSpace) -> dict:
     )
 
 
-def _tile_tables(host) -> dict:
-    """Per-workload prefix-sum tables. Call under ``search_numerics``."""
-    import jax.numpy as jnp
-
-    return dict(
-        # [A, S, 3, T+1, 5]: the 5 sim metrics ride in the trailing
-        # axis so one gather fetches all of them
-        pref0=jnp.asarray(np.stack(
-            [host.tiles[0]["pref"][f] for f in _SIM_METRICS], axis=-1)),
-        pref1=jnp.asarray(np.stack(
-            [host.tiles[1]["pref"][f] for f in _SIM_METRICS], axis=-1)),
-        mn0=jnp.asarray(host.tiles[0]["mn_pref"]),
-        mn1=jnp.asarray(host.tiles[1]["mn_pref"]),
-    )
-
-
 def _pallas_table(hosts, tb0: int, tb1: int):
     """The packed prefix-gather kernel table for a workload stack:
     each workload's per-metric ``[5, A*S*3, T+1]`` prefix tables,
     edge-padded to the shared tile buckets ``tb0``/``tb1`` and stacked
     along the row axis, so the kernel row is ``((wi*A + a)*S + s)*3 +
     d``; clip bounds stay at the true (unpadded) per-cell tile totals.
-    A single evaluator is the one-workload stack with unpadded buckets.
     Returns ``(tables dict, layout)``. Call under ``search_numerics``."""
     import jax.numpy as jnp
 
@@ -1616,7 +1572,7 @@ def _pallas_blocks(hosts, tb0: int, tb1: int):
 
 
 # ---------------------------------------------------------------------------
-# The device evaluator + lax.scan tempering engine
+# Results + the kernel switch
 # ---------------------------------------------------------------------------
 
 
@@ -1670,464 +1626,8 @@ def _resolve_pallas(use_pallas: Optional[bool]) -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _db_region_cols(db: TechDB) -> Tuple[np.float64, np.float64,
-                                         np.ndarray, np.ndarray]:
-    """The (price, embf, profile, pprofile) runtime region columns a
-    single-region evaluator synthesizes from its TechDB. A ``None`` grid
-    (price) profile becomes the flat row at ``carbon_intensity``
-    (``electricity_price``) — the in-program corrections
-    ``sum((profile - ci) * load)`` / ``sum((pprofile - price) * load)``
-    are then exactly +0.0, so the default columns are bit-neutral."""
-    price = np.float64(db.electricity_price)
-    embf = np.float64(db.emb_factor)
-    if db.grid_profile is None:
-        profile = np.full(len(db.load_profile),
-                          np.float64(db.carbon_intensity))
-    else:
-        profile = np.asarray(db.grid_profile, dtype=np.float64)
-    if db.price_profile is None:
-        pprofile = np.full(len(db.load_profile), price)
-    else:
-        pprofile = np.asarray(db.price_profile, dtype=np.float64)
-    return price, embf, profile, pprofile
-
-
-class DeviceEvaluator:
-    """Jit-compiled fused evaluate+cost + scan engine for one workload.
-
-    Reuses the host :class:`~repro.pathfinding.batch.BatchEvaluator`'s
-    numpy tables (chiplet physicals, tile prefix sums, package info) and
-    re-expresses stages 2-3 as a single jitted XLA program.
-    """
-
-    def __init__(self, wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
-                 tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
-                 space: Optional[DesignSpace] = None,
-                 use_pallas: Optional[bool] = None):
-        import jax
-        from repro.jaxenv import search_numerics
-
-        self.wl, self.db, self.tile_sizes = wl, db, tile_sizes
-        host = get_evaluator(wl, db, tile_sizes, space)
-        self.host = host
-        self.space = host.space
-        sp = self.space
-        T0, T1 = host.tiles[0]["T"], host.tiles[1]["T"]
-        layout = None
-        with search_numerics():
-            tb = {**_shared_tables(host, sp), **_tile_tables(host)}
-            if _resolve_pallas(use_pallas):
-                pal, layout = _pallas_table([host], T0, T1)
-                tb.update(pal)
-        self.cfg = _base_cfg(
-            sp, db, T0=T0, T1=T1,
-            wr_bits=float(wl.M * wl.N * OPERAND_BYTES * 8),
-            pallas_layout=layout)
-        self.tables = tb
-        cfg = self.cfg
-        # donate the padded population buffer (no-op on CPU, where XLA
-        # cannot reuse host-backed int buffers and would warn)
-        donate = () if jax.default_backend() == "cpu" else (0,)
-
-        def _eval_fn(v, mins, med, w, ci, price, embf, profile, pprofile):
-            _count_trace("eval_cost")
-            return _eval_cost_jax(v, mins, med, w, ci, price, embf,
-                                  profile, pprofile, tb, cfg)
-
-        self._eval_cost_jit = jax.jit(_eval_fn, donate_argnums=donate)
-        self._propose_jit = jax.jit(
-            lambda key, v: _propose_jax(key, v, tb, cfg))
-        self._pt_cache: Dict[tuple, object] = {}
-
-    # -- bucketed fused evaluation -----------------------------------------
-
-    @staticmethod
-    def _pad(encoded: np.ndarray) -> Tuple[np.ndarray, int]:
-        v = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
-        n_real = v.shape[0]
-        bucket = max(64, 1 << (n_real - 1).bit_length())
-        if bucket != n_real:
-            v = np.vstack(
-                [v, np.zeros((bucket - n_real, v.shape[1]), dtype=v.dtype)])
-        return v, n_real
-
-    def evaluate_cost(self, encoded: np.ndarray, norm: Normalizer,
-                      template: Template
-                      ) -> Tuple[MetricsBatch, np.ndarray]:
-        """Fused metrics + Eq. 17 cost for an encoded population.
-
-        Pads to a power-of-two bucket (>= 64) so repeated calls of any
-        size reuse a handful of compiled programs; the padded buffer is
-        donated to the program."""
-        mb, cost, _ = self.evaluate_cost_vector(encoded, norm, template)
-        return mb, cost
-
-    def evaluate_cost_vector(self, encoded: np.ndarray, norm: Normalizer,
-                             template: Template
-                             ) -> Tuple[MetricsBatch, np.ndarray,
-                                        np.ndarray]:
-        """Fused metrics + cost + ``(latency, dollar, total_cfp)`` vectors
-        — all three outputs of one jitted program."""
-        import jax.numpy as jnp
-        from repro.jaxenv import search_numerics
-
-        with search_numerics():
-            v, n_real = self._pad(encoded)
-            mins, medians = norm.weights_arrays()
-            price, embf, profile, pprofile = _db_region_cols(self.db)
-            mets, cost, vec = self._eval_cost_jit(
-                jnp.asarray(v), jnp.asarray(mins), jnp.asarray(medians),
-                jnp.asarray(np.asarray(template.weights, dtype=np.float64)),
-                jnp.asarray(np.float64(self.db.carbon_intensity)),
-                jnp.asarray(price), jnp.asarray(embf), jnp.asarray(profile),
-                jnp.asarray(pprofile))
-            arrs = [np.asarray(m)[:n_real] for m in mets]
-            return (MetricsBatch(*arrs), np.asarray(cost)[:n_real],
-                    np.asarray(vec)[:n_real])
-
-    def metrics(self, encoded: np.ndarray) -> MetricsBatch:
-        """Raw metrics through the jitted path (identity normalizer)."""
-        from repro.core.templates import IDENTITY_NORMALIZER, TEMPLATES
-
-        return self.evaluate_cost(encoded, IDENTITY_NORMALIZER,
-                                  TEMPLATES["T1"])[0]
-
-    def propose(self, encoded: np.ndarray, seed: int = 0) -> np.ndarray:
-        """One vectorized hierarchical move per row (valid rows only)."""
-        import jax
-        import jax.numpy as jnp
-        from repro.jaxenv import search_numerics
-
-        with search_numerics():
-            v = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
-            out = self._propose_jit(jax.random.PRNGKey(seed),
-                                    jnp.asarray(v))
-            return np.asarray(out)
-
-    # -- the fused tempering engine ----------------------------------------
-    #
-    # The sweep loop is *segmented*: a host loop advances the scan in
-    # fixed-size chunks (default: one chunk covering every sweep), with
-    # the carry round-tripping between jit calls. Segment boundaries are
-    # where long searches snapshot their state (see
-    # :mod:`repro.pathfinding.resume`) — and because the per-sweep body,
-    # the key stream (carried through the scan) and the sweep indices
-    # (``sweep0 + arange(seg)``) are identical to the monolithic scan,
-    # segmentation does not change a single bit of the trajectory. Each
-    # distinct segment length compiles once ("pt" in trace_count); the
-    # seed-population evaluation is its own tiny program ("pt_init").
-
-    def _pt_init_fn(self, n: int):
-        key_t = ("init", n)
-        fn = self._pt_cache.get(key_t)
-        if fn is not None:
-            return fn
-        import jax
-
-        tb, cfg = self.tables, self.cfg
-
-        def init(v0, mins, med, w, ci, price, embf, profile, pprofile):
-            _count_trace("pt_init")
-            _, cost0, vec0 = _eval_cost_jax(v0, mins, med, w, ci, price,
-                                            embf, profile, pprofile,
-                                            tb, cfg)
-            return cost0, vec0
-
-        fn = jax.jit(init)
-        self._pt_cache[key_t] = fn
-        return fn
-
-    def _pt_fn(self, n: int, seg: int, swap_every: int,
-               record_trace: bool, collect_samples: bool):
-        key_t = (n, seg, swap_every, record_trace, collect_samples)
-        fn = self._pt_cache.get(key_t)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-
-        tb, cfg = self.tables, self.cfg
-
-        def run(v0, costs0, best_v0, best_c0, key, sweep0, temps, mins,
-                med, w, pair_ok, ci, price, embf, profile, pprofile):
-            _count_trace("pt")
-            inv_t = 1.0 / temps
-
-            def body(carry, sweep):
-                v, costs, best_v, best_c, key = carry
-                key, kp, ka, ksw = jax.random.split(key, 4)
-                prop = _propose_jax(kp, v, tb, cfg)
-                _, pcost, pvec = _eval_cost_jax(prop, mins, med, w, ci,
-                                                price, embf, profile,
-                                                pprofile, tb, cfg)
-                u = jax.random.uniform(ka, (n,), dtype=jnp.float64)
-                delta = pcost - costs
-                accept = (delta <= 0) | (
-                    u < jnp.exp(-delta / jnp.maximum(temps, 1e-12)))
-                v = jnp.where(accept[:, None], prop, v)
-                costs = jnp.where(accept, pcost, costs)
-                acc = jnp.where(accept, pcost, jnp.inf)
-                i = jnp.argmin(acc)
-                better = acc[i] < best_c
-                best_c = jnp.where(better, acc[i], best_c)
-                best_v = jnp.where(better, prop[i], best_v)
-                us = jax.random.uniform(ksw, (max(n - 1, 1),),
-                                        dtype=jnp.float64)
-                do_swap = (sweep % swap_every) == 0
-                ex_body = _exchange_fn(inv_t, us, pair_ok)
-                v, costs = jax.lax.cond(
-                    do_swap,
-                    lambda vc: jax.lax.fori_loop(0, n - 1, ex_body, vc),
-                    lambda vc: vc, (v, costs))
-                ys = (costs[-1], best_c)
-                if collect_samples:
-                    ys = ys + (prop, pvec)
-                if record_trace:
-                    ys = ys + (prop, pcost, u, us, accept, costs)
-                return (v, costs, best_v, best_c, key), ys
-
-            carry, ys = jax.lax.scan(
-                body, (v0, costs0, best_v0, best_c0, key),
-                sweep0 + jnp.arange(seg))
-            return carry, ys
-
-        fn = jax.jit(run)
-        self._pt_cache[key_t] = fn
-        return fn
-
-    def parallel_tempering(self, v0: np.ndarray, temps, sweeps: int,
-                           swap_every: int, seed: int, norm: Normalizer,
-                           template: Template,
-                           record_trace: bool = False,
-                           weights: Optional[np.ndarray] = None,
-                           pair_mask: Optional[np.ndarray] = None,
-                           collect_samples: bool = True,
-                           segment: Optional[int] = None,
-                           checkpoint=None, resume: bool = True,
-                           archive=None) -> DevicePTResult:
-        """Run the fused propose/evaluate/accept/exchange scan.
-
-        ``v0`` is the encoded seed population (one row per chain, coldest
-        chain last as in the host strategy); ``temps`` the matching
-        temperature ladder.
-
-        ``weights`` (``[n, 6]``) gives every chain its own Eq. 17
-        scalarization row (default: ``template.weights`` for all) and
-        ``pair_mask`` (``[max(n-1, 1)]`` bool) disables replica exchange
-        across selected adjacent pairs — together they run K independent
-        scalarization ladders in one program (the
-        :class:`~repro.pathfinding.pareto.ScalarizationSweep` engine).
-        ``collect_samples`` returns every evaluated design + its
-        objective vector in ``.samples`` for Pareto-archive feeding.
-
-        ``segment`` chops the scan into host-driven chunks of that many
-        sweeps (default: one chunk); the chunking is invisible in the
-        results — same key stream, same sweep indices, bit-identical
-        trajectory. ``archive`` (a
-        :class:`~repro.pathfinding.pareto.ParetoArchive`) is fed each
-        segment's samples in place of returning ``.samples``, and
-        ``checkpoint`` (a
-        :class:`~repro.pathfinding.resume.SearchCheckpointer`) snapshots
-        carry + archive + history at every boundary; with ``resume=True``
-        the newest valid snapshot is restored and the run continues to
-        ``sweeps`` (``record_trace`` cannot be combined with
-        checkpointing)."""
-        import jax
-        import jax.numpy as jnp
-        from repro.jaxenv import search_numerics
-
-        with search_numerics():
-            v0 = np.atleast_2d(np.asarray(v0, dtype=np.int32))
-            n, width = v0.shape
-            sweeps = int(sweeps)
-            if segment is not None and int(segment) < 1:
-                raise ValueError(f"segment must be >= 1, got {segment}")
-            seg_size = max(1, sweeps) if segment is None else int(segment)
-            if checkpoint is not None and record_trace:
-                raise ValueError(
-                    "record_trace records host-replay state for the full "
-                    "run and cannot be checkpointed/resumed")
-            if checkpoint is not None and collect_samples and archive is None:
-                raise ValueError(
-                    "checkpointing with collect_samples requires an "
-                    "archive= to feed: bulk .samples live only in process "
-                    "memory and would be lost across a resume")
-            mins, medians = norm.weights_arrays()
-            if weights is None:
-                w = np.tile(np.asarray(template.weights, np.float64), (n, 1))
-            else:
-                w = np.asarray(weights, np.float64)
-                if w.shape != (n, 6):
-                    raise ValueError(
-                        f"weights must be [{n}, 6], got {w.shape}")
-            if pair_mask is None:
-                pair_ok = np.ones(max(n - 1, 1), dtype=bool)
-            else:
-                pair_ok = np.asarray(pair_mask, dtype=bool)
-                if pair_ok.shape != (max(n - 1, 1),):
-                    raise ValueError(
-                        f"pair_mask must be [{max(n - 1, 1)}], "
-                        f"got {pair_ok.shape}")
-            temps_np = np.asarray(temps, np.float64)
-            ci = np.float64(self.db.carbon_intensity)
-            price, embf, profile, pprofile = _db_region_cols(self.db)
-            key0 = jax.random.PRNGKey(seed)
-            args = (jnp.asarray(temps_np), jnp.asarray(mins),
-                    jnp.asarray(medians), jnp.asarray(w),
-                    jnp.asarray(pair_ok), jnp.asarray(ci),
-                    jnp.asarray(price), jnp.asarray(embf),
-                    jnp.asarray(profile), jnp.asarray(pprofile))
-
-            from repro.pathfinding.resume import (
-                run_segmented,
-                segment_fingerprint,
-            )
-
-            fp = None
-            carry_like = None
-            if checkpoint is not None:
-                extra = {}
-                if self.cfg.comm != "legacy":
-                    # non-legacy comm reshapes the encoding + the fused
-                    # program: pre-NoC checkpoints must mismatch cleanly
-                    extra["comm"] = np.frombuffer(
-                        self.cfg.comm.encode(), dtype=np.uint8)
-                if self.cfg.schedule != "fixed":
-                    # the window encoding reshapes the row: pre-schedule
-                    # checkpoints must mismatch cleanly (fixed-schedule
-                    # fingerprints stay byte-identical to pre-PR ones)
-                    extra["schedule"] = np.frombuffer(
-                        self.cfg.schedule.encode(), dtype=np.uint8)
-                if not np.all(pprofile == price):
-                    extra["pprofile"] = pprofile
-                fp = segment_fingerprint(
-                    "device_pt", v0=v0, temps=temps_np,
-                    swap_every=swap_every, seed=seed, mins=mins,
-                    medians=medians, weights=w, pair_mask=pair_ok, ci=ci,
-                    segment=segment, collect=collect_samples,
-                    price=price, embf=embf, profile=profile, **extra)
-                carry_like = dict(
-                    v=np.zeros((n, width), np.int32),
-                    costs=np.zeros(n, np.float64),
-                    best_v=np.zeros(width, np.int32),
-                    best_c=np.zeros((), np.float64),
-                    key=_key_to_np(key0))
-
-            # mutable host state the shared driver's hooks close over
-            st = dict(history=None, seed_block=None, cost0_np=None)
-            enc_parts, vec_parts, trace_parts = [], [], []
-
-            def fresh():
-                cost0, vec0 = self._pt_init_fn(n)(
-                    jnp.asarray(v0), args[1], args[2], args[3], args[5],
-                    args[6], args[7], args[8], args[9])
-                cost0_np = np.asarray(cost0)
-                st["cost0_np"] = cost0_np
-                bi = int(np.argmin(cost0_np))
-                st["history"] = [float(cost0_np.min())]
-                if collect_samples:
-                    st["seed_block"] = (v0[None], np.asarray(vec0)[None])
-                return (jnp.asarray(v0), cost0, jnp.asarray(v0[bi]),
-                        cost0[bi], key0)
-
-            def from_restored(r):
-                c = r.carry
-                st["history"] = r.history.tolist()
-                return (jnp.asarray(c["v"]), jnp.asarray(c["costs"]),
-                        jnp.asarray(c["best_v"]), jnp.asarray(c["best_c"]),
-                        _key_from_np(c["key"], key0))
-
-            def run_segment(carry, done, seg):
-                fn = self._pt_fn(n, seg, int(swap_every),
-                                 bool(record_trace), bool(collect_samples))
-                return fn(*carry, np.int64(done), *args)
-
-            def absorb(ys, seg):
-                # copy back only the outputs this call reads
-                off = 4 if collect_samples else 2
-                want = [0] + [2, 3] * collect_samples
-                if record_trace:
-                    want += [*range(off, off + 6), 1]
-                with span("repro.segment.fetch") as sp:
-                    h = {i: np.asarray(ys[i]) for i in want}
-                    sp.set_metadata(bytes=nbytes(h.values()))
-                st["history"].extend(h[0].tolist())
-                rows = 0
-                if collect_samples:
-                    enc_s, vec_s = h[2], h[3]
-                    if archive is not None:
-                        if st["seed_block"] is not None:
-                            enc_s = np.concatenate(
-                                [st["seed_block"][0], enc_s])
-                            vec_s = np.concatenate(
-                                [st["seed_block"][1], vec_s])
-                            st["seed_block"] = None
-                        archive.insert(enc_s.reshape(-1, width),
-                                       vec_s.reshape(-1, vec_s.shape[-1]))
-                    else:
-                        enc_parts.append(enc_s)
-                        vec_parts.append(vec_s)
-                    rows = enc_s.size // width
-                if record_trace:
-                    trace_parts.append(
-                        tuple(h[i] for i in range(off, off + 6)) + (h[1],))
-                return rows
-
-            def carry_np(carry):
-                return dict(v=np.asarray(carry[0]),
-                            costs=np.asarray(carry[1]),
-                            best_v=np.asarray(carry[2]),
-                            best_c=np.asarray(carry[3]),
-                            key=_key_to_np(carry[4]))
-
-            def flush_seed():
-                if st["seed_block"] is not None and archive is not None:
-                    archive.insert(
-                        st["seed_block"][0].reshape(-1, width),
-                        st["seed_block"][1].reshape(
-                            -1, st["seed_block"][1].shape[-1]))
-                    st["seed_block"] = None
-
-            carry, _ = run_segmented(
-                sweeps=sweeps, seg_size=seg_size, checkpoint=checkpoint,
-                resume=resume, fingerprint=fp, archives=archive,
-                carry_like=carry_like, fresh=fresh,
-                from_restored=from_restored, run_segment=run_segment,
-                absorb=absorb, carry_np=carry_np,
-                history_np=lambda: np.asarray(st["history"], np.float64),
-                sweep_counter=lambda done: done, flush_seed=flush_seed)
-            history, seed_block = st["history"], st["seed_block"]
-
-            v_fin, costs_fin, best_v, best_c, _ = carry
-            samples = None
-            if collect_samples and archive is None:
-                blocks_e = ([seed_block[0]] if seed_block is not None
-                            else []) + enc_parts
-                blocks_v = ([seed_block[1]] if seed_block is not None
-                            else []) + vec_parts
-                if blocks_e:
-                    samples = dict(enc=np.concatenate(blocks_e),
-                                   vec=np.concatenate(blocks_v))
-            trace = None
-            if record_trace:
-                fields = ("proposals", "proposal_costs", "u_accept",
-                          "u_swap", "accepted", "costs", "best_per_sweep")
-                cat = [np.concatenate([p[i] for p in trace_parts])
-                       if trace_parts else
-                       np.zeros((0,) + _TRACE_TAILS[i](n, width))
-                       for i in range(len(fields))]
-                trace = dict(zip(fields, cat))
-                trace["initial_costs"] = st["cost0_np"]
-            return DevicePTResult(
-                best_enc=np.asarray(best_v), best_cost=float(best_c),
-                history=history, evaluations=n + n * sweeps,
-                final_enc=np.asarray(v_fin),
-                final_costs=np.asarray(costs_fin), trace=trace,
-                samples=samples)
-
-
 # ---------------------------------------------------------------------------
-# The stacked scenario engine: one compile for a region x workload grid
+# The tempering engine: one compile for a region x workload grid
 # ---------------------------------------------------------------------------
 
 
@@ -2168,7 +1668,7 @@ class ScenarioPTResult:
 
 
 class ScenarioEngine:
-    """One fused program for a whole scenario grid.
+    """The tempering engine: one fused program for a whole scenario grid.
 
     The per-cell knobs of a (workload x deployment region) sweep are all
     runtime data of the fused evaluate+cost program: the grid carbon
@@ -2177,27 +1677,28 @@ class ScenarioEngine:
     totals / DRAM write-back bits (their prefix tables ride in a stacked,
     tile-bucket-padded lookup indexed by a per-cell workload id). One
     ``lax.scan`` over a ``vmap``-ped per-cell tempering step therefore
-    sweeps the full grid in a *single* XLA compile — where the PR-3 path
-    paid a fresh ``DeviceEvaluator`` build plus full program retrace per
-    region even though only one scalar changed.
+    sweeps the full grid in a *single* XLA compile. A single workload is
+    the one-cell grid: :class:`DeviceEvaluator` is that view, and this
+    class holds the only init, segment and eval programs.
 
-    Per-cell RNG: the scan folds the cell index into the base key
-    (``jax.random.fold_in``), so every cell gets a distinct,
+    Per-cell RNG: the init program folds the cell index into the base
+    key (``jax.random.fold_in``), so every cell gets a distinct,
     deterministic proposal stream that depends only on (seed, cell
-    index) — not on the grid's size or order.
+    index) — not on the grid's size or order. The single-workload view
+    gives its one cell the caller's key itself.
 
     The scenario axis can be sharded across local devices with a mesh
     from :func:`repro.distributed.sharding.scenario_mesh` (pass it as
     ``mesh=``); inputs are placed with their leading axis split over the
     mesh's data axes and XLA partitions the scan accordingly.
 
-    Like the single-workload engine, the grid scan is *segmented*
-    (``segment=`` sweeps per host-driven chunk, bit-invisible) so a
-    multi-thousand-cell sweep checkpoints at boundaries and resumes
-    bit-identically (:mod:`repro.pathfinding.resume`).
+    The grid scan is *segmented* (``segment=`` sweeps per host-driven
+    chunk, bit-invisible) so a multi-thousand-cell sweep checkpoints at
+    boundaries and resumes bit-identically
+    (:mod:`repro.pathfinding.resume`).
 
-    Kernel fast path: like :class:`DeviceEvaluator`, the stacked engine
-    takes ``use_pallas`` (default: the ``REPRO_PATHFINDER_PALLAS``
+    Kernel fast path: the engine takes ``use_pallas`` (default: the
+    ``REPRO_PATHFINDER_PALLAS``
     resolution, see :func:`_resolve_pallas`). When enabled, the gather +
     split-select stage of every cell's tempering step runs through the
     :func:`repro.kernels.prefix_gather.prefix_select_gather` kernel on
@@ -2293,8 +1794,7 @@ class ScenarioEngine:
                 tb[k] for k in ("pref0w", "pref1w", "mn0w", "mn1w",
                                 "pallas_table", "pallas_blocks",
                                 "layer_gemm", "layer_count") if k in tb))
-        self.cfg = _base_cfg(sp, db, T0=tb0, T1=tb1, wr_bits=0.0,
-                             pallas_layout=layout)
+        self.cfg = _base_cfg(sp, db, T0=tb0, T1=tb1, pallas_layout=layout)
         self.tables = tb
         self._fn_cache: Dict[tuple, object] = {}
 
@@ -2311,32 +1811,31 @@ class ScenarioEngine:
                   wr_bits=tb["wrw"][wi], wi=wi)
         return tbc, rt
 
+    # -- one cell's fused evaluate + cost (vmapped over the cells) ---------
+
+    def _eval_cell(self, v_s, mins_s, med_s, w_s, ci_s, price_s, embf_s,
+                   profile_s, pprofile_s, wi, metrics: bool = False):
+        tbc, rt = self._cell_tables(wi)
+        mets, cost, vec = _eval_cost_jax(v_s, mins_s, med_s, w_s, ci_s,
+                                         price_s, embf_s, profile_s,
+                                         pprofile_s, tbc, self.cfg, rt)
+        return (mets, cost, vec) if metrics else (cost, vec)
+
     # -- one-shot stacked evaluation (normalizer fits, finalization) -------
 
-    def _eval_fn(self, S: int, m: int):
-        key_t = ("eval", S, m)
+    def _eval_fn(self, S: int, m: int, metrics: bool = False):
+        key_t = ("eval", S, m, metrics)
         fn = self._fn_cache.get(key_t)
         if fn is not None:
             return fn
         import jax
 
-        cfg = self.cfg
-
         def run(v, mins, med, w, ci, price, embf, profile, pprofile,
                 widx):
             _count_trace("scenario_eval")
-
-            def cell(v_s, mins_s, med_s, w_s, ci_s, price_s, embf_s,
-                     profile_s, pprofile_s, wi):
-                tbc, rt = self._cell_tables(wi)
-                _, cost, vec = _eval_cost_jax(v_s, mins_s, med_s, w_s,
-                                              ci_s, price_s, embf_s,
-                                              profile_s, pprofile_s,
-                                              tbc, cfg, rt)
-                return cost, vec
-
-            return jax.vmap(cell)(v, mins, med, w, ci, price, embf,
-                                  profile, pprofile, widx)
+            return jax.vmap(functools.partial(self._eval_cell,
+                                              metrics=metrics))(
+                v, mins, med, w, ci, price, embf, profile, pprofile, widx)
 
         fn = jax.jit(run)
         self._fn_cache[key_t] = fn
@@ -2353,7 +1852,9 @@ class ScenarioEngine:
         is exactly +0.0) and ``pprofile`` [S, 24] (default
         flat-at-price rows, correction +0.0 too). Always materialized
         so the jitted programs have ONE signature — legacy scalar-CI
-        callers and full six-axis callers share the same compile."""
+        callers and full six-axis callers share the same compile. A
+        one-region caller passes its TechDB's price, embodied factor and
+        (possibly ``None``) grid and price profiles."""
         ci = np.asarray(ci, np.float64).reshape(S)
         price = (np.zeros(S, np.float64) if price is None
                  else np.asarray(price, np.float64).reshape(S))
@@ -2386,6 +1887,14 @@ class ScenarioEngine:
         Returns ``(cost [S, m], vec [S, m, 3])``; the row axis is
         padded to a power-of-two bucket so repeated calls share one
         program."""
+        return self._evaluate(encoded, mins, medians, weights, ci, widx,
+                              price, embf, profile, pprofile)[1:]
+
+    def _evaluate(self, encoded, mins, medians, weights, ci, widx,
+                  price=None, embf=None, profile=None, pprofile=None,
+                  metrics: bool = False):
+        """:meth:`evaluate_cost` after the 13 metric arrays (``[S, m]``
+        each, :class:`MetricsBatch` order; ``None`` unless ``metrics``)."""
         import jax.numpy as jnp
         from repro.jaxenv import search_numerics
 
@@ -2399,8 +1908,8 @@ class ScenarioEngine:
             ci_a = np.asarray(ci, np.float64).reshape(S)
             price_a, embf_a, profile_a, pprofile_a = self._region_cols(
                 S, ci_a, price, embf, profile, pprofile)
-            fn = self._eval_fn(S, mb)
-            cost, vec = fn(
+            fn = self._eval_fn(S, mb, metrics)
+            out = fn(
                 jnp.asarray(v),
                 jnp.asarray(np.asarray(mins, np.float64).reshape(S, 6)),
                 jnp.asarray(np.asarray(medians, np.float64).reshape(S, 6)),
@@ -2409,30 +1918,36 @@ class ScenarioEngine:
                 jnp.asarray(embf_a), jnp.asarray(profile_a),
                 jnp.asarray(pprofile_a),
                 jnp.asarray(np.asarray(widx, np.int32).reshape(S)))
-            return np.asarray(cost)[:, :m], np.asarray(vec)[:, :m]
+            mets = (tuple(np.asarray(x)[:, :m] for x in out[0])
+                    if metrics else None)
+            return (mets, np.asarray(out[-2])[:, :m],
+                    np.asarray(out[-1])[:, :m])
+
+    def _propose(self, encoded: np.ndarray, seed: int = 0) -> np.ndarray:
+        """One vectorized hierarchical move per encoded row (see
+        :func:`_propose_jax`: the space's own move levels; invalid
+        candidates keep the incumbent row)."""
+        import jax
+        import jax.numpy as jnp
+        from repro.jaxenv import search_numerics
+
+        fn = self._fn_cache.get("propose")
+        if fn is None:
+            fn = self._fn_cache["propose"] = jax.jit(
+                lambda key, v: _propose_jax(key, v, self.tables, self.cfg))
+        with search_numerics():
+            v = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
+            return np.asarray(fn(jax.random.PRNGKey(seed), jnp.asarray(v)))
 
     # -- the stacked tempering scan ----------------------------------------
     #
-    # Segmented exactly like :class:`DeviceEvaluator`: a host loop
-    # advances the grid scan in fixed-size chunks with the carry (per-cell
-    # populations, costs, incumbents and fold_in-derived key streams)
-    # round-tripping between jit calls, so a multi-thousand-cell sweep
-    # checkpoints at segment boundaries and resumes bit-identically.
-    # "scenario_init" evaluates the seed populations + folds the per-cell
-    # keys; each distinct segment length compiles one "scenario_pt".
-
-    def _eval_cell_fn(self):
-        cfg = self.cfg
-
-        def eval_cell(v_s, mins_s, med_s, w_s, ci_s, price_s, embf_s,
-                      profile_s, pprofile_s, wi):
-            tbc, rt = self._cell_tables(wi)
-            _, cost, vec = _eval_cost_jax(v_s, mins_s, med_s, w_s, ci_s,
-                                          price_s, embf_s, profile_s,
-                                          pprofile_s, tbc, cfg, rt)
-            return cost, vec
-
-        return eval_cell
+    # A host loop advances the scan in fixed-size chunks with the carry
+    # (per-cell populations, costs, incumbents and key streams)
+    # round-tripping between jit calls; the per-sweep body, the key
+    # stream and the sweep indices are those of a monolithic scan, so
+    # the chunking is bit-invisible. "scenario_init" evaluates the seed
+    # populations + folds the per-cell keys; each distinct segment
+    # length compiles one "scenario_pt".
 
     def _init_fn(self, S: int, n: int):
         key_t = ("init", S, n)
@@ -2442,7 +1957,7 @@ class ScenarioEngine:
         import jax
         import jax.numpy as jnp
 
-        eval_cell = self._eval_cell_fn()
+        eval_cell = self._eval_cell
 
         def init(v0, mins, med, w, ci, price, embf, profile, pprofile,
                  widx, key):
@@ -2459,8 +1974,12 @@ class ScenarioEngine:
         return fn
 
     def _pt_fn(self, S: int, n: int, seg: int, swap_every: int,
-               collect_samples: bool):
-        key_t = ("pt", S, n, seg, swap_every, collect_samples)
+               collect_samples: bool, record_trace: bool = False):
+        """The segment program. ``record_trace`` appends every cell's
+        per-sweep ``(proposals, proposal costs, accept draws, swap draws,
+        accepted, post-exchange costs)`` to the outputs, for a host
+        replay; without it the program carries none of them."""
+        key_t = ("pt", S, n, seg, swap_every, collect_samples, record_trace)
         fn = self._fn_cache.get(key_t)
         if fn is not None:
             return fn
@@ -2468,7 +1987,7 @@ class ScenarioEngine:
         import jax.numpy as jnp
 
         tb, cfg = self.tables, self.cfg
-        eval_cell = self._eval_cell_fn()
+        eval_cell = self._eval_cell
         mesh_comm = cfg.comm == "mesh_noc"
         win_sched = cfg.schedule == "window"
 
@@ -2499,7 +2018,8 @@ class ScenarioEngine:
                 do_swap,
                 lambda vc: jax.lax.fori_loop(0, n - 1, ex_body, vc),
                 lambda vc: vc, (v_s, costs_s))
-            return key_s, v_s, costs_s, cand_v, cand_c, prop, pvec
+            out = (key_s, v_s, costs_s, cand_v, cand_c, prop, pvec)
+            return out + ((pcost, u, us, accept) if record_trace else ())
 
         def _run(v0, costs0, best_v0, best_c0, keys0, sweep0, temps, mins,
                  med, w, pair_ok, ci, price, embf, profile, pprofile,
@@ -2518,18 +2038,21 @@ class ScenarioEngine:
 
             def body(carry, t):
                 v, costs, best_v, best_c, keys = carry
-                keys, v, costs, cand_v, cand_c, prop, pvec = jax.vmap(
+                out = jax.vmap(
                     cell_step,
                     in_axes=(0,) * 18,
                 )(keys, v, costs, temps, inv_t, mins, med, w, pair_ok,
                   ci, price, embf, profile, pprofile, widx, noc_on,
                   sched_on, sweep0 + t)
+                keys, v, costs, cand_v, cand_c, prop, pvec = out[:7]
                 better = cand_c < best_c
                 best_c = jnp.where(better, cand_c, best_c)
                 best_v = jnp.where(better[:, None], cand_v, best_v)
                 ys = (costs[:, -1], best_c)
                 if collect_samples:
                     ys = ys + (prop, pvec)
+                if record_trace:
+                    ys = ys + (prop,) + out[7:] + (costs,)
                 return (v, costs, best_v, best_c, keys), ys
 
             carry, ys = jax.lax.scan(
@@ -2630,27 +2153,21 @@ class ScenarioEngine:
         axis-searching cells share one compile.
         ``mesh`` (optional) shards the scenario axis.
 
-        ``segment``/``checkpoint``/``resume``/``archives`` mirror
-        :meth:`DeviceEvaluator.parallel_tempering`: the grid scan runs in
-        host-driven chunks whose carry (including the per-cell sweep
-        counters and fold_in-derived key streams) plus the per-cell
-        archives snapshot at every boundary, and the chunking never
-        changes a bit of any cell's trajectory. ``archives`` is one
+        ``segment``/``checkpoint``/``resume`` work as in
+        :meth:`DeviceEvaluator.parallel_tempering`, with the per-cell
+        sweep counters and key streams in the carry and one archive per
+        cell: the chunking never changes a bit of any cell's
+        trajectory. ``archives`` is one
         :class:`~repro.pathfinding.pareto.ParetoArchive` per cell, fed
         in place of returning ``.samples``."""
-        import jax
-        import jax.numpy as jnp
         from repro.jaxenv import search_numerics
 
         with search_numerics():
             v0 = np.asarray(v0, dtype=np.int32)
             if v0.ndim != 3:
                 raise ValueError(f"v0 must be [S, n, width], got {v0.shape}")
-            S, n, width = v0.shape
-            sweeps = int(sweeps)
-            if segment is not None and int(segment) < 1:
-                raise ValueError(f"segment must be >= 1, got {segment}")
-            seg_size = max(1, sweeps) if segment is None else int(segment)
+            S = v0.shape[0]
+            seg_size = _seg_size(segment, sweeps)
             if checkpoint is not None and collect_samples \
                     and archives is None:
                 raise ValueError(
@@ -2660,244 +2177,325 @@ class ScenarioEngine:
             if archives is not None and len(archives) != S:
                 raise ValueError(
                     f"need one archive per cell: {len(archives)} != {S}")
-            widx_a = np.asarray(widx, dtype=np.int32).reshape(S)
-            if widx_a.min(initial=0) < 0 or \
-                    widx_a.max(initial=0) >= len(self.workloads):
-                raise ValueError(
-                    f"widx out of range for {len(self.workloads)} workloads")
-            ci_a = np.asarray(ci, np.float64).reshape(S)
-            price_a, embf_a, profile_a, pprofile_a = self._region_cols(
-                S, ci_a, price, embf, profile, pprofile)
-            mesh_comm = self.cfg.comm == "mesh_noc"
-            noc_a = None
-            if mesh_comm:
-                noc_a = (np.full(
-                    S, 1.0 if self.space.noc_live else 0.0, np.float64)
-                    if noc_on is None
-                    else np.asarray(noc_on, np.float64).reshape(S))
-            elif noc_on is not None:
-                raise ValueError(
-                    "noc_on is only meaningful for mesh_noc engines")
-            win_sched = self.cfg.schedule == "window"
-            sched_a = None
-            if win_sched:
-                sched_a = (np.full(
-                    S, 1.0 if self.space.sched_live else 0.0, np.float64)
-                    if sched_on is None
-                    else np.asarray(sched_on, np.float64).reshape(S))
-            elif sched_on is not None:
-                raise ValueError(
-                    "sched_on is only meaningful for window-schedule "
-                    "engines")
-            arrays = dict(
-                v0=v0,
-                temps=np.asarray(temps, np.float64).reshape(S, n),
-                mins=np.asarray(mins, np.float64).reshape(S, 6),
-                med=np.asarray(medians, np.float64).reshape(S, 6),
-                w=np.asarray(weights, np.float64).reshape(S, n, 6),
-                pair_ok=np.asarray(pair_mask, bool).reshape(
-                    S, max(n - 1, 1)),
-                ci=ci_a,
-                price=price_a,
-                embf=embf_a,
-                profile=profile_a,
-                pprofile=pprofile_a,
-                widx=widx_a,
-            )
-            if mesh_comm:
-                arrays["noc_on"] = noc_a
-            if win_sched:
-                arrays["sched_on"] = sched_a
+            arrays = self._grid(v0, temps, mins, medians, weights,
+                                pair_mask, ci, widx, price, embf, profile,
+                                pprofile, noc_on, sched_on)
+            fp = (None if checkpoint is None else self._fingerprint(
+                arrays, seed, swap_every, segment, collect_samples))
+            return self._drive(
+                arrays, seed, sweeps=int(sweeps), seg_size=seg_size,
+                swap_every=swap_every, collect_samples=collect_samples,
+                fingerprint=fp, checkpoint=checkpoint, resume=resume,
+                archives=archives, mesh=mesh)[0]
+
+    def _fingerprint(self, arrays: Dict[str, np.ndarray], seed: int,
+                     swap_every: int, segment: Optional[int],
+                     collect: bool, one_cell: bool = False) -> np.ndarray:
+        """The checkpoint fingerprint of a run over ``arrays`` (see
+        :func:`~repro.pathfinding.resume.segment_fingerprint`): kind
+        ``"scenario_pt"`` for a grid; ``"device_pt"`` over the one
+        cell's columns, without workload ids or move gates, for the
+        single-workload view (``one_cell``)."""
+        from repro.pathfinding.resume import segment_fingerprint
+
+        a = {k: x[0] for k, x in arrays.items()} if one_cell else arrays
+        extra = {} if one_cell else dict(widx=a["widx"])
+        if self.cfg.comm != "legacy":
+            # non-legacy comm reshapes the encoding + the fused
+            # program: pre-NoC checkpoints must mismatch cleanly
+            extra["comm"] = np.frombuffer(self.cfg.comm.encode(),
+                                          dtype=np.uint8)
+            if not one_cell:
+                extra["noc_on"] = a["noc_on"]
+        if self.cfg.schedule != "fixed":
+            # the window encoding reshapes the row the same way:
+            # pre-schedule checkpoints must mismatch cleanly, while
+            # fixed-schedule fingerprints stay byte-identical to ones
+            # written before the schedule axis existed
+            extra["schedule"] = np.frombuffer(self.cfg.schedule.encode(),
+                                              dtype=np.uint8)
+            if not one_cell:
+                extra["sched_on"] = a["sched_on"]
+        if not np.all(a["pprofile"] == a["price"][..., None]):
+            extra["pprofile"] = a["pprofile"]
+        return segment_fingerprint(
+            "device_pt" if one_cell else "scenario_pt", v0=a["v0"],
+            temps=a["temps"], swap_every=swap_every, seed=seed,
+            mins=a["mins"], medians=a["med"], weights=a["w"],
+            pair_mask=a["pair_ok"], ci=a["ci"], segment=segment,
+            collect=collect, price=a["price"], embf=a["embf"],
+            profile=a["profile"], **extra)
+
+    def _grid(self, v0, temps, mins, medians, weights, pair_mask, ci, widx,
+              price, embf, profile, pprofile, noc_on, sched_on
+              ) -> Dict[str, np.ndarray]:
+        """The checked per-cell host columns of a grid run, omitted
+        regional axes and move gates synthesized (``noc_on`` only on
+        mesh_noc engines, ``sched_on`` only on window ones)."""
+        S, n, _ = v0.shape
+        widx_a = np.asarray(widx, dtype=np.int32).reshape(S)
+        if widx_a.min(initial=0) < 0 or \
+                widx_a.max(initial=0) >= len(self.workloads):
+            raise ValueError(
+                f"widx out of range for {len(self.workloads)} workloads")
+        ci_a = np.asarray(ci, np.float64).reshape(S)
+        price_a, embf_a, profile_a, pprofile_a = self._region_cols(
+            S, ci_a, price, embf, profile, pprofile)
+        arrays = dict(
+            v0=v0,
+            temps=np.asarray(temps, np.float64).reshape(S, n),
+            mins=np.asarray(mins, np.float64).reshape(S, 6),
+            med=np.asarray(medians, np.float64).reshape(S, 6),
+            w=np.asarray(weights, np.float64).reshape(S, n, 6),
+            pair_ok=np.asarray(pair_mask, bool).reshape(
+                S, max(n - 1, 1)),
+            ci=ci_a,
+            price=price_a,
+            embf=embf_a,
+            profile=profile_a,
+            pprofile=pprofile_a,
+            widx=widx_a,
+        )
+        if self.cfg.comm == "mesh_noc":
+            arrays["noc_on"] = (np.full(
+                S, 1.0 if self.space.noc_live else 0.0, np.float64)
+                if noc_on is None
+                else np.asarray(noc_on, np.float64).reshape(S))
+        elif noc_on is not None:
+            raise ValueError(
+                "noc_on is only meaningful for mesh_noc engines")
+        if self.cfg.schedule == "window":
+            arrays["sched_on"] = (np.full(
+                S, 1.0 if self.space.sched_live else 0.0, np.float64)
+                if sched_on is None
+                else np.asarray(sched_on, np.float64).reshape(S))
+        elif sched_on is not None:
+            raise ValueError(
+                "sched_on is only meaningful for window-schedule "
+                "engines")
+        return arrays
+
+    def _drive(self, arrays: Dict[str, np.ndarray], seed: int, *,
+               sweeps: int, seg_size: int, swap_every: int,
+               collect_samples: bool, fingerprint=None, checkpoint=None,
+               resume: bool = True, archives=None, mesh=None, keys=None,
+               record_trace: bool = False, unbatched: bool = False):
+        """The host segment loop over the init and segment programs
+        (:func:`~repro.pathfinding.resume.run_segmented`), for the grid
+        and for the single-workload view.
+
+        ``keys`` (``[S]`` PRNG keys) replaces the init program's
+        ``fold_in(PRNGKey(seed), cell)`` cell keys. ``record_trace``
+        collects the segment program's replay fields. ``unbatched``
+        keeps a one-cell search's checkpoints in the single-workload
+        layout: the carry without its cell axis (``key`` for ``keys``),
+        a flat history and a scalar sweep counter. Returns
+        ``(ScenarioPTResult, trace)``; the trace (``None`` unless
+        ``record_trace``) has a cell axis after its sweep axis."""
+        import jax
+        import jax.numpy as jnp
+        from repro.pathfinding.resume import run_segmented
+
+        v0 = arrays["v0"]
+        S, n, width = v0.shape
+        if mesh is not None:
+            from repro.distributed.sharding import shard_scenarios
+
+            arrays = shard_scenarios(arrays, mesh)
+        key0 = jax.random.PRNGKey(seed)
+        with span("repro.pt.prepare", cells=S, chains=n,
+                  layers=self.layers):
+            args = tuple(jnp.asarray(arrays[k]) for k in (
+                "temps", "mins", "med", "w", "pair_ok", "ci", "price",
+                "embf", "profile", "pprofile", "widx", "noc_on",
+                "sched_on") if k in arrays)
+
+        st = dict(hist_parts=None, seed_block=None, cost0=None,
+                  sweep_done=np.zeros(S, dtype=np.int64))
+        enc_parts, vec_parts, trace_parts = [], [], []
+
+        def feed_cells(enc_s, vec_s):
+            for s in range(S):
+                archives[s].insert(
+                    enc_s[:, s].reshape(-1, width),
+                    vec_s[:, s].reshape(-1, vec_s.shape[-1]))
+
+        def fresh():
+            keys0, cost0, vec0 = self._init_fn(S, n)(
+                jnp.asarray(arrays["v0"]), args[1], args[2], args[3],
+                args[5], args[6], args[7], args[8], args[9],
+                args[10], key0)
+            if keys is not None:
+                keys0 = keys
+            bi0 = jnp.argmin(cost0, axis=1)
+            best_v0 = jnp.take_along_axis(
+                jnp.asarray(arrays["v0"]), bi0[:, None, None],
+                axis=1)[:, 0]
+            best_c0 = jnp.take_along_axis(
+                cost0, bi0[:, None], axis=1)[:, 0]
+            st["cost0"] = np.asarray(cost0)
+            st["hist_parts"] = [np.min(st["cost0"], axis=1)[:, None]]
+            if collect_samples:
+                st["seed_block"] = (v0[None], np.asarray(vec0)[None])
+            return (jnp.asarray(arrays["v0"]), cost0, best_v0,
+                    best_c0, keys0)
+
+        def from_restored(r):
+            c = _cell_axis(r.carry) if unbatched else dict(r.carry)
             if mesh is not None:
+                # the fresh path's carry inherits the scenario-axis
+                # sharding from `arrays`; the restored one comes from
+                # host numpy and must be re-placed, or the first
+                # post-resume segment jits a second (unsharded)
+                # program signature
                 from repro.distributed.sharding import shard_scenarios
 
-                arrays = shard_scenarios(arrays, mesh)
-            key0 = jax.random.PRNGKey(seed)
-            with span("repro.pt.prepare", cells=S, chains=n,
-                      layers=self.layers):
-                args = tuple(jnp.asarray(arrays[k]) for k in (
-                    "temps", "mins", "med", "w", "pair_ok", "ci", "price",
-                    "embf", "profile", "pprofile", "widx"))
-                if mesh_comm:
-                    args = args + (jnp.asarray(arrays["noc_on"]),)
-                if win_sched:
-                    args = args + (jnp.asarray(arrays["sched_on"]),)
+                c = shard_scenarios(c, mesh)
+            st["sweep_done"] = np.asarray(
+                r.sweep_done_per_cell, dtype=np.int64).reshape(S)
+            st["hist_parts"] = [r.history.reshape(S, -1)]
+            return (jnp.asarray(c["v"]), jnp.asarray(c["costs"]),
+                    jnp.asarray(c["best_v"]), jnp.asarray(c["best_c"]),
+                    _key_from_np(c["keys"], key0))
 
-            from repro.pathfinding.resume import (
-                run_segmented,
-                segment_fingerprint,
-            )
+        # a traced run alone names its flag: stand-ins for the segment
+        # program (bench/faults.py) take the five shape arguments
+        trace_kw = dict(record_trace=True) if record_trace else {}
 
-            fp = None
-            carry_like = None
-            if checkpoint is not None:
-                key_np = _key_to_np(key0)
-                extra = {}
-                if self.cfg.comm != "legacy":
-                    # non-legacy comm reshapes the encoding + the fused
-                    # program: pre-NoC checkpoints must mismatch cleanly
-                    extra["comm"] = np.frombuffer(
-                        self.cfg.comm.encode(), dtype=np.uint8)
-                    extra["noc_on"] = noc_a
-                if self.cfg.schedule != "fixed":
-                    # the window encoding reshapes the row the same way:
-                    # pre-schedule checkpoints must mismatch cleanly,
-                    # while fixed-schedule fingerprints stay byte-
-                    # identical to pre-PR ones
-                    extra["schedule"] = np.frombuffer(
-                        self.cfg.schedule.encode(), dtype=np.uint8)
-                    extra["sched_on"] = sched_a
-                if not np.all(pprofile_a == price_a[:, None]):
-                    extra["pprofile"] = pprofile_a
-                fp = segment_fingerprint(
-                    "scenario_pt", v0=v0, temps=arrays["temps"],
-                    swap_every=swap_every, seed=seed,
-                    mins=arrays["mins"], medians=arrays["med"],
-                    weights=arrays["w"], pair_mask=arrays["pair_ok"],
-                    ci=arrays["ci"], segment=segment,
-                    collect=collect_samples, widx=widx_a,
-                    price=price_a, embf=embf_a, profile=profile_a,
-                    **extra)
-                carry_like = dict(
-                    v=np.zeros((S, n, width), np.int32),
-                    costs=np.zeros((S, n), np.float64),
-                    best_v=np.zeros((S, width), np.int32),
-                    best_c=np.zeros(S, np.float64),
-                    keys=np.zeros((S,) + key_np.shape, key_np.dtype))
+        def run_segment(carry, done, seg):
+            fn = self._pt_fn(S, n, seg, int(swap_every),
+                             bool(collect_samples), **trace_kw)
+            if mesh is not None:
+                # re-pin the carry to the scenario sharding: outputs
+                # may come back laid out otherwise, and a second
+                # layout would compile the segment program again
+                from repro.distributed.sharding import shard_scenarios
 
-            st = dict(hist_parts=None, seed_block=None,
-                      sweep_done=np.zeros(S, dtype=np.int64))
-            enc_parts, vec_parts = [], []
+                carry = tuple(shard_scenarios(
+                    dict(enumerate(carry)), mesh).values())
+            return fn(*carry, jnp.asarray(st["sweep_done"]), *args)
 
-            def feed_cells(enc_s, vec_s):
-                for s in range(S):
-                    archives[s].insert(
-                        enc_s[:, s].reshape(-1, width),
-                        vec_s[:, s].reshape(-1, vec_s.shape[-1]))
+        off = 4 if collect_samples else 2
 
-            def fresh():
-                keys0, cost0, vec0 = self._init_fn(S, n)(
-                    jnp.asarray(arrays["v0"]), args[1], args[2], args[3],
-                    args[5], args[6], args[7], args[8], args[9],
-                    args[10], key0)
-                bi0 = jnp.argmin(cost0, axis=1)
-                best_v0 = jnp.take_along_axis(
-                    jnp.asarray(arrays["v0"]), bi0[:, None, None],
-                    axis=1)[:, 0]
-                best_c0 = jnp.take_along_axis(
-                    cost0, bi0[:, None], axis=1)[:, 0]
-                st["hist_parts"] = [
-                    np.min(np.asarray(cost0), axis=1)[:, None]]
-                if collect_samples:
-                    st["seed_block"] = (v0[None], np.asarray(vec0)[None])
-                return (jnp.asarray(arrays["v0"]), cost0, best_v0,
-                        best_c0, keys0)
-
-            def from_restored(r):
-                c = dict(r.carry)
-                if mesh is not None:
-                    # the fresh path's carry inherits the scenario-axis
-                    # sharding from `arrays`; the restored one comes from
-                    # host numpy and must be re-placed, or the first
-                    # post-resume segment jits a second (unsharded)
-                    # program signature
-                    from repro.distributed.sharding import shard_scenarios
-
-                    c = shard_scenarios(c, mesh)
-                st["sweep_done"] = np.asarray(
-                    r.sweep_done_per_cell, dtype=np.int64).reshape(S)
-                st["hist_parts"] = [r.history.reshape(S, -1)]
-                return (jnp.asarray(c["v"]), jnp.asarray(c["costs"]),
-                        jnp.asarray(c["best_v"]), jnp.asarray(c["best_c"]),
-                        _key_from_np(c["keys"], key0))
-
-            def run_segment(carry, done, seg):
-                fn = self._pt_fn(S, n, seg, int(swap_every),
-                                 bool(collect_samples))
-                if mesh is not None:
-                    # re-pin the carry to the scenario sharding: outputs
-                    # may come back laid out otherwise, and a second
-                    # layout would compile the segment program again
-                    from repro.distributed.sharding import shard_scenarios
-
-                    carry = tuple(shard_scenarios(
-                        dict(enumerate(carry)), mesh).values())
-                return fn(*carry, jnp.asarray(st["sweep_done"]), *args)
-
-            def absorb(ys, seg):
-                with span("repro.segment.fetch") as sp:
-                    h = [np.asarray(y) for y in
-                         (ys[:1] + ys[2:4] if collect_samples else ys[:1])]
-                    sp.set_metadata(bytes=nbytes(h))
-                st["hist_parts"].append(h[0].T)
-                rows = 0
-                if collect_samples:
-                    enc_s, vec_s = h[1], h[2]
-                    if st["seed_block"] is not None:
-                        enc_s = np.concatenate(
-                            [st["seed_block"][0], enc_s])
-                        vec_s = np.concatenate(
-                            [st["seed_block"][1], vec_s])
-                        st["seed_block"] = None
-                    if archives is not None:
-                        feed_cells(enc_s, vec_s)
-                    else:
-                        enc_parts.append(enc_s)
-                        vec_parts.append(vec_s)
-                    rows = enc_s.size // width
-                st["sweep_done"] = st["sweep_done"] + seg
-                return rows
-
-            def carry_np(carry):
-                return dict(v=np.asarray(carry[0]),
-                            costs=np.asarray(carry[1]),
-                            best_v=np.asarray(carry[2]),
-                            best_c=np.asarray(carry[3]),
-                            keys=_key_to_np(carry[4]))
-
-            def flush_seed():
-                if st["seed_block"] is not None and archives is not None:
-                    feed_cells(*st["seed_block"])
+        def absorb(ys, seg):
+            # copy back only the outputs this call reads
+            want = [0] + [2, 3] * collect_samples
+            if record_trace:
+                want += [*range(off, off + 6), 1]
+            with span("repro.segment.fetch") as sp:
+                h = {i: np.asarray(ys[i]) for i in want}
+                sp.set_metadata(bytes=nbytes(h.values()))
+            st["hist_parts"].append(h[0].T)
+            rows = 0
+            if collect_samples:
+                enc_s, vec_s = h[2], h[3]
+                if st["seed_block"] is not None:
+                    enc_s = np.concatenate(
+                        [st["seed_block"][0], enc_s])
+                    vec_s = np.concatenate(
+                        [st["seed_block"][1], vec_s])
                     st["seed_block"] = None
+                if archives is not None:
+                    feed_cells(enc_s, vec_s)
+                else:
+                    enc_parts.append(enc_s)
+                    vec_parts.append(vec_s)
+                rows = enc_s.size // width
+            if record_trace:
+                trace_parts.append(
+                    tuple(h[i] for i in range(off, off + 6)) + (h[1],))
+            st["sweep_done"] = st["sweep_done"] + seg
+            return rows
 
-            # the mesh in context lets the gather kernel (which XLA
-            # cannot partition) run per device under a shard_map
-            with (jax.set_mesh(mesh) if mesh is not None
-                  else contextlib.nullcontext()):
-                carry, _ = run_segmented(
-                    sweeps=sweeps, seg_size=seg_size,
-                    checkpoint=checkpoint, resume=resume, fingerprint=fp,
-                    archives=archives, carry_like=carry_like, fresh=fresh,
-                    from_restored=from_restored, run_segment=run_segment,
-                    absorb=absorb, carry_np=carry_np,
-                    history_np=lambda: np.concatenate(
-                        st["hist_parts"], axis=1),
-                    sweep_counter=lambda done: st["sweep_done"],
-                    flush_seed=flush_seed)
-            hist_parts, seed_block = st["hist_parts"], st["seed_block"]
+        def carry_np(carry):
+            c = dict(v=np.asarray(carry[0]), costs=np.asarray(carry[1]),
+                     best_v=np.asarray(carry[2]),
+                     best_c=np.asarray(carry[3]),
+                     keys=_key_to_np(carry[4]))
+            return _one_cell(c) if unbatched else c
 
-            v_fin, costs_fin, best_v, best_c, _ = carry
-            samples = None
-            if collect_samples and archives is None:
-                blocks_e = ([seed_block[0]] if seed_block is not None
-                            else []) + enc_parts
-                blocks_v = ([seed_block[1]] if seed_block is not None
-                            else []) + vec_parts
-                if blocks_e:
-                    samples = dict(enc=np.concatenate(blocks_e),
-                                   vec=np.concatenate(blocks_v))
-            return ScenarioPTResult(
-                best_enc=np.asarray(best_v),
-                best_cost=np.asarray(best_c),
-                history=np.concatenate(hist_parts, axis=1),
-                evaluations=S * n * (1 + sweeps),
-                final_enc=np.asarray(v_fin),
-                final_costs=np.asarray(costs_fin),
-                samples=samples)
+        key_np = _key_to_np(key0)
+        carry_like = None if checkpoint is None else carry_np((
+            np.zeros((S, n, width), np.int32), np.zeros((S, n)),
+            np.zeros((S, width), np.int32), np.zeros(S),
+            np.zeros((S,) + key_np.shape, key_np.dtype)))
+
+        def history_np():
+            h = np.concatenate(st["hist_parts"], axis=1)
+            return h[0] if unbatched else h
+
+        def flush_seed():
+            if st["seed_block"] is not None and archives is not None:
+                feed_cells(*st["seed_block"])
+                st["seed_block"] = None
+
+        # the mesh in context lets the gather kernel (which XLA
+        # cannot partition) run per device under a shard_map
+        with (jax.set_mesh(mesh) if mesh is not None
+              else contextlib.nullcontext()):
+            carry, _ = run_segmented(
+                sweeps=sweeps, seg_size=seg_size,
+                checkpoint=checkpoint, resume=resume,
+                fingerprint=fingerprint, archives=archives,
+                carry_like=carry_like, fresh=fresh,
+                from_restored=from_restored, run_segment=run_segment,
+                absorb=absorb, carry_np=carry_np, history_np=history_np,
+                sweep_counter=lambda done: (st["sweep_done"][0] if unbatched
+                                            else st["sweep_done"]),
+                flush_seed=flush_seed)
+        seed_block = st["seed_block"]
+
+        v_fin, costs_fin, best_v, best_c, _ = carry
+        samples = None
+        if collect_samples and archives is None:
+            blocks_e = ([seed_block[0]] if seed_block is not None
+                        else []) + enc_parts
+            blocks_v = ([seed_block[1]] if seed_block is not None
+                        else []) + vec_parts
+            if blocks_e:
+                samples = dict(enc=np.concatenate(blocks_e),
+                               vec=np.concatenate(blocks_v))
+        trace = None
+        if record_trace:
+            fields = ("proposals", "proposal_costs", "u_accept", "u_swap",
+                      "accepted", "costs", "best_per_sweep")
+            # per-sweep shapes, for the zero-sweep edge
+            tails = ((n, width), (n,), (n,), (max(n - 1, 1),), (n,), (n,), ())
+            trace = {f: (np.concatenate([p[i] for p in trace_parts])
+                         if trace_parts else np.zeros((0, S) + tails[i]))
+                     for i, f in enumerate(fields)}
+            trace["initial_costs"] = st["cost0"]
+        return ScenarioPTResult(
+            best_enc=np.asarray(best_v),
+            best_cost=np.asarray(best_c),
+            history=np.concatenate(st["hist_parts"], axis=1),
+            evaluations=S * n * (1 + sweeps),
+            final_enc=np.asarray(v_fin),
+            final_costs=np.asarray(costs_fin),
+            samples=samples), trace
+
+
+def _seg_size(segment: Optional[int], sweeps: int) -> int:
+    """Sweeps per host-driven chunk (default: one chunk for the run)."""
+    if segment is not None and int(segment) < 1:
+        raise ValueError(f"segment must be >= 1, got {segment}")
+    return max(1, int(sweeps)) if segment is None else int(segment)
+
+
+def _one_cell(carry: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """A one-cell carry in the single-workload checkpoint layout."""
+    return {("key" if k == "keys" else k): a[0] for k, a in carry.items()}
+
+
+def _cell_axis(carry: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`_one_cell`."""
+    return {("keys" if k == "key" else k): np.asarray(a)[None]
+            for k, a in carry.items()}
 
 
 _SCENARIO_ENGINES: Dict[tuple, Tuple[TechDB, "ScenarioEngine"]] = {}
-_SCENARIO_ENGINE_CACHE_MAX = 4
+# grid engines and the one-workload engines under DeviceEvaluator share it
+_SCENARIO_ENGINE_CACHE_MAX = 12
 
 
 def get_scenario_engine(workloads: Sequence[Workload],
@@ -2907,10 +2505,12 @@ def get_scenario_engine(workloads: Sequence[Workload],
                         use_pallas: Optional[bool] = None
                         ) -> ScenarioEngine:
     """Cached :class:`ScenarioEngine` per (workload tuple, db, tiles,
-    chiplet bound) — the stacked twin of :func:`get_device_evaluator`.
-    Workloads may be GEMMs or networks.
+    chiplet bound) — jit warmup is expensive, so grids and
+    single-workload views (:func:`get_device_evaluator`) share engines
+    like ``get_evaluator`` shares host tables. Workloads may be GEMMs or
+    networks.
 
-    Like that twin, the resolved Pallas setting (``use_pallas``, else
+    The resolved Pallas setting (``use_pallas``, else
     :func:`_resolve_pallas`) is part of the key, so flipping
     ``REPRO_PATHFINDER_PALLAS`` mid-process builds a fresh engine
     instead of silently returning the cached other-path one.
@@ -2940,31 +2540,156 @@ def get_scenario_engine(workloads: Sequence[Workload],
 
 
 # ---------------------------------------------------------------------------
-# module-level evaluator cache + functional entry points
+# The single-workload view + functional entry points
 # ---------------------------------------------------------------------------
 
-_DEVICE_EVALUATORS: Dict[tuple, Tuple[TechDB, DeviceEvaluator]] = {}
-_DEVICE_EVALUATOR_CACHE_MAX = 8
+
+class DeviceEvaluator:
+    """One workload's view of :class:`ScenarioEngine`: the cached
+    one-workload engine's programs (:func:`get_scenario_engine`) run as
+    a one-cell grid, the cell's key the caller's ``PRNGKey(seed)`` and
+    its region the TechDB's own. ``space``, ``cfg`` and ``tables`` are
+    the engine's; the view builds no program of its own."""
+
+    def __init__(self, wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
+                 tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
+                 space: Optional[DesignSpace] = None,
+                 use_pallas: Optional[bool] = None):
+        self.wl, self.db, self.tile_sizes = wl, db, tile_sizes
+        self.engine = get_scenario_engine((wl,), db, tile_sizes, space,
+                                          use_pallas)
+        self.space, self.cfg, self.tables = (
+            self.engine.space, self.engine.cfg, self.engine.tables)
+
+    def _region(self) -> dict:
+        """The TechDB's region as one cell's columns: a ``None`` grid
+        (price) profile becomes the flat row at ``carbon_intensity``
+        (``electricity_price``), whose in-program correction is +0.0."""
+        db = self.db
+        return dict(ci=[db.carbon_intensity], price=[db.electricity_price],
+                    embf=[db.emb_factor], profile=db.grid_profile,
+                    pprofile=db.price_profile)
+
+    def evaluate_cost(self, encoded: np.ndarray, norm: Normalizer,
+                      template: Template
+                      ) -> Tuple[MetricsBatch, np.ndarray]:
+        """Fused metrics + Eq. 17 cost for an encoded population (rows
+        padded to a power-of-two bucket >= 64: a handful of programs)."""
+        return self.evaluate_cost_vector(encoded, norm, template)[:2]
+
+    def evaluate_cost_vector(self, encoded: np.ndarray, norm: Normalizer,
+                             template: Template
+                             ) -> Tuple[MetricsBatch, np.ndarray,
+                                        np.ndarray]:
+        """Fused metrics + cost + ``(latency, dollar, total_cfp)`` vectors
+        — all three outputs of the engine's one eval program."""
+        v = np.atleast_2d(np.asarray(encoded, dtype=np.int32))
+        mins, medians = norm.weights_arrays()
+        mets, cost, vec = self.engine._evaluate(
+            v[None], mins, medians, template.weights, widx=[0],
+            metrics=True, **self._region())
+        return MetricsBatch(*(m[0] for m in mets)), cost[0], vec[0]
+
+    def metrics(self, encoded: np.ndarray) -> MetricsBatch:
+        """Raw metrics through the jitted path (identity normalizer)."""
+        from repro.core.templates import IDENTITY_NORMALIZER, TEMPLATES
+
+        return self.evaluate_cost(encoded, IDENTITY_NORMALIZER,
+                                  TEMPLATES["T1"])[0]
+
+    def propose(self, encoded: np.ndarray, seed: int = 0) -> np.ndarray:
+        """One vectorized hierarchical move per row (valid rows only)."""
+        return self.engine._propose(encoded, seed)
+
+    def parallel_tempering(self, v0: np.ndarray, temps, sweeps: int,
+                           swap_every: int, seed: int, norm: Normalizer,
+                           template: Template,
+                           record_trace: bool = False,
+                           weights: Optional[np.ndarray] = None,
+                           pair_mask: Optional[np.ndarray] = None,
+                           collect_samples: bool = True,
+                           segment: Optional[int] = None,
+                           checkpoint=None, resume: bool = True,
+                           archive=None) -> DevicePTResult:
+        """Run the fused propose/evaluate/accept/exchange scan.
+
+        ``v0`` is the encoded seed population (one row per chain, coldest
+        last as in the host strategy), ``temps`` its ladder. ``weights``
+        (``[n, 6]``, default ``template.weights``) gives each chain its
+        own Eq. 17 row and ``pair_mask`` (``[max(n-1, 1)]`` bool) turns
+        off exchange across chosen adjacent pairs: K independent
+        scalarization ladders in one program
+        (:class:`~repro.pathfinding.pareto.ScalarizationSweep`).
+        ``collect_samples`` returns every evaluated design and its
+        objective vector in ``.samples``, or feeds them to ``archive`` (a
+        :class:`~repro.pathfinding.pareto.ParetoArchive`) segment by
+        segment. ``segment`` chops the scan into host-driven chunks
+        (default: one), invisible in the results; ``checkpoint`` (a
+        :class:`~repro.pathfinding.resume.SearchCheckpointer`) snapshots
+        carry, archive and history at every boundary, and with
+        ``resume=True`` the newest valid snapshot is restored and the run
+        continues to ``sweeps``. ``record_trace`` returns every proposal
+        and draw for a host replay (not with a checkpoint)."""
+        import jax
+        from repro.jaxenv import search_numerics
+
+        with search_numerics():
+            v0 = np.atleast_2d(np.asarray(v0, dtype=np.int32))
+            n = v0.shape[0]
+            seg_size = _seg_size(segment, sweeps)
+            if checkpoint is not None and record_trace:
+                raise ValueError(
+                    "record_trace records host-replay state for the full "
+                    "run and cannot be checkpointed/resumed")
+            if checkpoint is not None and collect_samples and archive is None:
+                raise ValueError(
+                    "checkpointing with collect_samples requires an "
+                    "archive= to feed: bulk .samples live only in process "
+                    "memory and would be lost across a resume")
+            mins, medians = norm.weights_arrays()
+            w = (np.tile(np.asarray(template.weights, np.float64), (n, 1))
+                 if weights is None else np.asarray(weights, np.float64))
+            if w.shape != (n, 6):
+                raise ValueError(f"weights must be [{n}, 6], got {w.shape}")
+            pair_ok = np.asarray(np.ones(max(n - 1, 1)) if pair_mask is None
+                                 else pair_mask, dtype=bool)
+            if pair_ok.shape != (max(n - 1, 1),):
+                raise ValueError(f"pair_mask must be [{max(n - 1, 1)}], "
+                                 f"got {pair_ok.shape}")
+            eng = self.engine
+            arrays = eng._grid(v0[None], temps, mins, medians, w, pair_ok,
+                               widx=[0], noc_on=None, sched_on=None,
+                               **self._region())
+            fp = (None if checkpoint is None else eng._fingerprint(
+                arrays, seed, swap_every, segment, collect_samples,
+                one_cell=True))
+            res, trace = eng._drive(
+                arrays, seed, sweeps=int(sweeps), seg_size=seg_size,
+                swap_every=swap_every, collect_samples=collect_samples,
+                fingerprint=fp, checkpoint=checkpoint, resume=resume,
+                archives=None if archive is None else [archive],
+                keys=jax.random.PRNGKey(seed)[None],
+                record_trace=record_trace, unbatched=True)
+            if trace is not None:
+                trace = {k: a[0] if k == "initial_costs" else a[:, 0]
+                         for k, a in trace.items()}
+            return DevicePTResult(
+                best_enc=res.best_enc[0], best_cost=float(res.best_cost[0]),
+                history=res.history[0].tolist(),
+                evaluations=res.evaluations, final_enc=res.final_enc[0],
+                final_costs=res.final_costs[0], trace=trace,
+                samples=None if res.samples is None else {
+                    k: a[:, 0] for k, a in res.samples.items()})
 
 
 def get_device_evaluator(wl: GEMMWorkload, db: TechDB = DEFAULT_DB,
                          tile_sizes: Tuple[int, int, int] = DEFAULT_TILE,
                          space: Optional[DesignSpace] = None
                          ) -> DeviceEvaluator:
-    """Cached :class:`DeviceEvaluator` (jit warmup is expensive — share
-    one per (workload, db, tiles, chiplet bound) like ``get_evaluator``).
-
-    The resolved Pallas setting is part of the key, so flipping
-    ``REPRO_PATHFINDER_PALLAS`` mid-process builds a fresh evaluator
-    instead of silently returning the cached other-path one."""
-    from repro.pathfinding.batch import cached_evaluator, evaluator_cache_key
-
-    use_pallas = _resolve_pallas(None)
-    key = evaluator_cache_key(wl, db, tile_sizes, space) + (use_pallas,)
-    return cached_evaluator(
-        _DEVICE_EVALUATORS, key, db,
-        lambda: DeviceEvaluator(wl, db, tile_sizes, space, use_pallas),
-        _DEVICE_EVALUATOR_CACHE_MAX)
+    """:class:`DeviceEvaluator` over the cached one-workload engine of
+    :func:`get_scenario_engine` (the resolved Pallas setting is part of
+    that key)."""
+    return DeviceEvaluator(wl, db, tile_sizes, space)
 
 
 def evaluate_batch_device(encoded: np.ndarray, wl: GEMMWorkload,

@@ -821,9 +821,8 @@ class ScenarioSweep:
     per-cell carbon intensities, normalizer rows, Eq. 17 weight rows and
     ``fold_in``-derived keys all ride through the single ``lax.scan`` of
     :class:`repro.pathfinding.device.ScenarioEngine`, so a 5-region x
-    2-workload sweep compiles the fused program exactly once (the
-    per-cell path re-built a ``Pathfinder``/``DeviceEvaluator`` and paid
-    a full retrace per region even though only one scalar changed).
+    2-workload sweep compiles the fused program exactly once (a cell
+    searched on its own would run a one-cell program of its own).
     Normalizer fits batch the same way: one ``evaluate_batch`` per
     workload plus an exact per-region ``ope`` rescale
     (:func:`repro.pathfinding.batch.fit_region_normalizers`).

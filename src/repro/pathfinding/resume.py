@@ -1,9 +1,9 @@
 """Checkpoint/resume of segmented device searches.
 
-The device engines (:class:`repro.pathfinding.device.DeviceEvaluator`
-and :class:`~repro.pathfinding.device.ScenarioEngine`) no longer run one
-monolithic ``lax.scan``: sweeps advance in fixed-size *segments* driven
-by a host loop, and at every segment boundary the full search state —
+The device tempering engine (:class:`repro.pathfinding.device
+.ScenarioEngine`, and :class:`~repro.pathfinding.device.DeviceEvaluator`,
+its one-cell view) does not run one monolithic ``lax.scan``: sweeps
+advance in fixed-size *segments* driven by a host loop, and at every segment boundary the full search state —
 the scan carry (chain populations, costs, incumbent best, RNG key
 stream, per-cell sweep counters) plus the host-side
 :class:`~repro.pathfinding.pareto.ParetoArchive` contents and the
@@ -15,7 +15,7 @@ and because the segmented scan consumes the *same* key stream as the
 monolithic one, an interrupted-then-resumed run reproduces the
 uninterrupted trajectory bit-for-bit.
 
-This module holds the host-side state plumbing shared by both engines:
+This module holds the host-side state plumbing of that engine:
 
 * :func:`search_fingerprint` — a digest of everything that defines the
   search (engine kind, seed, ladder, weight rows, normalizer rows,
@@ -29,9 +29,9 @@ This module holds the host-side state plumbing shared by both engines:
   checkpoint exists; archives are reloaded *in place* so the caller's
   references stay live).
 * :func:`run_segmented` — the restore-or-init / advance-in-chunks /
-  snapshot-at-boundaries host loop itself, shared by
-  ``DeviceEvaluator.parallel_tempering`` and
-  ``ScenarioEngine.parallel_tempering`` (the engines supply only the
+  snapshot-at-boundaries host loop itself, under
+  ``ScenarioEngine.parallel_tempering`` and the single-workload
+  ``DeviceEvaluator.parallel_tempering`` (the engine supplies only the
   carry packing and output absorption).
 
 The user surface lives one layer up: ``checkpoint_dir=`` / ``resume=``
@@ -269,19 +269,19 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
                   history_np: Callable[[], np.ndarray],
                   sweep_counter: Callable[[int], Union[int, np.ndarray]],
                   flush_seed: Callable[[], None]) -> Tuple[Any, int]:
-    """The host segment loop shared by both device tempering engines
+    """The host segment loop of the device tempering engine
     (restore-or-init / advance-in-chunks / snapshot-at-boundaries).
 
-    :meth:`DeviceEvaluator.parallel_tempering
-    <repro.pathfinding.device.DeviceEvaluator.parallel_tempering>` and
     :meth:`ScenarioEngine.parallel_tempering
-    <repro.pathfinding.device.ScenarioEngine.parallel_tempering>` differ
-    only in what the carry *is* (single-cell vs stacked, one RNG key vs a
-    per-cell key matrix), how a segment's outputs are absorbed (flat
-    history + one archive vs per-cell histories + per-cell archives) and
-    what the checkpoint's sweep counter looks like (scalar vs per-cell
-    vector); the control flow — which is what checkpoint correctness
-    hangs on — is this one function:
+    <repro.pathfinding.device.ScenarioEngine.parallel_tempering>` and its
+    one-cell view :meth:`DeviceEvaluator.parallel_tempering
+    <repro.pathfinding.device.DeviceEvaluator.parallel_tempering>` differ
+    only in what the checkpointed carry *is* (stacked with a per-cell key
+    matrix vs one cell's, with one RNG key), what the history looks like
+    (per-cell rows vs one flat row) and what the checkpoint's sweep
+    counter looks like (per-cell vector vs scalar); the control flow —
+    which is what checkpoint correctness hangs on — is this one
+    function:
 
     1. With ``checkpoint``/``resume``, restore the newest matching
        snapshot; otherwise initialize fresh state via ``fresh()``
@@ -300,9 +300,9 @@ def run_segmented(*, sweeps: int, seg_size: int, checkpoint, resume: bool,
     4. ``flush_seed()`` covers the zero-sweep / resumed-complete edge
        where the loop body never ran to consume the seed block.
 
-    Returns ``(carry, done)``. Bit-exactness contract: this drives the
-    exact same call sequence as the historical in-engine loops, so the
-    goldens in ``tests/test_resume.py`` pin it unchanged."""
+    Returns ``(carry, done)``. Bit-exactness contract: the segmented,
+    interrupted and resumed runs in ``tests/test_resume.py`` reproduce
+    the uninterrupted one exactly."""
     restored = None
     if checkpoint is not None and resume:
         restored = checkpoint.restore(carry_like, archives, fingerprint)

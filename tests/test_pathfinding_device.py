@@ -2,7 +2,9 @@
 the scalar reference, vectorized move validity, the lax.scan tempering
 engine's trajectory equivalence with a host replay, and the supporting
 satellites (LRU topology cache, exact-integer MetricsBatch rows)."""
+import json
 import math
+import os
 import random
 
 import numpy as np
@@ -17,8 +19,9 @@ from repro.core.templates import METRIC_FIELDS, sa_cost
 from repro.pathfinding import (
     DesignSpace,
     DeviceEvaluator,
-    Pathfinder,
     ParallelTempering,
+    ParetoArchive,
+    Pathfinder,
     evaluate_batch,
     fit_normalizer_batched,
     get_device_evaluator,
@@ -210,6 +213,71 @@ def test_device_pt_deterministic_and_improves(dev, norm):
     assert is_valid(SPACE.decode(r1.best_enc))
 
 
+GOLDEN_ONE_CELL = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "goldens", "device_pt_onecell.json")
+
+
+@pytest.mark.parametrize("comm,schedule", [("legacy", "fixed"),
+                                           ("mesh_noc", "window")])
+def test_single_workload_pt_pinned_bit_for_bit(comm, schedule):
+    """A short single-workload search (4 chains, 10 sweeps in segments
+    of 4, exchange every 5th sweep, an archive fed) is pinned bit for
+    bit: history, best cost and row, final population and costs, and
+    the archive's vectors. The values were taken from the separate
+    single-workload scan program that the one-cell view of the engine
+    replaced; both spaces' move levels (live NoC and schedule axes in
+    the second) ride the same pin."""
+    space = (DesignSpace() if comm == "legacy"
+             else DesignSpace(comm=comm, schedule=schedule))
+    assert (space.comm, space.schedule) == (comm, schedule)
+    norm = fit_normalizer_batched(WL, samples=200, seed=7, space=space)
+    archive = ParetoArchive(max_size=64)
+    res = get_device_evaluator(WL, space=space).parallel_tempering(
+        space.sample(4, key=5), np.array([8.0, 4.0, 2.0, 1.0]), 10, 5,
+        seed=11, norm=norm, template=TEMPLATES["T1"], segment=4,
+        archive=archive)
+    with open(GOLDEN_ONE_CELL) as f:
+        pin = json.load(f)[f"{comm}/{schedule}"]
+
+    def hexes(a):
+        return [float(x).hex() for x in np.asarray(a).ravel()]
+
+    assert [float(h).hex() for h in res.history] == pin["history"]
+    assert float(res.best_cost).hex() == pin["best_cost"]
+    assert res.best_enc.tolist() == pin["best_enc"]
+    assert res.final_enc.tolist() == pin["final_enc"]
+    assert hexes(res.final_costs) == pin["final_costs"]
+    assert [hexes(r) for r in archive.vectors] == pin["archive_vectors"]
+    assert res.evaluations == 4 + 4 * 10
+
+
+def test_one_cell_grid_and_single_workload_share_segment_program(dev, norm):
+    """A one-cell grid run of the engine and a single-workload run of
+    the same workload, chain count, segment and exchange cadence run
+    the same segment program: it compiles once between them."""
+    from repro.pathfinding.device import get_scenario_engine, trace_count
+
+    eng = get_scenario_engine((WL,), space=SPACE)
+    assert dev.engine is eng
+    n, sweeps, seg, swap = 3, 6, 3, 3
+    v0 = SPACE.sample(n, key=12)
+    temps = np.array([4.0, 2.0, 1.0])
+    mins, medians = norm.weights_arrays()
+    before = trace_count("scenario_pt")
+    grid = eng.parallel_tempering(
+        v0[None], temps[None], sweeps, swap, 5, mins[None], medians[None],
+        np.tile(TEMPLATES["T1"].weights, (1, n, 1)),
+        np.ones((1, n - 1), bool), [dev.db.carbon_intensity], [0],
+        segment=seg)
+    assert trace_count("scenario_pt") == before + 1
+    one = dev.parallel_tempering(v0, temps, sweeps, swap, seed=5,
+                                 norm=norm, template=TEMPLATES["T1"],
+                                 segment=seg)
+    assert trace_count("scenario_pt") == before + 1
+    assert one.history[0] == grid.history[0, 0]
+    assert len(one.history) == grid.history.shape[1] == 1 + sweeps
+
+
 @pytest.mark.slow
 def test_pt_strategy_device_flag(norm):
     """ParallelTempering through the facade: the device engine honors
@@ -283,7 +351,7 @@ def test_exact_tile_assignment_matches_float64_host():
     from repro.pathfinding.device import _assign_jax, _base_cfg, _exact_ints
 
     ev = get_evaluator(WL, space=SPACE)
-    cfg = _base_cfg(SPACE, ev.db, 6, 12, 0.0, None)
+    cfg = _base_cfg(SPACE, ev.db, 6, 12, None)
     rng = np.random.default_rng(0)
     P, C = 4000, cfg.C
     nmask = np.arange(C)[None] < rng.integers(1, C + 1, P)[:, None]

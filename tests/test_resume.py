@@ -123,11 +123,11 @@ def test_resume_after_completion_is_a_noop(dev, norm):
     with tempfile.TemporaryDirectory() as d:
         a, arch_a = _run(dev, norm, sweeps=10, segment=5,
                          checkpoint=SearchCheckpointer(d))
-        before = trace_count("pt")
+        before = trace_count("scenario_pt")
         b, arch_b = _run(dev, norm, sweeps=10, segment=5,
                          checkpoint=SearchCheckpointer(d))
         # restored at sweep 10: no segment runs, no compile, same result
-        assert trace_count("pt") == before
+        assert trace_count("scenario_pt") == before
         assert b.history == a.history and b.best_cost == a.best_cost
         assert np.array_equal(arch_b.vectors, arch_a.vectors)
 

@@ -124,13 +124,16 @@ def test_scenario_sweep_5x2_compiles_once_cells_differ_reproducible():
     sweep = ScenarioSweep(
         strategy=ScalarizationSweep(directions=2, n_chains=2, sweeps=3),
         norm_samples=100)  # default REGION_INTENSITIES: 5 regions
-    before = {k: trace_count(k) for k in ("scenario_pt", "pt", "eval_cost")}
+    before = {k: trace_count(k)
+              for k in ("scenario_pt", "scenario_init", "scenario_eval")}
     sf = sweep.run(wls, key=11)
     assert len(sf.scenarios) == 10
-    # exactly ONE fused scenario-scan compile, ZERO per-cell programs
+    # exactly ONE fused scenario-scan compile, ZERO per-cell programs: a
+    # cell searched alone would add a one-cell init program of its own,
+    # and one evaluator of its own beside the grid's frontier re-cost
     assert trace_count("scenario_pt") == before["scenario_pt"] + 1
-    assert trace_count("pt") == before["pt"]
-    assert trace_count("eval_cost") == before["eval_cost"]
+    assert trace_count("scenario_init") == before["scenario_init"] + 1
+    assert trace_count("scenario_eval") == before["scenario_eval"] + 1
     fronts = [sf.results[s.key].frontier.vectors for s in sf.scenarios]
     for f in fronts:
         assert len(f) >= 1 and non_dominated_mask(f).all()

@@ -101,7 +101,7 @@ def test_admission_into_partially_full_batch_zero_recompiles():
     svc.submit(spec("early", wl=0, ci=0.475))
     assert svc.step()           # bucket warmup + admit + first segment
     before = {k: trace_count(k)
-              for k in ("scenario_pt", "scenario_init", "pt", "eval_cost")}
+              for k in ("scenario_pt", "scenario_init", "scenario_eval")}
     # join mid-flight, mixed workloads/regions, same bucket shape
     svc.submit(spec("late-0", wl=1, ci=0.024))
     svc.submit(spec("late-1", wl=0, ci=0.82))

@@ -66,21 +66,24 @@ def test_prefix_select_kernel_compiles_at_stacked_size(one_chip):
 
 def test_eval_cost_jnp_program_compiles_at_4096_rows(one_chip):
     """The fused float64 evaluate+cost program on the jnp gather path
-    (WL1, 4096 rows) compiles for the chip and fits its memory."""
+    (WL1, 4096 rows) compiles for the chip and fits its memory: the
+    engine's one-cell eval program with its metrics, which the
+    single-workload evaluator runs."""
     from repro.core import workload
     from repro.jaxenv import search_numerics
     from repro.pathfinding.device import DeviceEvaluator
 
-    dev = DeviceEvaluator(workload(1), use_pallas=False)
-    width = dev.space.width
+    eng = DeviceEvaluator(workload(1), use_pallas=False).engine
+    width = eng.space.width
     f64 = jnp.float64
     with search_numerics():
-        compiled = dev._eval_cost_jit.lower(
-            _spec((SYSTEMS, width), jnp.int32, one_chip),
-            _spec((6,), f64, one_chip), _spec((6,), f64, one_chip),
-            _spec((6,), f64, one_chip), _spec((), f64, one_chip),
-            _spec((), f64, one_chip), _spec((), f64, one_chip),
-            _spec((24,), f64, one_chip), _spec((24,), f64, one_chip),
+        compiled = eng._eval_fn(1, SYSTEMS, True).lower(
+            _spec((1, SYSTEMS, width), jnp.int32, one_chip),
+            _spec((1, 6), f64, one_chip), _spec((1, 6), f64, one_chip),
+            _spec((1, 6), f64, one_chip), _spec((1,), f64, one_chip),
+            _spec((1,), f64, one_chip), _spec((1,), f64, one_chip),
+            _spec((1, 24), f64, one_chip), _spec((1, 24), f64, one_chip),
+            _spec((1,), jnp.int32, one_chip),
         ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
